@@ -643,7 +643,7 @@ def load_module(path: str, repo_root: str) -> ModuleInfo:
 def default_paths(repo_root: str) -> List[str]:
     """The scanned surface: the package, the live scripts, and the bench/
     driver entry points. Tests are excluded (they exercise pathological
-    patterns on purpose); scripts/archive is frozen history."""
+    patterns on purpose)."""
     paths: List[str] = []
     pkg_dir = os.path.join(repo_root, contracts.PKG)
     for base, dirs, files in os.walk(pkg_dir):
@@ -654,7 +654,7 @@ def default_paths(repo_root: str) -> List[str]:
     if os.path.isdir(scripts):
         paths.extend(os.path.join(scripts, f)
                      for f in os.listdir(scripts) if f.endswith(".py"))
-    for extra in ("bench.py", "federated.py"):
+    for extra in ("bench.py", "chip_smoke.py", "federated.py"):
         p = os.path.join(repo_root, extra)
         if os.path.exists(p):
             paths.append(p)

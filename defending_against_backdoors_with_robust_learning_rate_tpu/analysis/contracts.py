@@ -249,8 +249,9 @@ DONATED_FAMILIES: Tuple[str, ...] = (
 # callbacks stall the dispatch pipeline and are unserializable in the AOT
 # bank; infeed/outfeed are not part of this design at all.
 FORBIDDEN_PRIMITIVES = frozenset({
-    "debug_callback", "pure_callback", "io_callback", "callback",
-    "outside_call", "infeed", "outfeed", "host_local_array_to_global_array",
+    "debug_callback", "debug_print", "pure_callback", "io_callback",
+    "callback", "outside_call", "infeed", "outfeed",
+    "host_local_array_to_global_array",
 })
 
 # collective primitive names counted against the budgets

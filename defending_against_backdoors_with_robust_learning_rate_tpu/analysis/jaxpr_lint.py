@@ -57,9 +57,11 @@ class _rolled_scans:
             os.environ.pop("RLR_SCAN_MODE", None)
         else:
             os.environ["RLR_SCAN_MODE"] = self._prev
+# the result type is one array type, or — where XLA's combiner merged
+# several collectives into one op — a parenthesized tuple of them
 _HLO_COLLECTIVE_RE = re.compile(
-    r"=\s+\S+\s+(all-reduce|all-gather|all-to-all|collective-permute|"
-    r"reduce-scatter)(?:-start)?\(")
+    r"=\s+(?:\(.*?\)|\S+)\s+(all-reduce|all-gather|all-to-all|"
+    r"collective-permute|reduce-scatter)(?:-start)?\(")
 
 
 # --------------------------------------------------------------------------
@@ -67,7 +69,7 @@ _HLO_COLLECTIVE_RE = re.compile(
 # --------------------------------------------------------------------------
 
 def _sub_jaxprs(value):
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
     if isinstance(value, ClosedJaxpr):
         yield value.jaxpr
     elif isinstance(value, Jaxpr):

@@ -52,7 +52,9 @@ class Config:
     snap: int = 1                   # eval every `snap` rounds
 
     # --- TPU-native additions ---
-    platform: str = ""              # "" = default backend; "cpu"/"tpu" override
+    platform: str = ""              # "" = whatever JAX finds (the run's
+                                    # [device] line says which); "cpu"/"tpu"
+                                    # make JAX fail when it is absent
     seed: int = 0
     # multi-host (DCN) rendezvous — one process per host; all empty/0 means
     # single-process (or cloud auto-detection inside jax.distributed)
@@ -361,9 +363,9 @@ class Config:
                                     # executable AOT bank (warm starts skip
                                     # XLA entirely); --no_compile_cache
                                     # opts out
-    compile_cache_dir: str = ""     # cache root ("" = $RLR_COMPILE_CACHE_DIR
-                                    # or ~/.cache/rlr_fl — stable across
-                                    # runs by design)
+    compile_cache_dir: str = ""     # cache root ("" = the checkout's
+                                    # .compile_cache/); ignored where
+                                    # $JAX_COMPILATION_CACHE_DIR is set
     async_metrics: bool = True      # per-round scalars stay on device and
                                     # drain on a background thread (no
                                     # blocking host sync in the round
@@ -1124,8 +1126,9 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "the serialized-executable AOT bank "
                         "(utils/compile_cache.py)")
     p.add_argument("--compile_cache_dir", type=str, default=d.compile_cache_dir,
-                   help="compile-cache root (default: $RLR_COMPILE_CACHE_DIR "
-                        "or ~/.cache/rlr_fl)")
+                   help="compile-cache root (default: .compile_cache/ in "
+                        "the checkout; $JAX_COMPILATION_CACHE_DIR, where "
+                        "set, takes precedence)")
     p.add_argument("--telemetry", choices=("off", "basic", "full"),
                    default=d.telemetry,
                    help="in-jit defense telemetry (obs/telemetry.py): "

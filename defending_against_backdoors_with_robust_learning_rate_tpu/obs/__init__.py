@@ -15,9 +15,8 @@ Three layers, built to be cheap enough to leave on:
                    program untouched: training is bit-identical.
 - `obs.heartbeat`  an atomically-rewritten `status.json` (phase, round,
                    last span, compile-in-flight flag, PID, HBM live/peak
-                   watermarks) that `scripts/tpu_watch.sh` and the
-                   session stall detector consume instead of parsing
-                   stderr growth.
+                   watermarks) that the service supervisor and the fleet
+                   console consume instead of parsing stderr growth.
 - `obs.attribution` device-time attribution from `jax.profiler` traces:
                    the `--profile_rounds` sampled capture window, the
                    shared Chrome-trace parser (compute vs collective vs
@@ -48,7 +47,7 @@ The fleet plane (ISSUE 15) — cross-run, service-level observability:
                    table from heartbeats + ledgers.
 - `obs.trajectory` the cross-run perf trajectory
                    (`scripts/bench_trajectory.py`): bench artifacts
-                   folded into the committed `trajectory.json` series,
+                   folded into a `trajectory.json` series,
                    regressions judged against a pinned tolerance.
 - `obs.constants`  `NON_TIMING_PREFIXES`, the single-sourced exclusion
                    list every crash-exact metrics byte-compare filters

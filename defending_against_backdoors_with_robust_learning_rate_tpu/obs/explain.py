@@ -8,8 +8,8 @@ a bench artifact — a bare ``bench.py`` result object or a session
 ``BENCH_r*.json`` record), normalizes every span's total host time to
 ms per dispatched round, groups spans into phase families::
 
-    compile     bench/probe, bench/data, bench/aot_acquire,
-                bench/first_block  (+ the artifact's compile_s scalar)
+    compile     bench/data, bench/aot_acquire, bench/first_block
+                (+ the artifact's compile_s scalar)
     steady      round/*, prefetch/*, bench/steady_blocks,
                 bench/profile_blocks
     eval        eval/*, metrics/*
@@ -37,8 +37,7 @@ from . import trajectory
 
 FAMILIES = ("compile", "steady", "eval", "drain", "checkpoint", "other")
 
-_COMPILE_SPANS = ("bench/probe", "bench/data", "bench/aot_acquire",
-                  "bench/first_block")
+_COMPILE_SPANS = ("bench/data", "bench/aot_acquire", "bench/first_block")
 _STEADY_SPANS = ("bench/steady_blocks", "bench/profile_blocks")
 
 # a collective-share move this large reclassifies a steady regression:

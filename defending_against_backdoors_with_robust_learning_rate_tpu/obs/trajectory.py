@@ -1,12 +1,11 @@
-"""Cross-run perf trajectory: fold bench artifacts into one committed
+"""Cross-run perf trajectory: fold bench artifacts into one
 series and judge regressions against a pinned tolerance.
 
-Five ``BENCH_r*.json`` files sat on disk with no trajectory between
-them; this module (driven by ``scripts/bench_trajectory.py``) folds each
-bench artifact — either the session-runner record shape
+This module (driven by ``scripts/bench_trajectory.py``) folds each bench
+artifact — either the session-runner record shape
 (``{"n", "cmd", "rc", "tail", "parsed": {...}}``) or a bare bench.py
-result object (``{"metric": "fl_rounds_per_sec", ...}``) — into
-``trajectory.json``::
+result object (``{"metric": "fl_rounds_per_sec", ...}``) — into a
+series file::
 
     {"version": 1, "tolerance": 0.15, "series": [
         {"label": "r01", "source": "BENCH_r01.json", "ok": false,
@@ -14,10 +13,11 @@ result object (``{"metric": "fl_rounds_per_sec", ...}``) — into
         {"label": "r03", "ok": true, "rounds_per_sec": 2.2268,
          "mfu": 0.1011, "group": "tpu|fmnist|f32", ...}, ...]}
 
-Judgement extends the ``obs/report.py`` PASS/FAIL workflow to the time
-axis: points are grouped by comparability (backend class, bench config,
-dtype, reduced-shapes flag — a CPU-fallback number must never be judged
-against a TPU flagship), and within a group each point is compared to
+No series is committed: the driver's ``PERF_LEDGER.jsonl`` is the record
+of chip numbers. Judgement extends the ``obs/report.py`` PASS/FAIL
+workflow to the time axis: points are grouped by comparability (backend
+class, bench config, dtype — a ``--platform cpu`` debug number must never
+be judged against a TPU run), and within a group each point is compared to
 the best earlier point; a drop past ``tolerance`` is a REGRESSION. Exit
 codes mirror the report gate: 0 all pass, 1 regression, 2 malformed
 input. Stdlib-only.
@@ -50,8 +50,6 @@ class MalformedArtifact(ValueError):
 def _group_key(parsed: Dict[str, Any]) -> str:
     device = str(parsed.get("device", ""))
     plat = "tpu" if "tpu" in device.lower() else "cpu"
-    if parsed.get("reduced_shapes"):
-        plat += "_reduced"
     config = parsed.get("bench_config", "fmnist")
     dtype = parsed.get("dtype", "f32")
     return f"{plat}|{config}|{dtype}"
@@ -103,7 +101,7 @@ def parse_artifact(path: str) -> Dict[str, Any]:
     }
     for key in ("mfu", "tflops_per_sec", "tflop_per_round", "compile_s",
                 "chain", "vs_baseline", "dtype", "bench_config",
-                "reduced_shapes", "backend_note", "slot_occupancy",
+                "slot_occupancy",
                 "cells", "scheduler_bins", "wall_s", "population",
                 "workers", "shard_clients"):
         if key in parsed:
@@ -113,7 +111,7 @@ def parse_artifact(path: str) -> Dict[str, Any]:
 
 def point_value(point: Dict[str, Any]) -> float:
     """The judged value of an ok point, whichever metric it carries
-    (committed pre-fleet points have no 'metric' field and store
+    (pre-fleet points have no 'metric' field and store
     rounds_per_sec — the historical schema stays readable)."""
     for key in METRICS.values():
         if key in point:
@@ -124,7 +122,7 @@ def point_value(point: Dict[str, Any]) -> float:
 
 
 # --------------------------------------------------------------------------
-# the committed series
+# the series file
 # --------------------------------------------------------------------------
 
 def load(path: str) -> Dict[str, Any]:

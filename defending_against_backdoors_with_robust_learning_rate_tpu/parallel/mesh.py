@@ -20,13 +20,18 @@ def pick_agent_mesh_size(requested: int, agents_per_round: int,
                          n_devices: int | None = None) -> int:
     """Largest device count <= min(requested or all, available) that divides
     the per-round participant count (blocking policy, SURVEY.md 7.2.5 — e.g.
-    m=10 on a v5e-8 slice uses 5 devices, 2 agents per device)."""
+    m=10 on a v5e-8 slice uses 5 devices, 2 agents per device). A mesh
+    smaller than the one asked for says so: the run is otherwise
+    indistinguishable from the requested one except by its speed."""
     avail = n_devices if n_devices is not None else len(jax.devices())
-    cap = min(requested if requested > 0 else avail, avail)
-    for d in range(cap, 0, -1):
-        if agents_per_round % d == 0:
-            return d
-    return 1
+    want = requested if requested > 0 else avail
+    picked = next(d for d in range(min(want, avail), 0, -1)
+                  if agents_per_round % d == 0)
+    if picked < want:
+        print(f"[mesh] WARNING: --mesh {requested} asked for {want} "
+              f"device(s), using {picked}: {avail} available, and the mesh "
+              f"must divide agents_per_round={agents_per_round}")
+    return picked
 
 
 def make_mesh(n_devices: int = 0) -> Mesh:

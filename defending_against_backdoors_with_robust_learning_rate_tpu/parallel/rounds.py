@@ -34,6 +34,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
@@ -48,8 +49,6 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.ops.aggregate imp
     rlr_from_sign_sum, sq_dist_accum, trmean_k)
 from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
     buckets)
-from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.compat import (
-    shard_map)
 from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
     AGENTS_AXIS)
 

@@ -58,7 +58,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.service import (
 from defending_against_backdoors_with_robust_learning_rate_tpu.service.supervisor import (
     POISONED, Supervisor, UnitFailure, WEDGED)
 from defending_against_backdoors_with_robust_learning_rate_tpu.train import (
-    RoundEngine)
+    RoundEngine, device_record)
 from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
     checkpoint as ckpt)
 from defending_against_backdoors_with_robust_learning_rate_tpu.utils.metrics import (
@@ -239,7 +239,9 @@ def _serve(cfg: Config, writer, max_rounds, _adapt, _adapt_reentry,
         if lead:
             writer = MetricsWriter(cfg.log_dir, run_name(cfg),
                                    cfg.tensorboard,
-                                   boundary=recovery["boundary"])
+                                   boundary=recovery["boundary"],
+                                   start_fields={"device":
+                                                 device_record()})
         else:
             writer = NullWriter()
     if recovery["boundary"]:

@@ -43,7 +43,7 @@ Throughput accounting: slot OCCUPANCY = busy-slot-dispatches over
 total-slot-dispatches (idle slots compute masked garbage — the metric,
 not a mask, accounts for the waste), and the fleet-level `cells/hour`
 gauge rides the Prometheus textfile exporter plus a `fleet`
-comparability group in trajectory.json (obs/trajectory.py), gated in CI
+comparability group of a trajectory series (obs/trajectory.py)
 like every other perf number.
 """
 

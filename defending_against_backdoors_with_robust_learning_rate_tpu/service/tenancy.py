@@ -141,25 +141,6 @@ def plan_packs(base_cfg, cells: List[Dict[str, Any]], tenants: int,
     return items
 
 
-def _adopt(bank, cfg, family, jit_obj, example_args):
-    """AOT-adopt one tenant family (the train.py _adopt_aot discipline:
-    any failure falls back to the plain jit, which still warm-starts
-    through the persistent XLA cache). Returns (fn_or_None, seconds)."""
-    if bank is None:
-        return None, 0.0
-    try:
-        compiled, hit, secs, _ = bank.get_or_compile(
-            family, cfg, jit_obj, example_args)
-    except Exception as e:
-        print(f"[aot] {family}: falling back to jit "
-              f"({type(e).__name__}: {e})")
-        return None, 0.0
-    print(f"[aot] {family}: "
-          + ("loaded from cache" if hit else "compiled+banked")
-          + f" in {secs:.1f}s")
-    return compiled, secs
-
-
 class _Slot:
     """One resident tenant slot's host-side state: the cell it is
     running, its clock offset, its metrics writer and the per-tenant
@@ -356,7 +337,7 @@ class PackEngine:
             shard_avals = tuple(
                 jax.ShapeDtypeStruct((m,) + a.shape[1:], a.dtype)
                 for a in ab(arrays))
-            fn, secs = _adopt(
+            fn, secs = compile_cache.adopt(
                 bank, rep, round_fn.family, round_fn.jitted,
                 (carryE_aval, kE_aval, rnd_aval, knob_aval) + shard_avals)
             self.compile_s += secs
@@ -373,7 +354,7 @@ class PackEngine:
             round_fn = ftenancy.make_tenant_round_fn(rep, model, norm,
                                                      *arrays)
             data_avals = ab(arrays)
-            fn, secs = _adopt(
+            fn, secs = compile_cache.adopt(
                 bank, rep, round_fn.family, round_fn.jitted,
                 (carryE_aval, kE_aval, rnd_aval, knob_aval) + data_avals)
             self.compile_s += secs
@@ -388,7 +369,7 @@ class PackEngine:
                     rep, model, norm, *arrays)
                 ids_aval = jax.ShapeDtypeStruct((self.chain_n,),
                                                 jnp.int32)
-                fn, secs = _adopt(
+                fn, secs = compile_cache.adopt(
                     bank, rep, chained_fn.family, chained_fn.jitted,
                     (carryE_aval, kE_aval, ids_aval, knob_aval)
                     + data_avals)
@@ -406,13 +387,13 @@ class PackEngine:
         self.pval = tuple(map(jnp.asarray, pad_eval_set(
             fed.pval_images, fed.pval_labels, rep.eval_bs)))
         self.eval_val_fn = self.eval_pval_fn = eval_fn
-        fn, secs = _adopt(bank, rep, "eval_val_mt", eval_fn,
-                          (pE_aval,) + ab(self.val))
+        fn, secs = compile_cache.adopt(bank, rep, "eval_val_mt", eval_fn,
+                                       (pE_aval,) + ab(self.val))
         self.compile_s += secs
         if fn is not None:
             self.eval_val_fn = fn
-        fn, secs = _adopt(bank, rep, "eval_poison_mt", eval_fn,
-                          (pE_aval,) + ab(self.pval))
+        fn, secs = compile_cache.adopt(bank, rep, "eval_poison_mt",
+                                       eval_fn, (pE_aval,) + ab(self.pval))
         self.compile_s += secs
         if fn is not None:
             self.eval_pval_fn = fn

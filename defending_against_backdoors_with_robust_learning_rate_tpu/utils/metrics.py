@@ -318,7 +318,8 @@ class MetricsWriter:
     """JSONL always; TensorBoard when available and enabled."""
 
     def __init__(self, log_dir: str, name: Optional[str] = None,
-                 tensorboard: bool = True, boundary: bool = True):
+                 tensorboard: bool = True, boundary: bool = True,
+                 start_fields: Optional[dict] = None):
         os.makedirs(log_dir, exist_ok=True)
         self.dir = os.path.join(log_dir, name) if name else log_dir
         os.makedirs(self.dir, exist_ok=True)
@@ -337,11 +338,12 @@ class MetricsWriter:
         # `boundary=False` is the crash-exact resume path (service/driver):
         # the stream was truncated to a journaled offset and the continued
         # rows must splice in with NO extra record, so the recovered file
-        # is byte-identical to an uninterrupted run's.
+        # is byte-identical to an uninterrupted run's. `start_fields` ride
+        # the boundary record (train.py: `device`, what the segment ran on).
         if boundary:
             self._jsonl.write(json.dumps(
-                {"tag": "_run/start", "value": time.time(), "step": -1})
-                + "\n")
+                {"tag": "_run/start", "value": time.time(), "step": -1,
+                 **(start_fields or {})}) + "\n")
 
     def offset(self) -> int:
         """Current byte offset of metrics.jsonl (flushed) — what the round

@@ -316,8 +316,7 @@ def test_collective_counting_on_toy_shard_map():
     import numpy as np
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.compat import (
-        shard_map)
+    from jax import shard_map
     mesh = Mesh(np.array(jax.devices()[:8]), ("agents",))
 
     def body(x):
@@ -327,7 +326,7 @@ def test_collective_counting_on_toy_shard_map():
         return s + t + jnp.sum(g)
 
     f = jax.jit(shard_map(body, mesh=mesh, in_specs=(P("agents"),),
-                          out_specs=P()))
+                          out_specs=P(), check_vma=False))
     from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
         compile_cache)
     closed = compile_cache.trace_program(
@@ -336,12 +335,29 @@ def test_collective_counting_on_toy_shard_map():
     assert counts["psum"] == 2 and counts["all_gather"] == 1
 
 
+def test_hlo_collective_counts_array_and_tuple_results():
+    """Optimized-HLO counting: a single-array result type, and the tuple
+    type XLA's combiner gives a merged op (jaxlib 0.9.0's XLA:CPU merges
+    the per-leaf psums into one tuple-typed all-reduce — an op the
+    counter cannot see makes every compiled ceiling vacuous)."""
+    hlo = """
+  %psum.7 = f32[] all-reduce(%wrapped_reduce), channel_id=1, to_apply=%r
+  %all-reduce = (f32[32]{0}, f32[3,3,1,32]{3,2,1,0}, /*index=2*/f32[3]{0}) all-reduce(%a, %b, %c), channel_id=2
+  %get-tuple-element.1 = f32[32]{0} get-tuple-element(%all-reduce), index=0
+  %ag = f32[8,4]{1,0} all-gather(%param.1), channel_id=3, dimensions={0}
+  %ars = f32[4]{0} all-reduce-start(%x), channel_id=4
+  %ard = f32[4]{0} all-reduce-done(%ars)
+"""
+    assert jaxpr_lint.hlo_collective_counts(hlo) == {
+        "all-reduce": 3, "all-gather": 1}
+
+
 def test_forbidden_primitive_detected_on_broken_toy():
     import jax.numpy as jnp
 
     @jax.jit
     def leaky(x):
-        jax.debug.print("x={x}", x=x)   # debug_callback: forbidden
+        jax.debug.print("x={x}", x=x)   # debug_print: forbidden
         return x + 1
 
     from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
@@ -349,7 +365,7 @@ def test_forbidden_primitive_detected_on_broken_toy():
     closed = compile_cache.trace_program(
         leaky, (jax.ShapeDtypeStruct((4,), jnp.float32),))
     sites = jaxpr_lint.forbidden_sites(closed)
-    assert sites and "debug_callback" in sites[0]
+    assert sites and "debug_print" in sites[0]
     assert jaxpr_lint.forbidden_sites(
         compile_cache.trace_program(
             jax.jit(lambda x: x + 1),

@@ -1,10 +1,11 @@
 """Compile-persistence & AOT executable bank (utils/compile_cache.py).
 
-Covers the PR-2 acceptance surface: executable serialize/deserialize
-round-trip, manifest invalidation on a changed config fingerprint, the
-persistent-cache-dir smoke, the program-family planner, and the
-precompile -> train warm-start handoff (a banked family is LOADED, not
-recompiled, by a subsequent train.run)."""
+Covers: executable serialize/deserialize round-trip (executed, on a host
+with more devices than the program uses), manifest invalidation on a
+changed config fingerprint or an edited source file, cache-root
+resolution, the program-family planner, and the precompile -> train
+warm-start handoff (a banked family is LOADED, not recompiled, by a
+subsequent train.run)."""
 
 import os
 
@@ -50,10 +51,15 @@ def test_fingerprint_stability_and_invalidation():
                               _example()))
 
 
-def test_bank_roundtrip_and_manifest_invalidation(tmp_path):
+def test_bank_roundtrip_and_manifest_invalidation(tmp_path, monkeypatch):
     """Cold compile banks a loadable executable; a fresh bank instance
-    loads it (disk round-trip, no XLA); a changed config fingerprint
-    misses and recompiles."""
+    loads AND EXECUTES it (disk round-trip, no XLA); a changed config
+    fingerprint or an edited package source file misses and recompiles."""
+    # the habitat of the execution_devices default: a single-device
+    # executable reloaded where the backend has more devices (the faked
+    # 8-device harness here, any four-chip host in production) must stay
+    # pinned to the device it was compiled for
+    assert jax.device_count() > 1
     bank = cc.AotBank(str(tmp_path))
     jit_obj = jax.jit(lambda x: x @ x.T + 1.0)
     x = jnp.arange(64, dtype=jnp.float32).reshape(8, 8)
@@ -81,19 +87,77 @@ def test_bank_roundtrip_and_manifest_invalidation(tmp_path):
     assert not hit3 and entry3["fingerprint"] != entry["fingerprint"]
     assert len(bank2.entries()) == 2
 
+    # an edited source file => the warm run recompiles: an entry is only
+    # ever served to the code that built it
+    monkeypatch.setattr(cc, "source_digest", lambda: "edited")
+    _, hit4, _, entry4 = bank2.get_or_compile("unit", TINY, jit_obj, ex)
+    assert not hit4 and entry4["fingerprint"] != entry["fingerprint"]
+    assert len(bank2.entries()) == 3
 
-def test_persistent_cache_dir_smoke(tmp_path):
-    """enable_persistent_cache points jax at <root>/xla and compiles land
-    there as cache entries (tier-1 cache-dir smoke)."""
-    before = jax.config.jax_compilation_cache_dir
-    try:
-        xla_dir = cc.enable_persistent_cache(str(tmp_path))
-        assert xla_dir == os.path.join(str(tmp_path), "xla")
-        f = jax.jit(lambda x: jnp.sin(x) @ jnp.cos(x.T))
-        jax.block_until_ready(f(jnp.ones((16, 16))))
-        assert any(n.endswith("-cache") for n in os.listdir(xla_dir))
-    finally:
-        jax.config.update("jax_compilation_cache_dir", before)
+
+def test_source_digest_tracks_py_files_on_disk(tmp_path):
+    pkg = tmp_path / "pkg"
+    (pkg / "sub").mkdir(parents=True)
+    (pkg / "a.py").write_text("x = 1\n")
+    (pkg / "sub" / "b.py").write_text("y = 1\n")
+    (pkg / "notes.txt").write_text("not code\n")
+
+    def digest():
+        cc.source_digest.cache_clear()
+        return cc.source_digest(str(pkg))
+
+    d0 = digest()
+    assert d0 == digest()
+    (pkg / "notes.txt").write_text("still not code\n")
+    assert digest() == d0
+    (pkg / "sub" / "b.py").write_text("y = 2\n")
+    d1 = digest()
+    assert d1 != d0
+    (pkg / "sub" / "b.py").rename(pkg / "sub" / "c.py")
+    assert digest() not in (d0, d1)
+    cc.source_digest.cache_clear()
+    # the real package digest is memoized per process and keys the
+    # fingerprint (see test_bank_roundtrip_and_manifest_invalidation)
+    assert cc.source_digest() == cc.source_digest()
+
+
+def test_cache_root_resolution(tmp_path, monkeypatch):
+    """$JAX_COMPILATION_CACHE_DIR > --compile_cache_dir > the fixed,
+    git-ignored directory of the checkout. Under the variable XLA's cache
+    is that directory exactly and this module never re-sets it in code."""
+    machine, flag = str(tmp_path / "machine"), str(tmp_path / "flag")
+    cfg = TINY.replace(compile_cache_dir=flag)
+    monkeypatch.setenv(cc.CACHE_DIR_ENV, machine)
+    assert cc.cache_root(cfg) == cc.cache_root(TINY) == machine
+    updated = []
+    real_update = jax.config.update
+    monkeypatch.setattr(
+        jax.config, "update",
+        lambda name, val: (updated.append(name), real_update(name, val)))
+    assert cc.enable_persistent_cache(cfg) == machine
+    bank = cc.setup(cfg)
+    assert bank.dir == os.path.join(machine, "aot")
+    assert "jax_compilation_cache_dir" not in updated
+
+    monkeypatch.delenv(cc.CACHE_DIR_ENV)
+    assert cc.cache_root(cfg) == flag
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert cc.cache_root(TINY) == cc.CHECKOUT_CACHE_ROOT \
+        == os.path.join(repo, ".compile_cache")
+    with open(os.path.join(repo, ".gitignore")) as f:
+        assert ".compile_cache/" in f.read().split()
+
+
+def test_flag_root_persistent_cache_smoke(tmp_path, own_cache_root):
+    """Without the variable, enable_persistent_cache points jax at
+    <root>/xla and compiles land there as cache entries."""
+    xla_dir = cc.enable_persistent_cache(
+        TINY.replace(compile_cache_dir=str(tmp_path)))
+    assert xla_dir == os.path.join(str(tmp_path), "xla")
+    assert jax.config.jax_compilation_cache_dir == xla_dir
+    f = jax.jit(lambda x: jnp.sin(x) @ jnp.cos(x.T))
+    jax.block_until_ready(f(jnp.ones((16, 16))))
+    assert any(n.endswith("-cache") for n in os.listdir(xla_dir))
 
 
 def _plan_families(cfg, host_mode=None):
@@ -130,7 +194,7 @@ def test_plan_programs_families():
         "round_host", "eval_val", "eval_poison"]
 
 
-def test_precompile_then_train_loads(tmp_path, capsys):
+def test_precompile_then_train_loads(tmp_path, capsys, own_cache_root):
     """Acceptance: a precompiled family is LOADED (not recompiled) by the
     subsequent train.run, and the warm run's results equal a cold run's."""
     from defending_against_backdoors_with_robust_learning_rate_tpu import train
@@ -169,7 +233,8 @@ def test_precompile_then_train_loads(tmp_path, capsys):
 
 
 @pytest.mark.slow  # two in-process bench.main runs (~4 min on the CI box)
-def test_bench_cold_then_warm_cache_hit(tmp_path, monkeypatch, capsys):
+def test_bench_cold_then_warm_cache_hit(tmp_path, monkeypatch, capsys,
+                                        own_cache_root):
     """bench.py acceptance: a second run on a populated cache reports
     cache_hit true and compile_s_warm <= 20% of compile_s_cold."""
     import json

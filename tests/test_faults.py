@@ -66,18 +66,38 @@ def _leaves_equal(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
+# The masked weighted average multiplies by a reciprocal and reduces a
+# `where`-selected stack; whether XLA:CPU emits the bit-same fused sum as
+# for the dense expression is its choice, and under jaxlib 0.9.0 it does
+# not: avg differs by 1 ulp of the leaf's largest magnitude (3e-8 abs).
+# Pinned at 2, the bound tests/test_bucket_parity.py already gives avg's
+# reduction order. The sign rule reduces integer-valued f32 partials,
+# which sum exactly in any order, and stays pinned bitwise.
+AVG_ULPS = 2
+
+
+def _leaves_match(aggr, a, b):
+    if aggr != "avg":
+        return _leaves_equal(a, b)
+    for x, y in zip(jax.tree_util.tree_leaves(a), jax.tree_util.tree_leaves(b), strict=True):
+        x, y = np.asarray(x, np.float32), np.asarray(y, np.float32)
+        scale = np.spacing(max(np.max(np.abs(x)), np.max(np.abs(y))))
+        assert np.max(np.abs(x - y)) <= AVG_ULPS * scale
+
+
 # -------------------------------------------------- parity gate: all-ones ---
 
 @pytest.mark.parametrize("aggr", AGGRS)
 def test_all_ones_mask_matches_dense_bitwise(aggr):
     """Every rule with an all-ones mask == the dense rule, bit for bit
-    (jitted, so XLA's fusion/strength-reduction choices are in play)."""
+    (jitted, so XLA's fusion/strength-reduction choices are in play);
+    avg within AVG_ULPS (see there)."""
     u, sizes = _updates(), _sizes()
     mask = jnp.ones((8,), bool)
     dense = jax.jit(lambda u, s: _dense(aggr, u, s))(u, sizes)
     masked = jax.jit(lambda u, s, mk: _dense(aggr, u, s, mask=mk))(
         u, sizes, mask)
-    _leaves_equal(dense, masked)
+    _leaves_match(aggr, dense, masked)
 
 
 def test_all_ones_mask_rlr_matches_dense_bitwise():
@@ -92,10 +112,10 @@ def test_all_ones_mask_rlr_matches_dense_bitwise():
 def test_all_ones_mask_matches_dense_sharded(aggr):
     """Same parity gate on the faked 8-device mesh: the masked collective
     aggregation (masked psums / sentinel-padded all_to_all chunks) with an
-    all-ones mask == the dense collective path, bit for bit."""
+    all-ones mask == the dense collective path, bit for bit (avg within
+    AVG_ULPS)."""
     from jax.sharding import PartitionSpec as P
-    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.compat import (
-        shard_map)
+    from jax import shard_map
     from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
         make_mesh)
     from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
@@ -124,7 +144,7 @@ def test_all_ones_mask_matches_dense_sharded(aggr):
         masked_body, mesh=mesh,
         in_specs=(P("agents"), P("agents"), P()),
         out_specs=P(), check_vma=False))(u, sizes, mask)
-    _leaves_equal(dense, masked)
+    _leaves_match(aggr, dense, masked)
 
 
 def _setup(aggr="avg", num_agents=8, **kw):
@@ -145,14 +165,15 @@ def _setup(aggr="avg", num_agents=8, **kw):
 def test_all_ones_faults_round_matches_dense_round_bitwise():
     """End-to-end round-level parity gate on the vmap path: a faults config
     whose draw is an all-ones mask (straggler budget == local_ep) produces
-    bit-identical new params to the dense round — fault sampling must not
-    perturb any existing key stream."""
+    the dense round's new params — fault sampling must not perturb any
+    existing key stream (a perturbed stream differs in the first digits;
+    the avg server step itself is held to AVG_ULPS, see there)."""
     cfg, model, params, norm, arrays = _setup("avg")
     key = jax.random.PRNGKey(42)
     p1, i1 = make_round_fn(cfg, model, norm, *arrays)(params, key)
     fcfg = cfg.replace(straggler_rate=1.0, straggler_epochs=cfg.local_ep)
     p2, i2 = make_round_fn(fcfg, model, norm, *arrays)(params, key)
-    _leaves_equal(p1, p2)
+    _leaves_match("avg", p1, p2)
     assert float(i2["fault_voters"]) == cfg.agents_per_round
     assert float(i2["fault_dropped"]) == 0.0
 
@@ -331,14 +352,15 @@ def test_all_invalid_round_is_a_finite_noop():
 def test_norm_cap_alone_enables_validation():
     """--payload_norm_cap without any fault rate must still route through
     the validation + mask path (a cap that silently no-ops is worse than no
-    cap), and with no over-norm payloads it stays bit-identical to dense."""
+    cap), and with no over-norm payloads it matches dense (avg: within
+    AVG_ULPS, see there)."""
     assert Config(payload_norm_cap=5.0).faults_enabled
     cfg, model, params, norm, arrays = _setup("avg")
     key = jax.random.PRNGKey(4)
     p1, _ = make_round_fn(cfg, model, norm, *arrays)(params, key)
     p2, i2 = make_round_fn(cfg.replace(payload_norm_cap=1e9), model, norm,
                            *arrays)(params, key)
-    _leaves_equal(p1, p2)
+    _leaves_match("avg", p1, p2)
     assert float(i2["fault_voters"]) == cfg.agents_per_round
 
 

@@ -321,7 +321,7 @@ def test_span_zscores_spike_flat_and_short_window():
 
 def test_span_family_mapping():
     fam = obs_explain.span_family
-    assert fam("bench/probe") == "compile"
+    assert fam("bench/data") == "compile"
     assert fam("bench/aot_acquire") == "compile"
     assert fam("bench/steady_blocks") == "steady"
     assert fam("round/dispatch") == "steady"
@@ -341,7 +341,7 @@ def _artifact(path, value, steady_ms, compile_s, collective=None):
            "spans": {"bench/steady_blocks": {
                          "count": 8, "total_s": steady_ms * 32 / 1e3,
                          "p95_ms": steady_ms},
-                     "bench/probe": {"count": 1, "total_s": compile_s}}}
+                     "bench/data": {"count": 1, "total_s": compile_s}}}
     if collective is not None:
         doc["attribution"] = {"device_present": True,
                               "collective_frac": collective}
@@ -368,7 +368,7 @@ def test_explain_names_planted_steady_regression(tmp_path):
 
 def test_explain_compile_and_collective_classification(tmp_path):
     # compile_s growth reclassifies even when the span table is quiet
-    # (an AOT-miss recompile bypasses the bench/probe span entirely)
+    # (an AOT-miss recompile bypasses the bench/data span entirely)
     base = _artifact(tmp_path / "b.json", 10.0, 5.0, 2.0)
     cand = _artifact(tmp_path / "c.json", 9.9, 5.0, 2.0)
     doc = json.loads((tmp_path / "c.json").read_text())

@@ -335,16 +335,9 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.obs.constants imp
     NON_TIMING_PREFIXES as EXCLUDE)
 
 
-@pytest.fixture(scope="module")
-def svc_cache(tmp_path_factory):
-    return (os.environ.get("RLR_COMPILE_CACHE_DIR")
-            or str(tmp_path_factory.mktemp("hlth_aot")))
-
-
-def _cfg(tmp_path, svc_cache, tag, **kw):
+def _cfg(tmp_path, tag, **kw):
     return SVC.replace(log_dir=str(tmp_path / f"{tag}_logs"),
-                       checkpoint_dir=str(tmp_path / f"{tag}_ck"),
-                       compile_cache_dir=svc_cache, **kw)
+                       checkpoint_dir=str(tmp_path / f"{tag}_ck"), **kw)
 
 
 def _lines(cfg):
@@ -370,15 +363,14 @@ def test_serve_refuses_recover_with_rlr_adapt(tmp_path):
         serve(cfg)
 
 
-def test_serve_nan_recovers_via_rollback_byte_identical(tmp_path,
-                                                        svc_cache):
+def test_serve_nan_recovers_via_rollback_byte_identical(tmp_path):
     """THE ladder drill (vmap twin of the slow 8-way one): a seeded NaN
     burst DISCARDs, escalates to ROLLBACK (the restored prev_params were
     poisoned too), replays clean — rc 0, journaled phases, and a final
     stream byte-identical to the uninjected twin."""
-    cfg_a = _cfg(tmp_path, svc_cache, "a", service_rounds=6)
+    cfg_a = _cfg(tmp_path, "a", service_rounds=6)
     serve(cfg_a)
-    cfg_b = _cfg(tmp_path, svc_cache, "b", service_rounds=6,
+    cfg_b = _cfg(tmp_path, "b", service_rounds=6,
                  chaos="nan@3", health_policy="recover")
     summary = serve(cfg_b)
     hs = summary["service"]["health"]
@@ -399,11 +391,11 @@ def test_serve_nan_recovers_via_rollback_byte_identical(tmp_path,
 
 
 def test_serve_persistent_fault_escalates_to_quarantine_then_halt(
-        tmp_path, svc_cache):
+        tmp_path):
     """A fault with fire budget left re-poisons every replay: the walk
     must spend DISCARD -> ROLLBACK -> QUARANTINE and HALT loudly with
     the journal intact and every transition counted."""
-    cfg = _cfg(tmp_path, svc_cache, "h", service_rounds=6,
+    cfg = _cfg(tmp_path, "h", service_rounds=6,
                chaos="nan@3x9", health_policy="recover")
     with pytest.raises(UnitFailure, match="health ladder exhausted"):
         serve(cfg)
@@ -417,10 +409,10 @@ def test_serve_persistent_fault_escalates_to_quarantine_then_halt(
             "health_halt"} <= set(status["service_phases"])
 
 
-def test_serve_record_policy_keeps_metrics_flowing(tmp_path, svc_cache):
+def test_serve_record_policy_keeps_metrics_flowing(tmp_path):
     """The sweep default: a NaN cell is recorded-and-skipped — the run
     COMPLETES, Health/* rows mark the damage, no ladder arms."""
-    cfg = _cfg(tmp_path, svc_cache, "r", service_rounds=6,
+    cfg = _cfg(tmp_path, "r", service_rounds=6,
                chaos="nan@3", health_policy="record")
     summary = serve(cfg)
     assert "health" not in summary["service"]   # no ladder under record
@@ -433,12 +425,12 @@ def test_serve_record_policy_keeps_metrics_flowing(tmp_path, svc_cache):
     assert summary["health"]["params_finite"] == 0.0
 
 
-def test_serve_spike_heals_in_place_at_discard(tmp_path, svc_cache):
+def test_serve_spike_heals_in_place_at_discard(tmp_path):
     """A finite magnitude burst in the COMMIT (chaos spike@N) trips the
     ladder's committed-delta lane at the same boundary — before the
     checkpoint — and heals at the DISCARD rung (re-dispatch with the
     recovery nonce; the injection's fire budget is spent)."""
-    cfg = _cfg(tmp_path, svc_cache, "s", service_rounds=10, snap=1,
+    cfg = _cfg(tmp_path, "s", service_rounds=10, snap=1,
                chaos="spike@6:40", health_policy="recover")
     summary = serve(cfg)
     hs = summary["service"]["health"]
@@ -448,8 +440,7 @@ def test_serve_spike_heals_in_place_at_discard(tmp_path, svc_cache):
     assert state["episode"]["open"] is False
 
 
-def test_resume_from_mid_rollback_state_resumes_ladder(tmp_path,
-                                                       svc_cache):
+def test_resume_from_mid_rollback_state_resumes_ladder(tmp_path):
     """Kill-mid-rollback, the cheap in-process twin (true-SIGKILL twin
     below is slow-gated): reproduce on disk exactly what a kill between
     the ladder's rollback RECORD and the completed re-entry leaves —
@@ -457,9 +448,9 @@ def test_resume_from_mid_rollback_state_resumes_ladder(tmp_path,
     resumed process must pick the LADDER up (close the episode at the
     first healthy boundary), not re-meet the failure, and the stream
     must stay byte-identical to the uninjected twin."""
-    cfg_a = _cfg(tmp_path, svc_cache, "a", service_rounds=6)
+    cfg_a = _cfg(tmp_path, "a", service_rounds=6)
     serve(cfg_a)
-    cfg_b = _cfg(tmp_path, svc_cache, "b", service_rounds=6,
+    cfg_b = _cfg(tmp_path, "b", service_rounds=6,
                  chaos="nan@3", health_policy="recover")
     # life 1 equivalent, up to the kill: rounds 1-2 served + checkpointed
     serve(cfg_b.replace(chaos=""), max_rounds=2)
@@ -488,12 +479,12 @@ def test_resume_from_mid_rollback_state_resumes_ladder(tmp_path,
     assert state["episode"]["open"] is False
 
 
-def test_serve_rearms_journaled_quarantine_set(tmp_path, svc_cache):
+def test_serve_rearms_journaled_quarantine_set(tmp_path):
     """A kill AFTER a QUARANTINE rung was recorded but BEFORE its
     re-entry completed leaves the suspect set only in health_state.json
     — a fresh serve must re-arm it (the suspects stay out of the
     electorate; the ladder resumes, not the failure)."""
-    cfg = _cfg(tmp_path, svc_cache, "q", service_rounds=2,
+    cfg = _cfg(tmp_path, "q", service_rounds=2,
                health_policy="recover")
     os.makedirs(cfg.log_dir, exist_ok=True)
     with open(os.path.join(cfg.log_dir, "health_state.json"),
@@ -511,11 +502,11 @@ def test_serve_rearms_journaled_quarantine_set(tmp_path, svc_cache):
 
 @pytest.mark.slow  # sharded-family compile; the vmap twin above pins the
 # identical ladder machinery in tier-1 (ISSUE-14 acceptance drill)
-def test_serve_nan_recovers_on_8way_shard_map(tmp_path, svc_cache):
+def test_serve_nan_recovers_on_8way_shard_map(tmp_path):
     base = dict(service_rounds=6, mesh=8)
-    cfg_a = _cfg(tmp_path, svc_cache, "a", **base)
+    cfg_a = _cfg(tmp_path, "a", **base)
     serve(cfg_a)
-    cfg_b = _cfg(tmp_path, svc_cache, "b", chaos="nan@3",
+    cfg_b = _cfg(tmp_path, "b", chaos="nan@3",
                  health_policy="recover", **base)
     summary = serve(cfg_b)
     hs = summary["service"]["health"]
@@ -541,10 +532,7 @@ def test_service_kill_mid_rollback_subprocess_drill(tmp_path):
             "--robustLR_threshold", "3", "--seed", "5",
             "--no_tensorboard", "--service_rounds", "6",
             "--service_backoff_s", "0.01"]
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "RLR_COMPILE_CACHE_DIR":
-               os.environ.get("RLR_COMPILE_CACHE_DIR",
-                              str(tmp_path / "cache"))}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     def drill(tag, extra):
         cmd = args + ["--log_dir", str(tmp_path / f"{tag}_logs"),

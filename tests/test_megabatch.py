@@ -534,7 +534,7 @@ def test_flops_per_example_analytic():
     assert f28 and f8 and f28 > f8 > 0
     assert flops_per_example("cifar10", "cnn", (32, 32, 3)) > f28
     assert flops_per_example("cifar10", "resnet9", (32, 32, 3)) is None
-    cfg = bench_config("fmnist", cpu_fallback=True).replace(bs=16)
+    cfg = bench_config("fmnist").replace(bs=16)
     model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
     params = init_params(model, (28, 28, 1), jax.random.PRNGKey(0))
     norm = make_normalizer(0.5, 0.5, False)

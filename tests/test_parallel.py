@@ -79,8 +79,7 @@ def test_param_shard_transpose_roundtrip():
     """all_to_all param-sharding (SURVEY.md 7.3.1) is a lossless transpose:
     agents-sharded [m/d, ...] -> all-agents x param-chunk [m, c] -> back."""
     from jax.sharding import PartitionSpec as P
-    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.compat import (
-        shard_map)
+    from jax import shard_map
     from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
         _from_param_shard, _to_param_shards)
 

@@ -375,13 +375,7 @@ SVC = Config(data="synthetic", num_agents=8, bs=16, local_ep=1,
 
 
 @pytest.fixture(scope="module")
-def svc_cache(tmp_path_factory):
-    return (os.environ.get("RLR_COMPILE_CACHE_DIR")
-            or str(tmp_path_factory.mktemp("rep_aot")))
-
-
-@pytest.fixture(scope="module")
-def attack_runs(tmp_path_factory, svc_cache):
+def attack_runs(tmp_path_factory):
     """Three serve() runs shared by the drills below: boost with the
     plane on, its --reputation off twin, and signflip."""
     root = tmp_path_factory.mktemp("rep_runs")
@@ -392,8 +386,7 @@ def attack_runs(tmp_path_factory, svc_cache):
                     ("signflip", dict(attack="signflip",
                                       attack_boost=2.0))):
         cfg = SVC.replace(log_dir=str(root / f"{tag}_logs"),
-                          checkpoint_dir=str(root / f"{tag}_ck"),
-                          compile_cache_dir=svc_cache, **kw)
+                          checkpoint_dir=str(root / f"{tag}_ck"), **kw)
         out[tag] = (cfg, serve(cfg))
     return out
 

@@ -162,7 +162,6 @@ def test_churn_host_sampled_refused():
 def test_classify_failure_classes():
     assert classify(TimeoutError("x")) == WEDGED
     assert classify(RuntimeError("UNAVAILABLE: backend")) == TRANSIENT
-    assert classify(RuntimeError("Connection reset by peer")) == TRANSIENT
     assert classify(RuntimeError("please retry later")) == TRANSIENT
     # status names match case-sensitively: lowercase prose "unavailable"
     # alone is not the gRPC constant, and carries no other signature
@@ -498,18 +497,9 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.obs.constants imp
     NON_TIMING_PREFIXES as EXCLUDE)
 
 
-@pytest.fixture(scope="module")
-def svc_cache(tmp_path_factory):
-    """One AOT bank for every serve test in this module (CI reuses the
-    persisted cross-run cache instead)."""
-    return (os.environ.get("RLR_COMPILE_CACHE_DIR")
-            or str(tmp_path_factory.mktemp("svc_aot")))
-
-
-def _svc_cfg(tmp_path, svc_cache, tag, **kw):
+def _svc_cfg(tmp_path, tag, **kw):
     return SVC.replace(log_dir=str(tmp_path / f"{tag}_logs"),
-                       checkpoint_dir=str(tmp_path / f"{tag}_ck"),
-                       compile_cache_dir=svc_cache, **kw)
+                       checkpoint_dir=str(tmp_path / f"{tag}_ck"), **kw)
 
 
 def _metric_lines(cfg):
@@ -547,15 +537,15 @@ def _interrupt_mid_service(cfg, rounds, last_ckpt):
     eng.writer.close()                  # flushed file, no summary rows
 
 
-def test_serve_crash_exact_resume_vmap(tmp_path, svc_cache):
+def test_serve_crash_exact_resume_vmap(tmp_path):
     """THE acceptance drill (vmap path): interrupted-at-an-unjournaled-
     boundary + resumed == uninterrupted, byte-for-byte modulo wall-clock
     rows; the resume truncates the orphaned rows and replays them."""
-    cfg_a = _svc_cfg(tmp_path, svc_cache, "a", service_rounds=8)
+    cfg_a = _svc_cfg(tmp_path, "a", service_rounds=8)
     sum_a = serve(cfg_a)
     assert sum_a["service"]["rounds_served"] == 8
 
-    cfg_b = _svc_cfg(tmp_path, svc_cache, "b", service_rounds=8)
+    cfg_b = _svc_cfg(tmp_path, "b", service_rounds=8)
     # first life dies after round 6's eval rows landed but BEFORE round
     # 6's checkpoint: the newest journaled boundary is round 4
     _interrupt_mid_service(cfg_b, rounds=6, last_ckpt=4)
@@ -570,7 +560,7 @@ def test_serve_crash_exact_resume_vmap(tmp_path, svc_cache):
     assert status["phase"] == "done"
 
 
-def test_resume_reenters_aot_bank(tmp_path, svc_cache):
+def test_resume_reenters_aot_bank(tmp_path):
     """ISSUE-16 pin: a recovered service re-enters the AOT bank as a HIT.
 
     The restored PRNG key used to come back as a typed ``key<fry>``
@@ -582,7 +572,7 @@ def test_resume_reenters_aot_bank(tmp_path, svc_cache):
     belongs to the resumed life."""
     from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
         events as obs_events)
-    cfg = _svc_cfg(tmp_path, svc_cache, "aot", service_rounds=6)
+    cfg = _svc_cfg(tmp_path, "aot", service_rounds=6)
     # warm the bank AND leave a crash-exact interruption behind
     _interrupt_mid_service(cfg, rounds=4, last_ckpt=2)
     summary = serve(cfg)
@@ -597,13 +587,13 @@ def test_resume_reenters_aot_bank(tmp_path, svc_cache):
 # tier-1: test_serve_crash_exact_resume_vmap drills the identical
 # recovery protocol; the sharded round body itself is parity-pinned by
 # test_parallel + test_bucket_parity.
-def test_serve_crash_exact_resume_sharded(tmp_path, svc_cache):
+def test_serve_crash_exact_resume_sharded(tmp_path):
     """The same drill over the 8-device shard_map path (faked CPU mesh):
     churn + masked collectives + crash recovery compose."""
     base = dict(mesh=0, service_rounds=4)
-    cfg_a = _svc_cfg(tmp_path, svc_cache, "a", **base)
+    cfg_a = _svc_cfg(tmp_path, "a", **base)
     serve(cfg_a)
-    cfg_b = _svc_cfg(tmp_path, svc_cache, "b", **base)
+    cfg_b = _svc_cfg(tmp_path, "b", **base)
     _interrupt_mid_service(cfg_b, rounds=4, last_ckpt=2)
     sum_b = serve(cfg_b)
     assert sum_b["service"]["resumed_from"] == 2
@@ -611,10 +601,10 @@ def test_serve_crash_exact_resume_sharded(tmp_path, svc_cache):
     assert _metric_lines(cfg_b) == _metric_lines(cfg_a)
 
 
-def test_serve_wedged_dispatch_retries_and_completes(tmp_path, svc_cache):
+def test_serve_wedged_dispatch_retries_and_completes(tmp_path):
     """Acceptance: an injected wedged dispatch triggers backoff + retry
     and the run completes, with Service/* retry counters recorded."""
-    cfg = _svc_cfg(tmp_path, svc_cache, "w", service_rounds=4,
+    cfg = _svc_cfg(tmp_path, "w", service_rounds=4,
                    chaos="wedge@3x2")
     summary = serve(cfg)
     svc = summary["service"]
@@ -630,11 +620,10 @@ def test_serve_wedged_dispatch_retries_and_completes(tmp_path, svc_cache):
     assert {"retry", "backoff"} <= set(status["service_phases"])
 
 
-def test_serve_poisoned_eval_skipped_training_continues(tmp_path,
-                                                        svc_cache):
+def test_serve_poisoned_eval_skipped_training_continues(tmp_path):
     """Degradation policy: a deterministically failing eval is skipped
     (counted), training continues to completion."""
-    cfg = _svc_cfg(tmp_path, svc_cache, "pe", service_rounds=4,
+    cfg = _svc_cfg(tmp_path, "pe", service_rounds=4,
                    chaos="poison_eval@2")
     summary = serve(cfg)
     svc = summary["service"]
@@ -647,11 +636,11 @@ def test_serve_poisoned_eval_skipped_training_continues(tmp_path,
     assert steps == {4}                 # round-2 eval skipped, round-4 ran
 
 
-def test_serve_wedged_drain_degrades_to_sync_metrics(tmp_path, svc_cache):
+def test_serve_wedged_drain_degrades_to_sync_metrics(tmp_path):
     """A stalled metrics drain wedges the checkpoint flush; the driver
     closes the drain (bounded) and finishes on synchronous metrics — no
     boundary rows lost."""
-    cfg = _svc_cfg(tmp_path, svc_cache, "wd", service_rounds=4,
+    cfg = _svc_cfg(tmp_path, "wd", service_rounds=4,
                    chaos="wedge_drain@2:0.8", service_deadline_s=0.1,
                    service_retries=1)
     summary = serve(cfg)
@@ -664,12 +653,11 @@ def test_serve_wedged_drain_degrades_to_sync_metrics(tmp_path, svc_cache):
     assert steps == {2, 4}              # both boundaries recorded
 
 
-def test_serve_poisoned_dispatch_fails_loud_then_resumes(tmp_path,
-                                                         svc_cache):
+def test_serve_poisoned_dispatch_fails_loud_then_resumes(tmp_path):
     """A poisoned dispatch is non-degradable: the service exits loudly
     with the journal intact, and the next serve resumes crash-exactly and
     completes."""
-    cfg = _svc_cfg(tmp_path, svc_cache, "pd", service_rounds=4,
+    cfg = _svc_cfg(tmp_path, "pd", service_rounds=4,
                    chaos="poison@3")
     with pytest.raises(UnitFailure) as ei:
         serve(cfg)
@@ -681,9 +669,9 @@ def test_serve_poisoned_dispatch_fails_loud_then_resumes(tmp_path,
     assert summary["round"] == 4
 
 
-def test_serve_stop_file_ends_indefinite_service(tmp_path, svc_cache):
+def test_serve_stop_file_ends_indefinite_service(tmp_path):
     """service_rounds=0 streams until <log_dir>/service.stop appears."""
-    cfg = _svc_cfg(tmp_path, svc_cache, "stop", service_rounds=0)
+    cfg = _svc_cfg(tmp_path, "stop", service_rounds=0)
     os.makedirs(cfg.log_dir, exist_ok=True)
     open(os.path.join(cfg.log_dir, "service.stop"), "w").close()
     summary = serve(cfg)
@@ -740,10 +728,7 @@ def test_service_kill9_subprocess_drill(tmp_path):
             "--no_tensorboard", "--churn_available", "0.75",
             "--churn_period", "3", "--service_rounds", "6",
             "--service_backoff_s", "0.01"]
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "RLR_COMPILE_CACHE_DIR":
-               os.environ.get("RLR_COMPILE_CACHE_DIR",
-                              str(tmp_path / "cache"))}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     def drill(tag, extra):
         cmd = args + ["--log_dir", str(tmp_path / f"{tag}_logs"),
@@ -782,7 +767,7 @@ def test_chaos_kill_midbuf_grammar_and_gate(tmp_path):
         serve(cfg)
 
 
-def test_serve_buffered_midbuffer_recovery(tmp_path, svc_cache):
+def test_serve_buffered_midbuffer_recovery(tmp_path):
     """The ISSUE-12 chaos acceptance, in-process: a service interrupted
     at a checkpoint whose carried buffer is NON-EMPTY (K=2m, odd snap:
     commits land on even ticks, checkpoints on odd) resumes to
@@ -793,11 +778,11 @@ def test_serve_buffered_midbuffer_recovery(tmp_path, svc_cache):
     base = dict(agg_mode="buffered", async_buffer_k=16,
                 straggler_rate=0.4, snap=3, service_rounds=9,
                 churn_available=1.0)
-    cfg_a = _svc_cfg(tmp_path, svc_cache, "a", **base)
+    cfg_a = _svc_cfg(tmp_path, "a", **base)
     sum_a = serve(cfg_a)
     assert sum_a["service"]["rounds_served"] == 9
 
-    cfg_b = _svc_cfg(tmp_path, svc_cache, "b", **base)
+    cfg_b = _svc_cfg(tmp_path, "b", **base)
     # die after round 6's eval rows landed but BEFORE round 6's
     # checkpoint: the newest journaled boundary is round 3 — whose
     # buffer held round 3's uncommitted arrivals (fill > 0 at the
@@ -832,10 +817,7 @@ def test_service_kill_midbuf_subprocess_drill(tmp_path):
             "--service_backoff_s", "0.01",
             "--agg_mode", "buffered", "--async_buffer_k", "16",
             "--straggler_rate", "0.4"]
-    env = {**os.environ, "JAX_PLATFORMS": "cpu",
-           "RLR_COMPILE_CACHE_DIR":
-               os.environ.get("RLR_COMPILE_CACHE_DIR",
-                              str(tmp_path / "cache"))}
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
 
     def drill(tag, extra):
         cmd = args + ["--log_dir", str(tmp_path / f"{tag}_logs"),
