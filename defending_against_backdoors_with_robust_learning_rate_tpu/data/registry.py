@@ -443,26 +443,32 @@ def get_federated_data(cfg) -> FederatedData:
         native)
     from defending_against_backdoors_with_robust_learning_rate_tpu.attack.poison import (
         poison_agent_shards, build_poisoned_val)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
+        spans)
 
-    train, val, synthetic = get_datasets(cfg)
+    with spans.span("setup/data/load_or_generate"):
+        train, val, synthetic = get_datasets(cfg)
 
     # pad shards to a multiple of the batch size so the client's
     # [n_batches, bs] reshape is exact (fl/client.py)
-    if isinstance(train, list):     # fedemnist-style per-user shards
-        shards = native.pack_uneven([s[0] for s in train],
-                                    [s[1] for s in train],
-                                    pad_multiple=cfg.bs)
-    else:
-        groups = native.distribute_data(train.labels, cfg.num_agents,
-                                        n_classes=cfg.n_classes)
-        shards = native.pack_shards(train.images, train.labels, groups,
-                                    cfg.num_agents, pad_multiple=cfg.bs)
+    with spans.span("setup/data/partition"):
+        if isinstance(train, list):     # fedemnist-style per-user shards
+            shards = native.pack_uneven([s[0] for s in train],
+                                        [s[1] for s in train],
+                                        pad_multiple=cfg.bs)
+        else:
+            groups = native.distribute_data(train.labels, cfg.num_agents,
+                                            n_classes=cfg.n_classes)
+            shards = native.pack_shards(train.images, train.labels, groups,
+                                        cfg.num_agents, pad_multiple=cfg.bs)
 
-    imgs, lbls, pmask = poison_agent_shards(shards.images, shards.labels,
-                                            shards.sizes, cfg)
+    with spans.span("setup/data/poison"):
+        imgs, lbls, pmask = poison_agent_shards(shards.images, shards.labels,
+                                                shards.sizes, cfg)
     shards.images, shards.labels, shards.poison_mask = imgs, lbls, pmask
 
-    pv_imgs, pv_lbls = build_poisoned_val(val.images, val.labels, cfg)
+    with spans.span("setup/data/poisoned_val"):
+        pv_imgs, pv_lbls = build_poisoned_val(val.images, val.labels, cfg)
     mean, std = _norm_arrays(cfg.data)
     return FederatedData(
         train=shards,

@@ -5,8 +5,15 @@ Three layers, built to be cheap enough to leave on:
 
 - `obs.spans`      host-side span tracer emitting Chrome-trace/Perfetto
                    `trace.json` plus matching `jax.profiler` annotations;
-                   per-span p50/p95/max aggregates land in metrics.jsonl
-                   (`Spans/*`) and the bench JSON.
+                   a span records id, parent (also across a hand-over to
+                   another thread), dispatch unit, absolute start/end, the
+                   thread's CPU seconds and self time; counters and an
+                   `xla/acquire` span per program the backend acquires
+                   sit beside them. Per-span p50/p95/max/self/cpu
+                   aggregates land in metrics.jsonl (`Spans/*`) and the
+                   bench JSON; `spans.current()` is the newest engine's
+                   tracer (None under `--no_spans`), and module-level
+                   `spans.span()` / `spans.count()` go to it.
 - `obs.telemetry`  defense telemetry computed INSIDE the jitted round fn
                    (vote-margin histogram, lr flip fraction, update-norm
                    percentiles, honest-vs-corrupt cosine) — device-resident

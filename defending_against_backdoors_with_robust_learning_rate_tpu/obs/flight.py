@@ -48,7 +48,10 @@ import json
 import os
 import threading
 import time
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
+
+from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
+    spans as obs_spans)
 
 DEFAULT_WINDOW = 64
 STREAM_NAME = "flight.jsonl"
@@ -67,13 +70,16 @@ class FlightRecorder:
     """Per-round flight data: ring buffer + crash-exact stream +
     atomic incident snapshots (module docstring).
 
-    The hot-path cost per round is a few dict updates and one buffered
-    line write — ``observe_span`` is wired into the span tracer's
-    completion hook and must stay allocation-light."""
+    The hot-path cost per round is one buffered line write. A unit's
+    per-span milliseconds come from ``span_source`` (the engine hands in
+    ``SpanTracer.unit_ms``: the tracer already groups its spans by unit),
+    plus whatever a caller without a tracer fed ``observe_span``."""
 
     def __init__(self, path: str, run: str = "", corr: str = "",
                  slot: str = "", window: int = DEFAULT_WINDOW,
-                 clock=time.time):
+                 clock=time.time,
+                 span_source: Optional[Callable[..., Dict[str, float]]]
+                 = None):
         self.path = path
         self.snapshot_path = os.path.join(
             os.path.dirname(path) or ".", SNAPSHOT_NAME)
@@ -81,6 +87,7 @@ class FlightRecorder:
         self.corr = corr
         self.slot = slot
         self._clock = clock
+        self._span_source = span_source
         self._lock = threading.Lock()
         self._ring: collections.deque = collections.deque(maxlen=window)
         self._spans: Dict[str, float] = {}
@@ -131,10 +138,18 @@ class FlightRecorder:
 
     # ----------------------------------------------------------- recording
 
+    def _with_source(self, own: Dict[str, float], take: bool
+                     ) -> Dict[str, float]:
+        """`own` (what ``observe_span`` was fed) plus the span source's
+        milliseconds for the unit so far."""
+        if self._span_source is not None:
+            for name, ms in self._span_source(take=take).items():
+                own[name] = round(own.get(name, 0.0) + ms, 3)
+        return own
+
     def observe_span(self, name: str, dur_s: float) -> None:
-        """Span-completion hook (chained onto the tracer's ``on_end``):
-        accumulate this round's per-span milliseconds. Thread-safe —
-        the metrics drain completes spans on its own thread."""
+        """Accumulate this round's per-span milliseconds by hand, for a
+        caller with no tracer to be the ``span_source``. Thread-safe."""
         if not self.enabled:
             return
         with self._lock:
@@ -176,7 +191,8 @@ class FlightRecorder:
         # mutation (the torn-tail bug class this recorder exists to
         # catch must not live in the recorder itself)
         with self._lock:
-            spans, self._spans = self._spans, {}
+            own, self._spans = self._spans, {}
+            spans = self._with_source(own, take=True)
             notes, self._notes = self._notes, {}
             gap_ms = (round((self._t_begin - self._t_last_end) * 1e3, 3)
                       if self._t_begin is not None
@@ -212,8 +228,9 @@ class FlightRecorder:
                 return None
             if self._f is not None:
                 try:
-                    self._f.write((json.dumps(rec) + "\n").encode())
-                    self._f.flush()
+                    with obs_spans.span("obs/flight_write"):
+                        self._f.write((json.dumps(rec) + "\n").encode())
+                        self._f.flush()
                 except (OSError, ValueError):
                     # observability never downs the run
                     self.enabled = False
@@ -240,7 +257,7 @@ class FlightRecorder:
             return None
         with self._lock:
             win = list(self._ring)
-            current = dict(self._spans)
+            current = self._with_source(dict(self._spans), take=False)
         doc: Dict[str, Any] = {
             "v": 1, "run": self.run, "corr": self.corr,
             "slot": self.slot, "reason": reason, "round": rnd,

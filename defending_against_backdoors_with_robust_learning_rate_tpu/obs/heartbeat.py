@@ -27,6 +27,9 @@ import os
 import time
 from typing import Any, Dict, Optional
 
+from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
+    spans)
+
 DEFAULT_MIN_INTERVAL_S = 1.0
 # a heartbeat older than this is stale — unless a compile is in flight,
 # which legitimately produces no updates for minutes (stall detectors must
@@ -88,9 +91,10 @@ class Heartbeat:
         self._state["updated_at"] = now
         tmp = f"{self.path}.tmp.{os.getpid()}"
         try:
-            with open(tmp, "w") as f:
-                json.dump(self._state, f)
-            os.replace(tmp, self.path)
+            with spans.span("obs/heartbeat_write"):
+                with open(tmp, "w") as f:
+                    json.dump(self._state, f)
+                os.replace(tmp, self.path)
             self._last_write = now
         except OSError:
             # observability must never take down the run (e.g. read-only

@@ -36,6 +36,8 @@ from typing import Any, Dict, List, Optional, Tuple
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
     attribution)
+from defending_against_backdoors_with_robust_learning_rate_tpu.obs.spans import (
+    SPAN_STATS)
 
 BASELINE_NAME = "obs_baseline.json"
 DEFAULT_TOLERANCE = 1.5
@@ -52,9 +54,6 @@ DEFAULT_PIN_METRICS = (
     "Device/Gap_Ms_Per_Round",
     "Memory/HBM_Peak_Bytes",
 )
-
-SPAN_STATS = ("count", "total_s", "p50_ms", "p95_ms", "max_ms")
-
 
 def repo_root() -> str:
     pkg_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))

@@ -48,7 +48,8 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.health import (
 from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
     Heartbeat, NullHeartbeat, SpanTracer, attribution as obs_attribution,
     events as obs_events, flight as obs_flight,
-    reputation as obs_reputation, telemetry as obs_telemetry)
+    reputation as obs_reputation, spans as obs_spans,
+    telemetry as obs_telemetry)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
     get_model, init_params, param_count)
 from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
@@ -155,6 +156,31 @@ class RoundEngine:
 
     def __init__(self, cfg: Config, writer: Optional[MetricsWriter] = None,
                  resume_upto: Optional[int] = None):
+        # observability (obs/): host-side round-trace spans + the
+        # status.json heartbeat, lead process only. The tracer comes first
+        # so that `engine/build` spans the whole constructor; it is the
+        # process's current one (obs/spans.current()) from here on, and
+        # listens for every program the backend acquires until close().
+        self.lead = jax.process_index() == 0
+        self.hb = NullHeartbeat()
+        self.tracer = SpanTracer(enabled=cfg.spans and self.lead,
+                                 on_end=self._span_ended)
+        obs_spans.set_current(self.tracer)
+        self.tracer.watch_compiles()
+        try:
+            with self.tracer.span("engine/build"):
+                self._build(cfg, writer, resume_upto)
+        except BaseException:
+            self.tracer.close()
+            raise
+
+    def _span_ended(self, name: str, dur_s: float) -> None:
+        # the heartbeat rides the tracer's span-completion hook, so
+        # `last_span` tracks without extra calls
+        self.hb.span_hook(name, dur_s)
+
+    def _build(self, cfg: Config, writer: Optional[MetricsWriter],
+               resume_upto: Optional[int]) -> None:
         # resume_upto pins the newest checkpoint round restore may pick
         # (0 = none): the service driver passes its journal-agreed resume
         # round so a kill between ckpt.save and journal_record cannot make
@@ -225,18 +251,13 @@ class RoundEngine:
         impl = apply_rng_impl(cfg.rng_impl)
         if impl != "threefry2x32":
             print(f"[rng] {impl} bit generator")
-        # observability (obs/): host-side round-trace spans + the
-        # status.json heartbeat, lead process only. The heartbeat rides the
-        # tracer's span-completion hook, so `last_span` tracks without
-        # extra calls.
-        self.lead = lead = jax.process_index() == 0
-        self.hb = hb = (Heartbeat(cfg.status_file
-                                  or os.path.join(cfg.log_dir,
-                                                  "status.json"))
-                        if cfg.heartbeat and lead else NullHeartbeat())
-        self.tracer = tracer = SpanTracer(enabled=cfg.spans and lead,
-                                          on_end=hb.span_hook)
-        hb.update(phase="setup", rounds=cfg.rounds, force=True)
+        lead, tracer = self.lead, self.tracer
+        with tracer.span("setup/obs"):
+            self.hb = hb = (Heartbeat(cfg.status_file
+                                      or os.path.join(cfg.log_dir,
+                                                      "status.json"))
+                            if cfg.heartbeat and lead else NullHeartbeat())
+            hb.update(phase="setup", rounds=cfg.rounds, force=True)
         if cfg.telemetry != "off":
             print(f"[telemetry] in-jit defense telemetry: {cfg.telemetry} "
                   f"(Defense/* scalars ride the metrics stream)")
@@ -274,24 +295,33 @@ class RoundEngine:
                   f"(data/cohort.py MAX_CANDIDATES); staying on the dense "
                   f"path — set --cohort_size to decouple population from "
                   f"cohort")
-        if cohort_mode:
-            from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
-                get_cohort_data)
-            cohort_src = fed = get_cohort_data(cfg)
-        else:
-            fed = get_federated_data(cfg)
+        with tracer.span("setup/data"):
+            if cohort_mode:
+                from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
+                    get_cohort_data)
+                cohort_src = fed = get_cohort_data(cfg)
+            else:
+                fed = get_federated_data(cfg)
+        tracer.count("data_bytes_host", sum(
+            int(a.nbytes) for a in (
+                fed.train.images, fed.train.labels, fed.train.sizes,
+                fed.val_images, fed.val_labels, fed.pval_images,
+                fed.pval_labels)))
         if fed.synthetic and cfg.data != "synthetic":
             print(f"[data] {cfg.data} files not found under "
                   f"{cfg.data_dir!r}; using the deterministic synthetic "
                   f"fallback")
 
-        model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
-                          remat=cfg.remat, remat_policy=cfg.remat_policy)
-        params = init_params(model, fed.train.images.shape[2:],
-                             jax.random.PRNGKey(cfg.seed))
-        print(f"[model] {type(model).__name__}: "
-              f"{param_count(params):,} params")
-        norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)
+        with tracer.span("setup/model_init"):
+            model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
+                              remat=cfg.remat,
+                              remat_policy=cfg.remat_policy)
+            params = init_params(model, fed.train.images.shape[2:],
+                                 jax.random.PRNGKey(cfg.seed))
+            print(f"[model] {type(model).__name__}: "
+                  f"{param_count(params):,} params")
+            norm = make_normalizer(fed.mean, fed.std,
+                                   fed.raw_is_normalized)
 
         # single source with the precompile planner
         # (compile_cache.is_host_mode) so banked families always match what
@@ -365,315 +395,349 @@ class RoundEngine:
             # placed ONCE, replicated over the mesh: an uncommitted array
             # sits on device 0, and every dispatch would re-ship the whole
             # dataset stack from it to the other devices
-            arrays = multihost.put_replicated(
-                mesh, (fed.train.images, fed.train.labels,
-                       fed.train.sizes))
-            params = multihost.put_replicated(mesh, params)
+            with tracer.span("setup/place"):
+                arrays = multihost.put_replicated(
+                    mesh, (fed.train.images, fed.train.labels,
+                           fed.train.sizes))
+                params = multihost.put_replicated(mesh, params)
+            tracer.count("data_bytes_placed",
+                         n_mesh * sum(int(a.nbytes) for a in arrays))
             print(f"[mesh] {n_mesh} devices on the `agents` axis "
                   f"({cfg.agents_per_round // n_mesh} agents/device), "
                   f"{jax.process_count()} process(es)")
             print(f"[agg] {multihost.agg_plan_note(cfg, params, mesh)}")
-            round_fn = make_sharded_round_fn(plain_cfg, model, norm, mesh,
-                                             *arrays)
-            diag_round_fn = (make_sharded_round_fn(cfg, model, norm, mesh,
-                                                   *arrays)
-                             if cfg.diagnostics else round_fn)
-            if chain_n > 1:
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                    make_sharded_chained_round_fn)
-                chained_fn = make_sharded_chained_round_fn(
-                    plain_cfg, model, norm, mesh, *arrays)
-        elif cohort_mode:
-            # ----------------------------------------------- cohort mode
-            # population decoupled from cohort (ISSUE 7): the driver
-            # mirrors the seeded in-program cohort draw (data/cohort.py)
-            # to gather only the m sampled clients' rows — from the
-            # memory-mapped client bank, or (churn-aware host mode) from
-            # the dense host stacks — and the round program recomputes
-            # the same ids from the traced round index to derive corrupt
-            # and churn flags per cohort MEMBER. Host/HBM stay O(cohort).
-            m = cfg.agents_per_round
-            if jax.process_count() > 1:
-                raise NotImplementedError(
-                    "cohort-sampled mode is single-process for now — the "
-                    "pod-scale aggregation rework (ROADMAP) will shard "
-                    "the cohort gather across hosts")
-            if cohort_src is not None:
-                print(f"[cohort] population {cfg.num_agents:,} clients -> "
-                      f"{m}-client cohorts ({cfg.partitioner} client "
-                      f"bank, {cohort_src.max_n} rows/cohort member; "
-                      f"in-program sampling, cohort_seed "
-                      f"{cfg.cohort_seed})")
-                gather_rows = cohort_src.gather_cohort
-            else:
-                print(f"[cohort] {cfg.num_agents} clients -> {m}-client "
-                      f"cohorts sampled from the churn-present set over "
-                      f"the host shard stacks")
-
-                def gather_rows(ids):
-                    return (fed.train.images[ids], fed.train.labels[ids],
-                            fed.train.sizes[ids])
-            take = lambda a: jnp.asarray(a)  # noqa: E731
-            take_block = take
-            round_fn_host = None
-            if cfg.mesh != 1:
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
-                    AGENTS_AXIS, make_mesh, pick_agent_mesh_size)
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                    make_sharded_cohort_round_fn)
-                n_mesh = pick_agent_mesh_size(cfg.mesh, m)
-                if n_mesh > 1:
-                    mesh = make_mesh(n_mesh)
-                    print(f"[mesh] {n_mesh} devices on the `agents` axis "
-                          f"({m // n_mesh} cohort members/device), "
-                          f"cohort-sampled")
-                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-                        multihost as mh)
-                    print(f"[agg] {mh.agg_plan_note(cfg, params, mesh)}")
-                    agents_sharding = NamedSharding(mesh, P(AGENTS_AXIS))
-                    block_sharding = NamedSharding(mesh,
-                                                   P(None, AGENTS_AXIS))
-                    take = lambda a: jax.device_put(  # noqa: E731
-                        a, agents_sharding)
-                    take_block = lambda a: jax.device_put(  # noqa: E731
-                        a, block_sharding)
-                    round_fn_host = make_sharded_cohort_round_fn(
-                        plain_cfg, model, norm, mesh)
-                    diag_round_fn_host = (
-                        make_sharded_cohort_round_fn(cfg, model, norm,
-                                                     mesh)
-                        if cfg.diagnostics else round_fn_host)
-            if round_fn_host is None:
-                from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-                    make_cohort_round_fn)
-                round_fn_host = make_cohort_round_fn(plain_cfg, model, norm)
-                diag_round_fn_host = (
-                    make_cohort_round_fn(cfg, model, norm)
-                    if cfg.diagnostics else round_fn_host)
-            if chain_n > 1:
-                # cohort chaining survives faults AND keeps the full-
-                # telemetry cosine split: the scanned round index
-                # re-derives flags in-program (fl/rounds.make_cohort_step)
-                if n_mesh > 1:
+            with tracer.span("setup/build_programs"):
+                round_fn = make_sharded_round_fn(plain_cfg, model, norm,
+                                                 mesh, *arrays)
+                diag_round_fn = (make_sharded_round_fn(cfg, model, norm,
+                                                       mesh, *arrays)
+                                 if cfg.diagnostics else round_fn)
+                if chain_n > 1:
                     from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                        make_sharded_chained_cohort_round_fn)
-                    host_chained_fn = make_sharded_chained_cohort_round_fn(
-                        plain_cfg, model, norm, mesh)
+                        make_sharded_chained_round_fn)
+                    chained_fn = make_sharded_chained_round_fn(
+                        plain_cfg, model, norm, mesh, *arrays)
+        elif cohort_mode:
+            with tracer.span("setup/build_programs"):
+                # ----------------------------------------------- cohort mode
+                # population decoupled from cohort (ISSUE 7): the driver
+                # mirrors the seeded in-program cohort draw (data/cohort.py)
+                # to gather only the m sampled clients' rows — from the
+                # memory-mapped client bank, or (churn-aware host mode) from
+                # the dense host stacks — and the round program recomputes
+                # the same ids from the traced round index to derive corrupt
+                # and churn flags per cohort MEMBER. Host/HBM stay O(cohort).
+                m = cfg.agents_per_round
+                if jax.process_count() > 1:
+                    raise NotImplementedError(
+                        "cohort-sampled mode is single-process for now — "
+                        "the pod-scale aggregation rework (ROADMAP) will "
+                        "shard the cohort gather across hosts")
+                if cohort_src is not None:
+                    print(f"[cohort] population {cfg.num_agents:,} clients -> "
+                          f"{m}-client cohorts ({cfg.partitioner} client "
+                          f"bank, {cohort_src.max_n} rows/cohort member; "
+                          f"in-program sampling, cohort_seed "
+                          f"{cfg.cohort_seed})")
+                    gather_rows = cohort_src.gather_cohort
                 else:
+                    print(f"[cohort] {cfg.num_agents} clients -> {m}-client "
+                          f"cohorts sampled from the churn-present set over "
+                          f"the host shard stacks")
+
+                    def gather_rows(ids):
+                        return (fed.train.images[ids], fed.train.labels[ids],
+                                fed.train.sizes[ids])
+                take = lambda a: jnp.asarray(a)  # noqa: E731
+                take_block = take
+                round_fn_host = None
+                if cfg.mesh != 1:
+                    from jax.sharding import NamedSharding, PartitionSpec as P
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
+                        AGENTS_AXIS, make_mesh, pick_agent_mesh_size)
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
+                        make_sharded_cohort_round_fn)
+                    n_mesh = pick_agent_mesh_size(cfg.mesh, m)
+                    if n_mesh > 1:
+                        mesh = make_mesh(n_mesh)
+                        print(f"[mesh] {n_mesh} devices on the `agents` axis "
+                              f"({m // n_mesh} cohort members/device), "
+                              f"cohort-sampled")
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
+                            multihost as mh)
+                        print(f"[agg] {mh.agg_plan_note(cfg, params, mesh)}")
+                        agents_sharding = NamedSharding(mesh, P(AGENTS_AXIS))
+                        block_sharding = NamedSharding(mesh,
+                                                       P(None, AGENTS_AXIS))
+                        take = lambda a: jax.device_put(  # noqa: E731
+                            a, agents_sharding)
+                        take_block = lambda a: jax.device_put(  # noqa: E731
+                            a, block_sharding)
+                        round_fn_host = make_sharded_cohort_round_fn(
+                            plain_cfg, model, norm, mesh)
+                        diag_round_fn_host = (
+                            make_sharded_cohort_round_fn(cfg, model, norm,
+                                                         mesh)
+                            if cfg.diagnostics else round_fn_host)
+                if round_fn_host is None:
                     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-                        make_chained_cohort_round_fn)
-                    host_chained_fn = make_chained_cohort_round_fn(
-                        plain_cfg, model, norm)
+                        make_cohort_round_fn)
+                    round_fn_host = make_cohort_round_fn(plain_cfg, model,
+                                                         norm)
+                    diag_round_fn_host = (
+                        make_cohort_round_fn(cfg, model, norm)
+                        if cfg.diagnostics else round_fn_host)
+                if chain_n > 1:
+                    # cohort chaining survives faults AND keeps the full-
+                    # telemetry cosine split: the scanned round index
+                    # re-derives flags in-program (fl/rounds.make_cohort_step)
+                    if n_mesh > 1:
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
+                            make_sharded_chained_cohort_round_fn)
+                        host_chained_fn = make_sharded_chained_cohort_round_fn(
+                            plain_cfg, model, norm, mesh)
+                    else:
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
+                            make_chained_cohort_round_fn)
+                        host_chained_fn = make_chained_cohort_round_fn(
+                            plain_cfg, model, norm)
 
-            from defending_against_backdoors_with_robust_learning_rate_tpu.data import (
-                cohort as cohort_mod)
+                from defending_against_backdoors_with_robust_learning_rate_tpu.data import (
+                    cohort as cohort_mod)
 
-            def sample_ids(rnd):
-                # the host mirror of the in-program draw — bit-identical
-                # ids (data/cohort.py), evaluated on the prefetch thread.
-                # static: ok(host-sync)
-                ids, _active = cohort_mod.sample_cohort_host(cfg, rnd)
-                return ids
+                def sample_ids(rnd):
+                    # the host mirror of the in-program draw — bit-identical
+                    # ids (data/cohort.py), evaluated on the prefetch thread.
+                    # static: ok(host-sync)
+                    ids, _active = cohort_mod.sample_cohort_host(cfg, rnd)
+                    return ids
 
-            def gather_unit(unit):
-                """One dispatch unit's cohort payload: a single round's
-                [m, ...] stacks or a chained block's [chain, m, ...]
-                stacks — O(cohort) gather riding the prefetch thread, so
-                bank reads + H2D overlap the running round program."""
-                with tracer.span("prefetch/gather", rounds=len(unit)):
-                    ids = np.stack([sample_ids(r) for r in unit])
-                    if len(unit) == 1:
-                        imgs, lbls, szs = gather_rows(ids[0])
-                        return (ids[0], take(imgs), take(lbls), take(szs))
-                    rows = [gather_rows(i) for i in ids]
-                    return (ids,
-                            take_block(np.stack([r[0] for r in rows])),
-                            take_block(np.stack([r[1] for r in rows])),
-                            take_block(np.stack([r[2] for r in rows])))
+                def gather_unit(unit, enqueued_by=None):
+                    """One dispatch unit's cohort payload: a single round's
+                    [m, ...] stacks or a chained block's [chain, m, ...]
+                    stacks — O(cohort) gather riding the prefetch thread, so
+                    bank reads + H2D overlap the running round program."""
+                    with tracer.span("prefetch/gather",
+                                     parent=enqueued_by and (
+                                         enqueued_by[0], unit[-1]),
+                                     rounds=len(unit)):
+                        ids = np.stack([sample_ids(r) for r in unit])
+                        if len(unit) == 1:
+                            imgs, lbls, szs = gather_rows(ids[0])
+                            return (ids[0], take(imgs), take(lbls), take(szs))
+                        rows = [gather_rows(i) for i in ids]
+                        return (ids,
+                                take_block(np.stack([r[0] for r in rows])),
+                                take_block(np.stack([r[1] for r in rows])),
+                                take_block(np.stack([r[2] for r in rows])))
 
-            if cfg.host_prefetch > 0:
-                print(f"[prefetch] cohort gather pipeline, depth "
-                      f"{cfg.host_prefetch}")
-            get_unit = self._unit_fetcher(gather_unit)
+                if cfg.host_prefetch > 0:
+                    print(f"[prefetch] cohort gather pipeline, depth "
+                          f"{cfg.host_prefetch}")
+                get_unit = self._unit_fetcher(gather_unit)
 
-            def host_sampler(params, key, rnd, want_diag):
-                with tracer.span("round/data_prep", round=rnd):
-                    _ids, imgs, lbls, szs = get_unit((rnd,))
-                fn = diag_round_fn_host if want_diag else round_fn_host
-                with tracer.span("round/dispatch", round=rnd):
-                    # the round index is a traced int32 lead argument —
-                    # the program recomputes the cohort (ids, flags,
-                    # churn mask) from it; `sampled` in the info dict is
-                    # the program's own draw
-                    new_params, info = fn(params, key, jnp.int32(rnd),
-                                          imgs, lbls, szs)
-                return new_params, info
+                def host_sampler(params, key, rnd, want_diag):
+                    with tracer.span("round/data_prep"):
+                        _ids, imgs, lbls, szs = get_unit((rnd,))
+                    fn = diag_round_fn_host if want_diag else round_fn_host
+                    tracer.count("dispatch", family=self._family[
+                        "diag" if want_diag else "round"])
+                    with tracer.span("round/dispatch"):
+                        # the round index is a traced int32 lead argument —
+                        # the program recomputes the cohort (ids, flags,
+                        # churn mask) from it; `sampled` in the info dict is
+                        # the program's own draw
+                        new_params, info = fn(params, key, jnp.int32(rnd),
+                                              imgs, lbls, szs)
+                    return new_params, info
         elif host_mode:
-            print(f"[data] host-sampled mode "
-                  f"({fed.train.images.nbytes / 2**30:.1f} GiB of shards)")
-            # take(base, ids) materializes the round's sampled [m, ...]
-            # stack for this mode: the multi-process variant never gathers
-            # rows this process's devices don't own. take_block is the
-            # chained variant: ids [chain, m] -> [chain, m, ...] block in
-            # one placement.
-            take = lambda a, ids: jnp.asarray(a[ids])  # noqa: E731
-            take_block = take
-            round_fn_host = None
-            if cfg.mesh != 1 and jax.process_count() > 1:
-                # multi-process host-sampled: every process runs the
-                # identical seeded sampling over its (replicated) host
-                # dataset, then materializes only its addressable shards
-                # of the global [m, ...] stacks
-                # (multihost.take_agents_sharded); the shard_mapped round
-                # runs over ONE global agents mesh exactly like the
-                # device-resident multi-host path
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-                    multihost)
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                    make_sharded_round_fn_host)
-                n_mesh = multihost.require_pod_divisible(
-                    cfg.agents_per_round, "multi-host host-sampled")
-                mesh = multihost.global_agents_mesh(0)
-                print(f"[mesh] {n_mesh} global devices on the `agents` "
-                      f"axis ({cfg.agents_per_round // n_mesh} "
-                      f"agents/device), host-sampled shards, "
-                      f"{jax.process_count()} processes")
-                take = lambda a, ids: multihost.take_agents_sharded(  # noqa: E731
-                    mesh, a, ids)
-                take_block = lambda a, ids: \
-                    multihost.take_agents_sharded_block(  # noqa: E731
+            with tracer.span("setup/build_programs"):
+                print(f"[data] host-sampled mode "
+                      f"({fed.train.images.nbytes / 2**30:.1f} GiB of shards)")
+                # take(base, ids) materializes the round's sampled [m, ...]
+                # stack for this mode: the multi-process variant never gathers
+                # rows this process's devices don't own. take_block is the
+                # chained variant: ids [chain, m] -> [chain, m, ...] block in
+                # one placement.
+                take = lambda a, ids: jnp.asarray(a[ids])  # noqa: E731
+                take_block = take
+                round_fn_host = None
+                if cfg.mesh != 1 and jax.process_count() > 1:
+                    # multi-process host-sampled: every process runs the
+                    # identical seeded sampling over its (replicated) host
+                    # dataset, then materializes only its addressable shards
+                    # of the global [m, ...] stacks
+                    # (multihost.take_agents_sharded); the shard_mapped round
+                    # runs over ONE global agents mesh exactly like the
+                    # device-resident multi-host path
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
+                        multihost)
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
+                        make_sharded_round_fn_host)
+                    n_mesh = multihost.require_pod_divisible(
+                        cfg.agents_per_round, "multi-host host-sampled")
+                    mesh = multihost.global_agents_mesh(0)
+                    print(f"[mesh] {n_mesh} global devices on the `agents` "
+                          f"axis ({cfg.agents_per_round // n_mesh} "
+                          f"agents/device), host-sampled shards, "
+                          f"{jax.process_count()} processes")
+                    take = lambda a, ids: multihost.take_agents_sharded(  # noqa: E731
                         mesh, a, ids)
-                params = multihost.put_replicated(mesh, params)
-                round_fn_host = make_sharded_round_fn_host(plain_cfg, model,
-                                                           norm, mesh)
-                diag_round_fn_host = (
-                    make_sharded_round_fn_host(cfg, model, norm, mesh)
-                    if cfg.diagnostics else round_fn_host)
-            elif cfg.mesh != 1:
-                # the m sampled shards gathered each round are fixed-shape
-                # [m, ...] stacks — partition them over the agents mesh
-                # (m/d per device) and run the shard_mapped round body
-                from jax.sharding import NamedSharding, PartitionSpec as P
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
-                    AGENTS_AXIS, make_mesh, pick_agent_mesh_size)
-                from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                    make_sharded_round_fn_host)
-                n_mesh = pick_agent_mesh_size(cfg.mesh,
-                                              cfg.agents_per_round)
-                if n_mesh > 1:
-                    mesh = make_mesh(n_mesh)
-                    print(f"[mesh] {n_mesh} devices on the `agents` axis "
-                          f"({cfg.agents_per_round // n_mesh} "
-                          f"agents/device), host-sampled shards")
-                    agents_sharding = NamedSharding(mesh, P(AGENTS_AXIS))
-                    block_sharding = NamedSharding(mesh,
-                                                   P(None, AGENTS_AXIS))
-                    # device_put on the host array splits host->devices in
-                    # one step (no staging copy through device 0)
-                    take = lambda a, ids: jax.device_put(  # noqa: E731
-                        a[ids], agents_sharding)
-                    take_block = lambda a, ids: jax.device_put(  # noqa: E731
-                        a[ids], block_sharding)
+                    take_block = lambda a, ids: \
+                        multihost.take_agents_sharded_block(  # noqa: E731
+                            mesh, a, ids)
+                    params = multihost.put_replicated(mesh, params)
                     round_fn_host = make_sharded_round_fn_host(
                         plain_cfg, model, norm, mesh)
                     diag_round_fn_host = (
                         make_sharded_round_fn_host(cfg, model, norm, mesh)
                         if cfg.diagnostics else round_fn_host)
-            if round_fn_host is None:
-                round_fn_host = make_round_fn_host(plain_cfg, model, norm)
-                diag_round_fn_host = (make_round_fn_host(cfg, model, norm)
-                                      if cfg.diagnostics else round_fn_host)
-            # one site builds the chained-host variant for whichever round
-            # fn was picked above (sharded single- or multi-process mesh,
-            # or single-device); a multi-process job WITHOUT the global
-            # mesh gets no chaining (it is the redundant-work warning case
-            # below). Host-sampled chaining is also skipped under faults:
-            # the host step then takes per-round corrupt flags the chained
-            # scan doesn't carry (device-resident chaining computes them
-            # in-jit and is unaffected).
-            if chain_n > 1 and (cfg.faults_enabled
-                                or attack_registry.in_jit(cfg)):
-                chain_n = 1
-                tag, why = (("faults", "faults") if cfg.faults_enabled
-                            else ("attack", f"--attack {cfg.attack}"))
-                print(f"[{tag}] host-sampled mode: --chain disabled "
-                      f"({why} needs per-round corrupt flags riding "
-                      f"each dispatch)")
-            if chain_n > 1:
-                if n_mesh > 1:
+                elif cfg.mesh != 1:
+                    # the m sampled shards gathered each round are fixed-shape
+                    # [m, ...] stacks — partition them over the agents mesh
+                    # (m/d per device) and run the shard_mapped round body
+                    from jax.sharding import NamedSharding, PartitionSpec as P
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
+                        AGENTS_AXIS, make_mesh, pick_agent_mesh_size)
                     from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-                        make_sharded_chained_round_fn_host)
-                    host_chained_fn = make_sharded_chained_round_fn_host(
-                        plain_cfg, model, norm, mesh)
-                elif jax.process_count() == 1:
-                    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-                        make_chained_round_fn_host)
-                    host_chained_fn = make_chained_round_fn_host(
-                        plain_cfg, model, norm)
+                        make_sharded_round_fn_host)
+                    n_mesh = pick_agent_mesh_size(cfg.mesh,
+                                                  cfg.agents_per_round)
+                    if n_mesh > 1:
+                        mesh = make_mesh(n_mesh)
+                        print(f"[mesh] {n_mesh} devices on the `agents` axis "
+                              f"({cfg.agents_per_round // n_mesh} "
+                              f"agents/device), host-sampled shards")
+                        agents_sharding = NamedSharding(mesh, P(AGENTS_AXIS))
+                        block_sharding = NamedSharding(mesh,
+                                                       P(None, AGENTS_AXIS))
+                        # device_put on the host array splits host->devices in
+                        # one step (no staging copy through device 0)
+                        take = lambda a, ids: jax.device_put(  # noqa: E731
+                            a[ids], agents_sharding)
+                        take_block = lambda a, ids: jax.device_put(  # noqa: E731
+                            a[ids], block_sharding)
+                        round_fn_host = make_sharded_round_fn_host(
+                            plain_cfg, model, norm, mesh)
+                        diag_round_fn_host = (
+                            make_sharded_round_fn_host(cfg, model, norm, mesh)
+                            if cfg.diagnostics else round_fn_host)
+                if round_fn_host is None:
+                    round_fn_host = make_round_fn_host(plain_cfg, model, norm)
+                    diag_round_fn_host = (make_round_fn_host(cfg, model, norm)
+                                          if cfg.diagnostics else round_fn_host)
+                # one site builds the chained-host variant for whichever round
+                # fn was picked above (sharded single- or multi-process mesh,
+                # or single-device); a multi-process job WITHOUT the global
+                # mesh gets no chaining (it is the redundant-work warning case
+                # below). Host-sampled chaining is also skipped under faults:
+                # the host step then takes per-round corrupt flags the chained
+                # scan doesn't carry (device-resident chaining computes them
+                # in-jit and is unaffected).
+                if chain_n > 1 and (cfg.faults_enabled
+                                    or attack_registry.in_jit(cfg)):
+                    chain_n = 1
+                    tag, why = (("faults", "faults") if cfg.faults_enabled
+                                else ("attack", f"--attack {cfg.attack}"))
+                    print(f"[{tag}] host-sampled mode: --chain disabled "
+                          f"({why} needs per-round corrupt flags riding "
+                          f"each dispatch)")
+                if chain_n > 1:
+                    if n_mesh > 1:
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
+                            make_sharded_chained_round_fn_host)
+                        host_chained_fn = make_sharded_chained_round_fn_host(
+                            plain_cfg, model, norm, mesh)
+                    elif jax.process_count() == 1:
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
+                            make_chained_round_fn_host)
+                        host_chained_fn = make_chained_round_fn_host(
+                            plain_cfg, model, norm)
 
-            def sample_ids(rnd):
-                # per-round generator so --resume continues the same
-                # sampling sequence the uninterrupted run would have used
-                rng = np.random.default_rng(cfg.seed * 100_003 + rnd)
-                return rng.choice(cfg.num_agents, cfg.agents_per_round,
-                                  replace=False)
+                def sample_ids(rnd):
+                    # per-round generator so --resume continues the same
+                    # sampling sequence the uninterrupted run would have used
+                    rng = np.random.default_rng(cfg.seed * 100_003 + rnd)
+                    return rng.choice(cfg.num_agents, cfg.agents_per_round,
+                                      replace=False)
 
-            def gather_unit(unit):
-                """One dispatch unit's payload: a single round's [m, ...]
-                stacks or a chained block's [chain, m, ...] stacks (one
-                placement). The span lands on whichever thread runs the
-                gather — the prefetch worker in pipelined mode, so
-                trace.json shows the overlap."""
-                with tracer.span("prefetch/gather", rounds=len(unit)):
-                    ids = np.stack([sample_ids(r) for r in unit])
-                    if len(unit) == 1:
-                        return (ids[0], take(fed.train.images, ids[0]),
-                                take(fed.train.labels, ids[0]),
-                                take(fed.train.sizes, ids[0]))
-                    return (ids, take_block(fed.train.images, ids),
-                            take_block(fed.train.labels, ids),
-                            take_block(fed.train.sizes, ids))
+                def gather_unit(unit, enqueued_by=None):
+                    """One dispatch unit's payload: a single round's [m, ...]
+                    stacks or a chained block's [chain, m, ...] stacks (one
+                    placement). The span lands on whichever thread runs the
+                    gather — the prefetch worker in pipelined mode, so
+                    trace.json shows the overlap."""
+                    with tracer.span("prefetch/gather",
+                                     parent=enqueued_by and (
+                                         enqueued_by[0], unit[-1]),
+                                     rounds=len(unit)):
+                        ids = np.stack([sample_ids(r) for r in unit])
+                        if len(unit) == 1:
+                            return (ids[0], take(fed.train.images, ids[0]),
+                                    take(fed.train.labels, ids[0]),
+                                    take(fed.train.sizes, ids[0]))
+                        return (ids, take_block(fed.train.images, ids),
+                                take_block(fed.train.labels, ids),
+                                take_block(fed.train.sizes, ids))
 
-            # host gather + H2D transfer overlap the running round program
-            # (data/prefetch.py); created lazily at the first dispatch so
-            # a resumed run prefetches from its restored start round
-            if cfg.host_prefetch > 0:
-                print(f"[prefetch] host->device pipeline, depth "
-                      f"{cfg.host_prefetch}")
+                # host gather + H2D transfer overlap the running round program
+                # (data/prefetch.py); created lazily at the first dispatch so
+                # a resumed run prefetches from its restored start round
+                if cfg.host_prefetch > 0:
+                    print(f"[prefetch] host->device pipeline, depth "
+                          f"{cfg.host_prefetch}")
 
-            get_unit = self._unit_fetcher(gather_unit)
+                get_unit = self._unit_fetcher(gather_unit)
 
-            def host_sampler(params, key, rnd, want_diag):
-                with tracer.span("round/data_prep", round=rnd):
-                    ids, imgs, lbls, szs = get_unit((rnd,))
-                fn = diag_round_fn_host if want_diag else round_fn_host
-                with tracer.span("round/dispatch", round=rnd):
-                    if host_takes_flags(cfg):
-                        # faults: the host-sampled ids determine which
-                        # slots hold malicious agents
-                        # (--faults_spare_corrupt participation); full
-                        # telemetry: the honest/corrupt cosine split needs
-                        # the same flags
-                        flags = jnp.asarray(ids < cfg.num_corrupt)
-                        new_params, info = fn(params, key, imgs, lbls, szs,
-                                              flags)
-                    else:
-                        new_params, info = fn(params, key, imgs, lbls, szs)
-                info["sampled"] = ids
-                return new_params, info
+                def host_sampler(params, key, rnd, want_diag):
+                    with tracer.span("round/data_prep"):
+                        ids, imgs, lbls, szs = get_unit((rnd,))
+                    fn = diag_round_fn_host if want_diag else round_fn_host
+                    tracer.count("dispatch", family=self._family[
+                        "diag" if want_diag else "round"])
+                    with tracer.span("round/dispatch"):
+                        if host_takes_flags(cfg):
+                            # faults: the host-sampled ids determine which
+                            # slots hold malicious agents
+                            # (--faults_spare_corrupt participation); full
+                            # telemetry: the honest/corrupt cosine split needs
+                            # the same flags
+                            flags = jnp.asarray(ids < cfg.num_corrupt)
+                            new_params, info = fn(params, key, imgs, lbls, szs,
+                                                  flags)
+                        else:
+                            new_params, info = fn(params, key, imgs, lbls, szs)
+                    info["sampled"] = ids
+                    return new_params, info
         else:
-            arrays = (jnp.asarray(fed.train.images),
-                      jnp.asarray(fed.train.labels),
-                      jnp.asarray(fed.train.sizes))
-            round_fn = make_round_fn(plain_cfg, model, norm, *arrays)
-            diag_round_fn = (make_round_fn(cfg, model, norm, *arrays)
-                             if cfg.diagnostics else round_fn)
-            if chain_n > 1:
-                from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-                    make_chained_round_fn)
-                chained_fn = make_chained_round_fn(plain_cfg, model, norm,
-                                                   *arrays)
+            with tracer.span("setup/place"):
+                arrays = (jnp.asarray(fed.train.images),
+                          jnp.asarray(fed.train.labels),
+                          jnp.asarray(fed.train.sizes))
+            tracer.count("data_bytes_placed",
+                         sum(int(a.nbytes) for a in arrays))
+            with tracer.span("setup/build_programs"):
+                round_fn = make_round_fn(plain_cfg, model, norm, *arrays)
+                diag_round_fn = (make_round_fn(cfg, model, norm, *arrays)
+                                 if cfg.diagnostics else round_fn)
+                if chain_n > 1:
+                    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
+                        make_chained_round_fn)
+                    chained_fn = make_chained_round_fn(plain_cfg, model,
+                                                       norm, *arrays)
+        # the families' names, for the bank and the `dispatch` counter
+        # (an adopted executable no longer carries its own)
+        if host_sampler is None:
+            self._family = {
+                k: fn.family for k, fn in (
+                    ("round", round_fn), ("diag", diag_round_fn),
+                    ("chained", chained_fn)) if fn is not None}
+        else:
+            kind = "cohort" if cohort_mode else "host"
+            sfx = compile_cache.family_suffix(cfg)
+            self._family = {"round": f"round_{kind}{sfx}",
+                            "diag": f"round_{kind}_diag",
+                            "chained": f"chained_{kind}{sfx}"}
         if chained_fn is not None or host_chained_fn is not None:
             print(f"[chain] {chain_n} rounds per compiled dispatch "
                   f"(lax.scan"
@@ -770,22 +834,27 @@ class RoundEngine:
                       f"noise=0; aggr={cfg.aggr!r} noise={cfg.noise} falls "
                       f"back to the jnp path")
 
-        eval_fn = make_eval_fn(model, norm, cfg.n_classes)
-        self._fisher_fn = None
-        if cfg.diagnostics:
-            from defending_against_backdoors_with_robust_learning_rate_tpu.fl.diagnostics import (
-                make_fisher_fn)
-            self._fisher_fn = make_fisher_fn(model, norm)
-        val = tuple(map(jnp.asarray, pad_eval_set(
-            fed.val_images, fed.val_labels, cfg.eval_bs)))
-        pval = tuple(map(jnp.asarray, pad_eval_set(
-            fed.pval_images, fed.pval_labels, cfg.eval_bs)))
+        with tracer.span("setup/build_programs"):
+            eval_fn = make_eval_fn(model, norm, cfg.n_classes)
+            self._fisher_fn = None
+            if cfg.diagnostics:
+                from defending_against_backdoors_with_robust_learning_rate_tpu.fl.diagnostics import (
+                    make_fisher_fn)
+                self._fisher_fn = make_fisher_fn(model, norm)
+        with tracer.span("setup/place"):
+            val = tuple(map(jnp.asarray, pad_eval_set(
+                fed.val_images, fed.val_labels, cfg.eval_bs)))
+            pval = tuple(map(jnp.asarray, pad_eval_set(
+                fed.pval_images, fed.pval_labels, cfg.eval_bs)))
+        tracer.count("data_bytes_placed",
+                     sum(int(a.nbytes) for a in val + pval))
 
         if writer is None:
-            writer = (MetricsWriter(cfg.log_dir, run_name(cfg),
-                                    cfg.tensorboard,
-                                    start_fields={"device": self.device})
-                      if lead else NullWriter())
+            with tracer.span("setup/obs"):
+                writer = (MetricsWriter(cfg.log_dir, run_name(cfg),
+                                        cfg.tensorboard,
+                                        start_fields={"device": self.device})
+                          if lead else NullWriter())
         self.writer = writer
 
         base_key = jax.random.PRNGKey(cfg.seed)
@@ -811,36 +880,37 @@ class RoundEngine:
                   f"top-{cfg.rep_topk} heavy-hitter ledger "
                   f"(O(cohort + k) RSS)")
         if cfg.resume and cfg.checkpoint_dir:
-            restored = ckpt.restore(
-                cfg.checkpoint_dir, params, upto=self._resume_upto,
-                upto_validated=self._resume_upto is not None)
-            if restored is not None:
-                (start_round, params, base_key, cum_poison_acc,
-                 self.cum_net_mov) = restored
-                if jax.process_count() > 1 and n_mesh > 1:
-                    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-                        multihost)
-                    params = multihost.put_replicated(mesh, params)
-                else:
-                    params = jax.device_put(params)
-                # the health-EMA baseline rides the round journal
-                # (save_checkpoint writes it): restoring it is what keeps
-                # replayed Health/Loss_Z rows byte-identical across a
-                # crash-exact resume
-                for entry in ckpt.journal_read(cfg.checkpoint_dir):
-                    if entry["round"] == start_round:
-                        health_ema = entry.get("health") or None
-                        # the suspicion ledger rides the same journal
-                        # entry; restoring it is what keeps replayed
-                        # Reputation/* rows byte-identical
-                        if self._rep_tracker is not None:
-                            self._rep_tracker.load_state(
-                                entry.get("reputation") or None)
-                print(f"[ckpt] resumed from round {start_round}")
-                # a per-life record (obs/events.PER_LIFE_PREFIXES): each
-                # process/segment that restores emits its own — a no-op
-                # outside the service plane (no ledger installed)
-                obs_events.emit("checkpoint/restore", round=start_round)
+            with tracer.span("setup/restore"):
+                restored = ckpt.restore(
+                    cfg.checkpoint_dir, params, upto=self._resume_upto,
+                    upto_validated=self._resume_upto is not None)
+                if restored is not None:
+                    (start_round, params, base_key, cum_poison_acc,
+                     self.cum_net_mov) = restored
+                    if jax.process_count() > 1 and n_mesh > 1:
+                        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
+                            multihost)
+                        params = multihost.put_replicated(mesh, params)
+                    else:
+                        params = jax.device_put(params)
+                    # the health-EMA baseline rides the round journal
+                    # (save_checkpoint writes it): restoring it is what keeps
+                    # replayed Health/Loss_Z rows byte-identical across a
+                    # crash-exact resume
+                    for entry in ckpt.journal_read(cfg.checkpoint_dir):
+                        if entry["round"] == start_round:
+                            health_ema = entry.get("health") or None
+                            # the suspicion ledger rides the same journal
+                            # entry; restoring it is what keeps replayed
+                            # Reputation/* rows byte-identical
+                            if self._rep_tracker is not None:
+                                self._rep_tracker.load_state(
+                                    entry.get("reputation") or None)
+                    print(f"[ckpt] resumed from round {start_round}")
+                    # a per-life record (obs/events.PER_LIFE_PREFIXES): each
+                    # process/segment that restores emits its own — a no-op
+                    # outside the service plane (no ledger installed)
+                    obs_events.emit("checkpoint/restore", round=start_round)
 
         # --- AOT adoption: swap jitted program families for banked
         # serialized executables (utils/compile_cache.py). A warm start
@@ -882,17 +952,14 @@ class RoundEngine:
                     jax.ShapeDtypeStruct((m,) + a.shape[1:], a.dtype)
                     for a in (fed.train.images, fed.train.labels,
                               fed.train.sizes))
-                sfx = compile_cache.family_suffix(cfg)
+                fams = tuple(self._family[k]
+                             for k in ("round", "diag", "chained"))
                 if cohort_mode:
-                    fams = ("round_cohort" + sfx, "round_cohort_diag",
-                            "chained_cohort" + sfx)
                     round_avals = (
                         (p_aval, k_aval,
                          jax.ShapeDtypeStruct((), jnp.int32))
                         + shard_avals)
                 else:
-                    fams = ("round_host" + sfx, "round_host_diag",
-                            "chained_host" + sfx)
                     flag_avals = ((jax.ShapeDtypeStruct((m,), jnp.bool_),)
                                   if host_takes_flags(cfg) else ())
                     round_avals = ((p_aval, k_aval) + shard_avals
@@ -949,51 +1016,51 @@ class RoundEngine:
             if fn is not None:
                 eval_pval_fn = fn
 
-        # sampled device-trace window (--profile_rounds N,
-        # obs/attribution.py): opens at the first STEADY dispatch unit
-        # (never the compile unit), closes after N rounds, and is parsed
-        # into Device/* + Memory/* attribution rows after the loop. A bare
-        # --profile_dir (without --profile_rounds) keeps its historical
-        # whole-run trace semantics.
-        self.prof = None
-        if cfg.profile_rounds > 0 and lead:
-            run_dir_hint = getattr(writer, "dir", None) or cfg.log_dir
-            self.prof = obs_attribution.RoundProfiler(
-                cfg.profile_rounds,
-                cfg.profile_dir or os.path.join(run_dir_hint, "profile"))
-        self._whole_run_trace = bool(cfg.profile_dir and lead
-                                     and self.prof is None)
-        if self._whole_run_trace:
-            jax.profiler.start_trace(cfg.profile_dir)
+        with tracer.span("setup/obs"):
+            # sampled device-trace window (--profile_rounds N,
+            # obs/attribution.py): opens at the first STEADY dispatch unit
+            # (never the compile unit), closes after N rounds, and is parsed
+            # into Device/* + Memory/* attribution rows after the loop. A bare
+            # --profile_dir (without --profile_rounds) keeps its historical
+            # whole-run trace semantics.
+            self.prof = None
+            if cfg.profile_rounds > 0 and lead:
+                run_dir_hint = getattr(writer, "dir", None) or cfg.log_dir
+                self.prof = obs_attribution.RoundProfiler(
+                    cfg.profile_rounds,
+                    cfg.profile_dir or os.path.join(run_dir_hint, "profile"))
+            self._whole_run_trace = bool(cfg.profile_dir and lead
+                                         and self.prof is None)
+            if self._whole_run_trace:
+                jax.profiler.start_trace(cfg.profile_dir)
 
-        # incident flight recorder (obs/flight.py): a bounded per-round
-        # ring + crash-exact flight.jsonl next to metrics.jsonl, lead
-        # process only. Span durations ride the tracer's completion
-        # hook, chained after the heartbeat's — no extra timing calls
-        # on the hot path.
-        self.flight = None
-        if cfg.flight == "on" and lead:
-            flight_dir = getattr(writer, "dir", None) or cfg.log_dir
-            flight_run = run_name(cfg)
-            self.flight = obs_flight.FlightRecorder(
-                os.path.join(flight_dir, obs_flight.STREAM_NAME),
-                run=flight_run, corr=obs_events.corr_id(flight_run),
-                slot=f"p{jax.process_index()}"
-                     + (f"-E{cfg.tenants}" if cfg.tenants > 0 else ""))
-            tracer.chain_on_end(self.flight.observe_span)
+            # incident flight recorder (obs/flight.py): a bounded per-round
+            # ring + crash-exact flight.jsonl next to metrics.jsonl, lead
+            # process only. A unit's span durations are the tracer's own
+            # per-unit group — no extra timing calls on the hot path.
+            self.flight = None
+            if cfg.flight == "on" and lead:
+                flight_dir = getattr(writer, "dir", None) or cfg.log_dir
+                flight_run = run_name(cfg)
+                self.flight = obs_flight.FlightRecorder(
+                    os.path.join(flight_dir, obs_flight.STREAM_NAME),
+                    run=flight_run, corr=obs_events.corr_id(flight_run),
+                    slot=f"p{jax.process_index()}"
+                         + (f"-E{cfg.tenants}" if cfg.tenants > 0 else ""),
+                    span_source=tracer.unit_ms if tracer.enabled else None)
 
-        # --- async metrics pipeline: per-round/eval scalars stay on device
-        # and drain through a background thread's batched device_get, so
-        # the round loop never blocks on a host sync (~24% of round time on
-        # the small CNN, r3 flagship ladder). Diagnostics and --debug_nan
-        # need inline host values; multi-process jobs keep the lead-only
-        # writer synchronous.
-        use_async = (cfg.async_metrics and not cfg.debug_nan
-                     and not cfg.diagnostics and jax.process_count() == 1)
-        self.drain = MetricsDrain(tracer=tracer) if use_async else None
-        if self.drain is not None:
-            print("[metrics] async drain: host syncs ride a background "
-                  "thread (--sync_metrics restores the inline path)")
+            # --- async metrics pipeline: per-round/eval scalars stay on device
+            # and drain through a background thread's batched device_get, so
+            # the round loop never blocks on a host sync (~24% of round time on
+            # the small CNN, r3 flagship ladder). Diagnostics and --debug_nan
+            # need inline host values; multi-process jobs keep the lead-only
+            # writer synchronous.
+            use_async = (cfg.async_metrics and not cfg.debug_nan
+                         and not cfg.diagnostics and jax.process_count() == 1)
+            self.drain = MetricsDrain(tracer=tracer) if use_async else None
+            if self.drain is not None:
+                print("[metrics] async drain: host syncs ride a background "
+                      "thread (--sync_metrics restores the inline path)")
         # steady-state clock (VERDICT r1 #9): stamped in emit_eval, i.e.
         # when a boundary's values ARRIVE (post-execution) — in async mode
         # the dispatch timestamps would measure queueing, not compute
@@ -1083,6 +1150,13 @@ class RoundEngine:
         dispatch) keeps the historical derivation bit-for-bit. Chained
         blocks never take a nonce (the service driver, the only ladder
         host, dispatches unchained)."""
+        # every span from here to the unit's post_unit (and what the
+        # drain thread does for its boundary) carries the unit's last round
+        self.tracer.set_unit(unit[-1])
+        with self.tracer.span("engine/dispatch"):
+            self._dispatch(unit, nonce)
+
+    def _dispatch(self, unit, nonce: int) -> None:
         cfg, tracer = self.cfg, self.tracer
         self.hb.update(phase="train", round=unit[-1])
         if self.flight is not None:
@@ -1094,12 +1168,12 @@ class RoundEngine:
             self.prof.maybe_start()
         if len(unit) > 1:
             # chained block: fixed length => one compilation per shape
-            with tracer.span("round/data_prep", round=unit[-1]):
+            with tracer.span("round/data_prep"):
                 ids = jnp.arange(unit[0], unit[-1] + 1)
                 payload = (None if self._chained_fn is not None
                            else self._get_unit(unit))
-            with tracer.span("round/dispatch", round=unit[-1],
-                             chain=len(unit)):
+            with tracer.span("round/dispatch", chain=len(unit)):
+                tracer.count("dispatch", family=self._family["chained"])
                 if self._chained_fn is not None:
                     self.params, stacked = self._chained_fn(
                         self.params, self.base_key, ids)
@@ -1133,7 +1207,7 @@ class RoundEngine:
             self._want_diag, self._prev_params = False, None
         else:
             rnd = unit[0]
-            with tracer.span("round/data_prep", round=rnd):
+            with tracer.span("round/data_prep"):
                 key = jax.random.fold_in(self.base_key, rnd)
                 if nonce:
                     key = jax.random.fold_in(
@@ -1147,9 +1221,11 @@ class RoundEngine:
                 self.params, info = self._host_sampler(
                     self.params, key, rnd, self._want_diag)
             else:
-                with tracer.span("round/dispatch", round=rnd):
+                with tracer.span("round/dispatch"):
                     fn = (self._diag_round_fn if self._want_diag
                           else self._round_fn)
+                    tracer.count("dispatch", family=self._family[
+                        "diag" if self._want_diag else "round"])
                     self.params, info = fn(self.params, key,
                                            *self._round_lead(rnd))
             self.rnd = rnd
@@ -1187,9 +1263,12 @@ class RoundEngine:
                 if self._prefetcher is None:
                     from defending_against_backdoors_with_robust_learning_rate_tpu.data.prefetch import (
                         RoundPrefetcher)
+                    # the whole schedule is enqueued here, once: the
+                    # worker's gathers name the span open now as parent
+                    enqueued_by = self.tracer.handoff()
                     self._prefetcher = RoundPrefetcher(
-                        gather_unit, self._sched_units,
-                        depth=cfg.host_prefetch)
+                        lambda u: gather_unit(u, enqueued_by),
+                        self._sched_units, depth=cfg.host_prefetch)
                 return self._prefetcher.get(unit)
             return gather_unit(unit)
 
@@ -1237,25 +1316,31 @@ class RoundEngine:
         """One eval boundary: dispatch the two eval programs on the
         (un-donated) params and route the values through the async drain
         (or emit inline in sync mode)."""
+        with self.tracer.span("engine/eval_boundary"):
+            self._eval_boundary(rnd)
+
+    def _eval_boundary(self, rnd: int) -> None:
         cfg, tracer, info = self.cfg, self.tracer, self._last_info
         # HBM watermarks ride the heartbeat so the session stall detectors
         # see memory pressure, not just phase ({} on backends without
         # allocator stats)
-        mem = obs_attribution.memory_watermarks()
+        with tracer.span("obs/memory_poll"):
+            mem = obs_attribution.memory_watermarks()
         self.hb.update(phase="eval", round=rnd, **mem)
         if self.flight is not None and mem:
             self.flight.note(**mem)
         # divergence aborts only under --debug_nan (sync mode); otherwise
         # the finite check rides the drain and warns, and the run keeps
         # recording its (NaN) metrics
-        vals = {"finite": all_finite_device(self.params)}
+        with tracer.span("eval/finite_dispatch"):
+            vals = {"finite": all_finite_device(self.params)}
         # eval dispatches on the (un-donated) params BEFORE the next
         # dispatch unit runs: in async mode round r's eval executes
         # overlapped with the round r+1 training block
-        with tracer.span("eval/val_dispatch", round=rnd):
+        with tracer.span("eval/val_dispatch"):
             val_loss_d, val_acc_d, per_class_d = self._eval_val_fn(
                 self.model_params, *self.val)
-        with tracer.span("eval/poison_dispatch", round=rnd):
+        with tracer.span("eval/poison_dispatch"):
             poison_loss_d, poison_acc_d, _ = self._eval_pval_fn(
                 self.model_params, *self.pval)
         vals.update(val_loss=val_loss_d, val_acc=val_acc_d,
@@ -1289,9 +1374,9 @@ class RoundEngine:
         if self.drain is not None:
             elapsed = time.perf_counter() - self.t_loop
             self.drain.submit(self._emit_eval, vals, rnd, self.rounds_done,
-                              elapsed)
+                              elapsed, tracer.handoff())
         else:
-            with tracer.span("metrics/host_sync", round=rnd):
+            with tracer.span("metrics/host_sync"):
                 # this IS the --sync_metrics fallback path; async mode
                 # routes the same fetch through the MetricsDrain instead.
                 # static: ok(host-sync)
@@ -1299,14 +1384,16 @@ class RoundEngine:
             elapsed = time.perf_counter() - self.t_loop
             self._emit_eval(vals, rnd, self.rounds_done, elapsed)
 
-    def _emit_eval(self, vals, ernd, rounds_done_now, elapsed):
+    def _emit_eval(self, vals, ernd, rounds_done_now, elapsed,
+                   enqueued_by=None):
         """One eval boundary's host side-effects, in the exact synchronous
         order. Sync mode calls it inline with fetched values; async mode
         runs it on the drain thread — one code path, so metrics.jsonl is
         bit-identical between the modes (tests/test_async_metrics.py).
         The cumulative poison mean accumulates HERE in host float64,
-        matching the synchronous semantics exactly."""
-        with self.tracer.span("metrics/emit", round=ernd):
+        matching the synchronous semantics exactly. `enqueued_by` is the
+        boundary's span, handed over with the work in async mode."""
+        with self.tracer.span("metrics/emit", parent=enqueued_by):
             self._emit_eval_body(vals, ernd, rounds_done_now, elapsed)
 
     def _emit_eval_body(self, vals, ernd, rounds_done_now, elapsed):
@@ -1464,7 +1551,7 @@ class RoundEngine:
     def drain_flush(self, timeout: Optional[float] = None) -> None:
         """Surface queued metrics (and any drain-thread error) now."""
         if self.drain is not None:
-            with self.tracer.span("drain/wait", round=self.rnd):
+            with self.tracer.span("drain/wait"):
                 self.drain.flush(timeout=timeout)
 
     def save_checkpoint(self, rnd: int, journal: bool = True,
@@ -1482,7 +1569,7 @@ class RoundEngine:
             return
         self.drain_flush(timeout=drain_timeout)
         self.hb.update(phase="checkpoint", round=rnd)
-        with self.tracer.span("ckpt/save", round=rnd):
+        with self.tracer.span("ckpt/save"):
             # -1 = auto: keep everything in the one-shot trainer (historic
             # behavior); serve() replaces it with its bounded default
             keep = max(cfg.service_keep_ckpts, 0)
@@ -1515,6 +1602,10 @@ class RoundEngine:
         """End-of-unit bookkeeping: flip the compile flag after the first
         unit (from here a silent heartbeat means a stall, not XLA working),
         close the flight record and flush the writer in sync mode."""
+        with self.tracer.span("engine/post_unit"):
+            self._post_unit()
+
+    def _post_unit(self) -> None:
         if self.first_unit:
             self.first_unit = False
             self.hb.update(compile_in_flight=False, force=True)
@@ -1545,6 +1636,9 @@ class RoundEngine:
             # stream handle only — the ring stays live so the driver can
             # still snapshot a post-teardown incident (recovery re-entry)
             self.flight.close()
+        # the compile listener only: the records stay for finalize() and
+        # for readers that come after (obs/spans.current())
+        self.tracer.close()
 
     def finalize(self) -> Dict:
         """Post-loop summary: throughput, attribution, memory watermarks,

@@ -552,13 +552,23 @@ def adopt(bank, cfg, family, jit_obj, example_args):
     could not be banked must not read like one that was."""
     if bank is None:
         return None, 0.0
+    from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
+        spans)
     try:
-        compiled, hit, secs, entry = bank.get_or_compile(
-            family, cfg, jit_obj, example_args)
+        with spans.span(spans.ADOPT_PREFIX + family) as sp:
+            compiled, hit, secs, entry = bank.get_or_compile(
+                family, cfg, jit_obj, example_args)
     except Exception as e:
         print(f"[aot] {family}: falling back to jit "
               f"({type(e).__name__}: {e})")
         return None, 0.0
+    # a bank miss compiles through XLA's persistent cache where the backend
+    # allows it: the tracer's compile listener saw which it was
+    served = sp is not None and any(src == "xla_cache_hit"
+                                    for _p, src, _s in sp.acquired)
+    spans.count(spans.PROGRAMS_COUNTER, family=family,
+                source=("bank_hit" if hit else
+                        "xla_cache_hit" if served else "compiled"))
     if hit:
         how = "loaded from cache"
     elif "unserializable" in entry:

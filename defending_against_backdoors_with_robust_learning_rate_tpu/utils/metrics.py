@@ -184,7 +184,8 @@ class MetricsDrain:
         self._dead = False      # drain thread exited on error: reject work
         self._thread = None
         # optional obs.spans.SpanTracer: attributes the batched device_get
-        # (the host sync this pipeline hides) on the drain thread's track
+        # (the host sync this pipeline hides) on the drain thread's track,
+        # as a child of the span that submitted the batch's first item
         self._tracer = tracer
 
     @property
@@ -227,7 +228,9 @@ class MetricsDrain:
                 self._thread = threading.Thread(
                     target=self._loop, name="metrics-drain", daemon=True)
                 self._thread.start()
-            self._items.append((fn, device_vals, host_args))
+            parent = (self._tracer.handoff()
+                      if self._tracer is not None else None)
+            self._items.append((fn, device_vals, host_args, parent))
             self._pending += 1
             self._cond.notify_all()
 
@@ -246,11 +249,13 @@ class MetricsDrain:
                 # batch's device scalars come back in a single device_get
                 if self._tracer is not None:
                     with self._tracer.span("drain/device_get",
+                                           parent=batch[0][3],
                                            batch=len(batch)):
-                        fetched = jax.device_get([d for _, d, _ in batch])
+                        fetched = jax.device_get([b[1] for b in batch])
                 else:
-                    fetched = jax.device_get([d for _, d, _ in batch])
-                for (fn, _, host_args), vals in zip(batch, fetched, strict=True):
+                    fetched = jax.device_get([b[1] for b in batch])
+                for (fn, _, host_args, _), vals in zip(batch, fetched,
+                                                       strict=True):
                     fn(vals, *host_args)
             except BaseException as e:  # noqa: BLE001 — re-raised at flush
                 with self._cond:

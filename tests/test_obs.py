@@ -15,7 +15,8 @@ import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.config import Config
 from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
-    Heartbeat, SpanTracer, heartbeat as hb_mod, telemetry)
+    Heartbeat, SpanTracer, heartbeat as hb_mod, spans as spans_mod,
+    telemetry)
 from defending_against_backdoors_with_robust_learning_rate_tpu.obs.spans import (
     _percentile)
 
@@ -25,8 +26,8 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # --- spans ---------------------------------------------------------------
 
 class FakeClock:
-    def __init__(self):
-        self.t = 100.0
+    def __init__(self, t=100.0):
+        self.t = t
 
     def __call__(self):
         return self.t
@@ -59,8 +60,147 @@ def test_span_nesting_and_exactness(tmp_path):
     assert ev["outer"]["ts"] <= ev["inner"]["ts"]
     assert (ev["inner"]["ts"] + ev["inner"]["dur"]
             <= ev["outer"]["ts"] + ev["outer"]["dur"] + 1e-6)
-    assert ev["inner"]["args"]["depth"] == 1
+    # the new fields ride each event's args
+    assert ev["inner"]["args"]["parent"] == ev["outer"]["args"]["id"]
+    assert ev["outer"]["args"]["parent"] is None
+    assert ev["inner"]["args"]["unit"] == spans_mod.SETUP_UNIT
+    assert ev["outer"]["args"]["self_ms"] == pytest.approx(1500.0)
     assert doc["displayTimeUnit"] == "ms"
+
+
+def test_span_parent_unit_self_and_cpu_exact():
+    """Nested and sibling spans under injected clocks: ids, parents, the
+    unit, self time (duration less same-thread children) and CPU time."""
+    clock, cpu = FakeClock(), FakeClock(7.0)
+    tr = SpanTracer(clock=clock, cpu_clock=cpu, annotate=False)
+    with tr.span("setup_span"):
+        clock.t += 0.125
+    tr.set_unit(7)
+    with tr.span("outer", note="x"):
+        clock.t += 1.0
+        cpu.t += 0.5
+        with tr.span("a"):
+            clock.t += 0.25
+            cpu.t += 0.25
+            with tr.span("leaf"):
+                clock.t += 0.125
+        with tr.span("b"):          # sibling of a
+            clock.t += 0.5          # blocked: no CPU time
+        clock.t += 0.25
+    by = {s.name: s for s in tr.records()}
+    assert len({s.id for s in by.values()}) == 5
+    assert by["setup_span"].unit == spans_mod.SETUP_UNIT
+    assert by["setup_span"].parent is None and by["outer"].parent is None
+    assert by["a"].parent == by["outer"].id == by["b"].parent
+    assert by["leaf"].parent == by["a"].id
+    assert {by[n].unit for n in ("outer", "a", "b", "leaf")} == {7}
+    assert by["outer"].start == pytest.approx(100.125)   # absolute clock
+    assert by["outer"].end - by["outer"].start == pytest.approx(2.125)
+    assert by["outer"].self_s == pytest.approx(1.25)     # less a and b
+    assert by["a"].self_s == pytest.approx(0.25)         # less leaf
+    assert by["b"].self_s == pytest.approx(0.5)
+    assert by["outer"].cpu_s == pytest.approx(0.75)
+    assert by["a"].cpu_s == pytest.approx(0.25)
+    assert by["b"].cpu_s == 0.0
+    assert by["outer"].args == {"note": "x"}
+    agg = tr.aggregates()
+    assert agg["outer"]["self_ms"] == pytest.approx(1250.0)
+    assert agg["outer"]["cpu_ms"] == pytest.approx(750.0)
+    assert agg["leaf"]["self_ms"] == pytest.approx(125.0)
+    rows = dict(tr.scalar_rows())
+    assert rows["Spans/outer/self_ms"] == pytest.approx(1250.0)
+    assert rows["Spans/b/cpu_ms"] == 0.0
+    # the flight recorder's view: milliseconds by name since the last take
+    assert tr.unit_ms(take=False)["a"] == pytest.approx(375.0)
+    assert tr.unit_ms()["outer"] == pytest.approx(2125.0)
+    assert tr.unit_ms() == {}
+
+
+def test_span_handed_to_another_thread_names_its_enqueuer():
+    tr = SpanTracer(annotate=False)
+    tr.set_unit(3)
+    handed = {}
+
+    def worker(parent):
+        assert tr.handoff() is None          # nothing open on this thread
+        with tr.span("metrics/emit", parent=parent):
+            with tr.span("inner"):
+                pass
+
+    with tr.span("engine/eval_boundary"):
+        handed["link"] = tr.handoff()
+    tr.set_unit(4)                           # the loop has moved on
+    t = threading.Thread(target=worker, args=(handed["link"],))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    by = {s.name: s for s in tr.records()}
+    assert by["metrics/emit"].parent == by["engine/eval_boundary"].id
+    assert by["metrics/emit"].unit == 3 and by["inner"].unit == 3
+    assert by["inner"].parent == by["metrics/emit"].id
+    assert by["metrics/emit"].tid != by["engine/eval_boundary"].tid
+    # a handed-over child ran elsewhere: it covers none of its parent
+    assert by["engine/eval_boundary"].self_s == pytest.approx(
+        by["engine/eval_boundary"].end - by["engine/eval_boundary"].start)
+
+
+def test_counters_with_labels():
+    clock = FakeClock()
+    tr = SpanTracer(clock=clock, annotate=False)
+    tr.count("programs", family="round", source="compiled")
+    clock.t += 1.0
+    tr.count("programs", family="round", source="compiled")
+    tr.count("programs", family="eval_val", source="bank_hit")
+    tr.count("data_bytes_host", 4096)
+    agg = tr.aggregates()
+    assert agg["programs{family=round,source=compiled}"] == {"count": 2}
+    assert agg["programs{family=eval_val,source=bank_hit}"] == {"count": 1}
+    assert agg["data_bytes_host"] == {"count": 4096}
+    rows = dict(tr.scalar_rows())
+    assert rows["Spans/data_bytes_host/count"] == 4096.0
+    assert "Spans/data_bytes_host/p50_ms" not in rows
+    # cut by the caller's own stamp on the tracer's clock
+    assert tr.counted(before=100.5) == [
+        ("programs", 1, {"family": "round", "source": "compiled"})]
+    assert len(tr.counted()) == 4
+
+
+def test_obs_span_fires_no_completion_hook():
+    seen = []
+    tr = SpanTracer(annotate=False, on_end=lambda n, d: seen.append(n))
+    with tr.span("round/dispatch"):
+        with tr.span("obs/heartbeat_write"):
+            pass
+    assert seen == ["round/dispatch"]
+    assert {s.name for s in tr.records()} == {"round/dispatch",
+                                              "obs/heartbeat_write"}
+
+
+def test_compile_listener_records_acquisitions():
+    """Every program the backend acquires becomes an `xla/acquire` span
+    under the span open on that thread, and a `programs` count, except
+    inside `setup/acquire/<family>`, which counts its own family."""
+    tr = SpanTracer(annotate=False)
+    x = jnp.ones((3,))          # its own small programs: before the watch
+    tr.watch_compiles()
+    try:
+        with tr.span("round/dispatch") as sp:
+            jax.jit(lambda x: x * 3 + 1)(x).block_until_ready()
+        with tr.span(spans_mod.ADOPT_PREFIX + "fam"):
+            jax.jit(lambda x: x * 5 - 2)(x).block_until_ready()
+    finally:
+        tr.close()
+    jax.jit(lambda x: x * 7 + 4)(x).block_until_ready()   # not watched
+    acq = [s for s in tr.records() if s.name == spans_mod.ACQUIRE_SPAN]
+    by_id = {s.id: s for s in tr.records()}
+    assert [by_id[s.parent].name for s in acq] == [
+        "round/dispatch", spans_mod.ADOPT_PREFIX + "fam"]
+    assert all(s.args["source"] in ("compiled", "xla_cache_hit")
+               and s.args["program"] for s in acq)
+    assert [src for _p, src, _s in sp.acquired] == [acq[0].args["source"]]
+    counted = [lab for n, _c, lab in tr.counted() if n == "programs"]
+    assert counted == [{"family": acq[0].args["program"],
+                        "source": acq[0].args["source"]}]
 
 
 def test_span_aggregates_percentiles():
@@ -82,10 +222,24 @@ def test_span_aggregates_percentiles():
 
 
 def test_disabled_tracer_is_noop(tmp_path):
+    """One attribute check and nothing else: no record, no counter, no
+    compile listener, no hand-over, and never the process's current."""
     tr = SpanTracer(enabled=False)
-    with tr.span("never"):
-        pass
-    assert tr.aggregates() == {} and tr.span_names() == []
+    with tr.span("never") as sp:
+        assert sp is None and tr.handoff() is None
+    tr.count("never")
+    tr.set_unit(5)
+    tr.watch_compiles()
+    assert tr.aggregates() == {} and tr.records() == []
+    assert tr.unit_ms() == {} and not tr._watching
+    spans_mod.set_current(tr)
+    try:
+        assert spans_mod.current() is None
+        with spans_mod.span("never") as sp:     # module level: no-ops
+            assert sp is None
+        spans_mod.count("never")
+    finally:
+        spans_mod.set_current(None)
     assert tr.write_trace(str(tmp_path / "t.json")) is None
     assert not (tmp_path / "t.json").exists()
 
@@ -351,12 +505,94 @@ def test_driver_smoke_full_observability(tmp_path):
     assert {"round/dispatch", "eval/val_dispatch",
             "eval/poison_dispatch", "metrics/emit"} <= names
     assert summary["spans"]["round/dispatch"]["count"] == cfg.rounds
+    # the engine's own spans: every public call is a parent of what it
+    # does, set-up is split under engine/build, the observers' writes
+    # are inside spans, and each span names its unit
+    ev = doc["traceEvents"]
+    by_id = {e["args"]["id"]: e for e in ev}
+
+    def parent_name(e):
+        up = by_id.get(e["args"]["parent"])
+        return up["name"] if up else None
+
+    assert {"engine/build", "engine/dispatch", "engine/eval_boundary",
+            "engine/post_unit", "setup/data", "setup/model_init",
+            "setup/place", "setup/build_programs", "setup/obs",
+            "setup/acquire/eval_val", "obs/heartbeat_write",
+            "obs/flight_write", "obs/memory_poll"} <= names
+    for e in ev:
+        want = {"round/dispatch": "engine/dispatch",
+                "round/data_prep": "engine/dispatch",
+                "eval/val_dispatch": "engine/eval_boundary",
+                "metrics/emit": "engine/eval_boundary",
+                "setup/data": "engine/build",
+                "setup/data/poison": "setup/data",
+                "obs/flight_write": "engine/post_unit"}.get(e["name"])
+        if want:
+            assert parent_name(e) == want, e
+        if e["name"].startswith(("setup/", "engine/build")):
+            assert e["args"]["unit"] == "setup"
+    assert sorted(e["args"]["unit"] for e in ev
+                  if e["name"] == "metrics/emit") == [1, 2]
+    assert summary["spans"]["dispatch{family=round}"] == {
+        "count": cfg.rounds}
+    assert any(k.startswith("programs{family=eval_val,")
+               for k in summary["spans"])
+    # the flight record's per-span milliseconds are the tracer's unit group
+    from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
+        flight)
+    recs = flight.read_flight(os.path.join(run_dir, flight.STREAM_NAME))
+    assert [r["round"] for r in recs] == [1, 2]
+    assert all(r["spans"]["round/dispatch"] > 0 for r in recs)
+    assert "engine/build" in recs[0]["spans"]
+    assert "engine/build" not in recs[1]["spans"]
 
     status = json.load(open(os.path.join(cfg.log_dir, "status.json")))
     assert status["phase"] == "done"
     assert status["pid"] == os.getpid()
     assert status["compile_in_flight"] is False
     assert status["round"] == cfg.rounds
+
+
+def test_current_tracer_follows_newest_engine(tmp_path):
+    """`spans.current()` is the tracer of the newest RoundEngine, None
+    under --no_spans; close() stops the compile listener and keeps the
+    records readable."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu import train
+    from defending_against_backdoors_with_robust_learning_rate_tpu.utils.metrics import (
+        NullWriter)
+
+    cfg = SMOKE.replace(log_dir=str(tmp_path / "logs"), flight="off",
+                        heartbeat=False)
+    a = train.RoundEngine(cfg, writer=NullWriter())
+    try:
+        assert spans_mod.current() is a.tracer and a.tracer._watching
+        b = train.RoundEngine(cfg.replace(seed=6), writer=NullWriter())
+        try:
+            assert spans_mod.current() is b.tracer
+            with spans_mod.span("setup/data/poison"):   # to the newest
+                pass
+            assert [s.name for s in b.tracer.records()][-1] == \
+                "setup/data/poison"
+        finally:
+            b.close()
+        assert not b.tracer._watching
+        assert spans_mod.current() is b.tracer      # readers come after
+        build = [s for s in b.tracer.records() if s.name == "engine/build"]
+        assert len(build) == 1 and build[0].parent is None
+        c = train.RoundEngine(cfg.replace(spans=False), writer=NullWriter())
+        try:
+            assert spans_mod.current() is None and not c.tracer.enabled
+            c.dispatch((1,))
+            c.eval_boundary(1)
+            c.post_unit()
+            c.drain_flush()
+            assert c.tracer.records() == []
+        finally:
+            c.close()
+    finally:
+        a.close()
+        spans_mod.set_current(None)
 
 
 def test_driver_telemetry_sync_async_defense_parity(tmp_path):
