@@ -390,6 +390,10 @@ def main():
     hb.update(phase="data", force=True)
     with tracer.span("bench/data"):
         fed = get_federated_data(cfg)
+    # as the engine does: every measure(cfg.replace(...)) below keys its
+    # programs on the policy the model was built with
+    cfg = cfg.replace(
+        remat_policy=compile_cache.resolved_remat(cfg, fed).policy)
     model = get_model(cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
                       remat_policy=cfg.remat_policy)
     norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)

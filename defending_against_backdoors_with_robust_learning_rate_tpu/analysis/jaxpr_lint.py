@@ -37,6 +37,8 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.analysis import (
     contracts)
 from defending_against_backdoors_with_robust_learning_rate_tpu.analysis.ast_rules import (
     Finding)
+from defending_against_backdoors_with_robust_learning_rate_tpu.utils.jaxprs import (
+    iter_eqns)
 
 BASELINE_NAME = "analysis_baseline.json"
 
@@ -67,30 +69,6 @@ _HLO_COLLECTIVE_RE = re.compile(
 # --------------------------------------------------------------------------
 # jaxpr walking
 # --------------------------------------------------------------------------
-
-def _sub_jaxprs(value):
-    from jax.extend.core import ClosedJaxpr, Jaxpr
-    if isinstance(value, ClosedJaxpr):
-        yield value.jaxpr
-    elif isinstance(value, Jaxpr):
-        yield value
-    elif isinstance(value, (list, tuple)):
-        for item in value:
-            yield from _sub_jaxprs(item)
-
-
-def iter_eqns(closed):
-    """Every eqn in a ClosedJaxpr, recursing into scan/pjit/shard_map/cond
-    sub-jaxprs (each counted once — a scan body's collectives are per-
-    program, not per-iteration)."""
-    stack = [closed.jaxpr]
-    while stack:
-        jaxpr = stack.pop()
-        for eqn in jaxpr.eqns:
-            yield eqn
-            for v in eqn.params.values():
-                stack.extend(_sub_jaxprs(v))
-
 
 def count_primitives(closed) -> Dict[str, int]:
     counts: Dict[str, int] = {}
@@ -154,9 +132,12 @@ def _build_env(cfg):
         make_normalizer)
     from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
         get_model)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
+        compile_cache)
     fed = get_federated_data(cfg)
-    model = get_model(cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
-                     remat_policy=cfg.remat_policy)
+    model = get_model(
+        cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
+        remat_policy=compile_cache.resolved_remat(cfg, fed).policy)
     norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)
     return fed, model, norm
 

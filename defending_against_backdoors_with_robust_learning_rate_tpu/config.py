@@ -122,14 +122,16 @@ class Config:
                                     # activation HBM by m/chunk for big
                                     # models; must divide the per-device
                                     # agent count (else full vmap)
-    remat: bool = False             # blockwise rematerialization of the
-                                    # model's forward (ResNet-9): backward
+    remat: bool = False             # rematerialization of the model's
+                                    # forward (ResNet-9): backward
                                     # recomputes activations instead of
                                     # stashing them (exact, saves HBM)
-    remat_policy: str = "block"     # block: recompute everything per block;
-                                    # conv: save the conv (MXU) outputs and
-                                    # recompute only the elementwise tail
-                                    # (~3x saved bytes, no conv recompute)
+    remat_policy: str = "auto"      # what backward recomputes under remat.
+                                    # block: everything in a block; conv:
+                                    # only the elementwise tail, the conv
+                                    # (MXU) outputs are kept; auto: conv
+                                    # when their bytes fit the device, else
+                                    # block (compile_cache.resolved_remat)
     # --- fault injection & elastic participation (faults/) ---
     dropout_rate: float = 0.0       # per-round Bernoulli client dropout
     straggler_rate: float = 0.0     # per-round straggler probability
@@ -611,7 +613,10 @@ FIELD_PROVENANCE = {
                                   # the fingerprint already
     "agent_chunk": "program",     # chunked lax.map vs full vmap
     "remat": "program",
-    "remat_policy": "program",
+    "remat_policy": "program",    # the fingerprint keys the RESOLVED
+                                  # policy (compile_cache.resolved_remat:
+                                  # `auto` is block or conv by the
+                                  # device's memory limit)
     "dropout_rate": "program",    # faults path is traced
     "straggler_rate": "program",
     "straggler_epochs": "program",
@@ -853,14 +858,19 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "(divides peak activation HBM; must divide the "
                         "per-device agent count)")
     p.add_argument("--remat", action="store_true",
-                   help="blockwise rematerialization of the model forward "
+                   help="rematerialization of the model forward "
                         "(ResNet-9): recompute activations in backward "
                         "instead of stashing them — exact, saves HBM")
     p.add_argument("--remat_policy", type=str, default=d.remat_policy,
-                   choices=("block", "conv"),
-                   help="remat flavor: block = recompute everything; conv "
-                        "= save conv (MXU) outputs, recompute only the "
-                        "elementwise tail")
+                   choices=("auto", "block", "conv"),
+                   help="what backward recomputes under --remat: block = "
+                        "everything in a block; conv = only the "
+                        "elementwise tail (GroupNorm, relu, pool), the "
+                        "conv (MXU) outputs are kept; auto = conv when "
+                        "the kept bytes (examples in flight on a device x "
+                        "the model's conv outputs) fit a third of what "
+                        "the device has free, else block; block on a "
+                        "backend that reports no memory limit (the CPU)")
     p.add_argument("--dropout_rate", type=float, default=d.dropout_rate,
                    help="per-round Bernoulli client dropout probability "
                         "(faults/: dropped agents are masked out of "

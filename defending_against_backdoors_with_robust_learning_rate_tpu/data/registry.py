@@ -63,6 +63,14 @@ class FederatedData:
     raw_is_normalized: bool              # fedemnist: skip /255 + mean/std
     synthetic: bool = False
 
+    @property
+    def nbytes(self) -> int:
+        """Host bytes of the train shards and both eval sets."""
+        return sum(int(a.nbytes) for a in (
+            self.train.images, self.train.labels, self.train.sizes,
+            self.val_images, self.val_labels, self.pval_images,
+            self.pval_labels))
+
 
 def _norm_arrays(data: str) -> Tuple[np.ndarray, np.ndarray]:
     mean, std = NORM_STATS[data]

@@ -9,8 +9,11 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.models.cnn import
     CNN_MNIST, CNN_CIFAR)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.resnet import (
     ResNet9)
+from defending_against_backdoors_with_robust_learning_rate_tpu.utils.jaxprs import (
+    iter_eqns)
 
 _DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
+REMAT_POLICIES = ("block", "conv")
 
 
 def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
@@ -20,7 +23,14 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     arch='resnet9' selects the BASELINE north-star ResNet-9 extension.
     `remat` enables rematerialization (ResNet-9 only; the small CNNs'
     activations never pressure HBM); `remat_policy` picks full blockwise
-    ("block") or selective save-conv-outputs ("conv") recompute."""
+    ("block") or selective save-conv-outputs ("conv") recompute. It takes
+    the RESOLVED policy: `--remat_policy auto` is a rule over the device's
+    memory (utils/compile_cache.resolved_remat), not a model property."""
+    if remat_policy not in REMAT_POLICIES:
+        raise ValueError(
+            f"remat_policy must be one of {REMAT_POLICIES}, got "
+            f"{remat_policy!r} (resolve 'auto' with "
+            f"compile_cache.resolved_remat first)")
     dt = _DTYPES[dtype]
     if arch == "resnet9":
         return ResNet9(n_classes=n_classes, dtype=dt, remat=remat,
@@ -40,6 +50,29 @@ def init_params(model, image_shape, key=None, batch: int = 2):
 
 def param_count(params) -> int:
     return sum(int(x.size) for x in jax.tree_util.tree_leaves(params))
+
+
+def abstract_params(model, image_shape):
+    """The parameter tree as ShapeDtypeStructs: nothing materialized."""
+    return jax.eval_shape(lambda: init_params(model, image_shape))
+
+
+def named_activation_bytes(model, image_shape,
+                           name: str = "conv_out") -> int:
+    """Bytes per example of the activations the model tags
+    `checkpoint_name(x, name)`, read off the jaxpr of one abstract forward
+    at batch 1 in the model's own dtype: nothing is compiled or run. They
+    are what `remat_policy="conv"` keeps per example in flight on top of
+    what "block" keeps; a model that tags nothing (the CNNs) reads 0."""
+    params = abstract_params(model, image_shape)
+    x = jax.ShapeDtypeStruct((1,) + tuple(image_shape), jnp.float32)
+    closed = jax.make_jaxpr(lambda p, x: model.apply(
+        {"params": p}, x, train=True,
+        rngs={"dropout": jax.random.PRNGKey(0)}))(params, x)
+    return sum(v.aval.size * v.aval.dtype.itemsize
+               for eqn in iter_eqns(closed)
+               if eqn.primitive.name == "name" and eqn.params["name"] == name
+               for v in eqn.outvars)
 
 
 def flops_per_example(data: str, arch: str, image_shape,

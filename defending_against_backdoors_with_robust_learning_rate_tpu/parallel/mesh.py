@@ -16,17 +16,25 @@ from jax.sharding import Mesh
 AGENTS_AXIS = "agents"
 
 
-def pick_agent_mesh_size(requested: int, agents_per_round: int,
-                         n_devices: int | None = None) -> int:
+def agent_mesh_size(requested: int, agents_per_round: int,
+                    n_devices: int | None = None) -> int:
     """Largest device count <= min(requested or all, available) that divides
     the per-round participant count (blocking policy, SURVEY.md 7.2.5 — e.g.
-    m=10 on a v5e-8 slice uses 5 devices, 2 agents per device). A mesh
-    smaller than the one asked for says so: the run is otherwise
-    indistinguishable from the requested one except by its speed."""
+    m=10 on a v5e-8 slice uses 5 devices, 2 agents per device)."""
     avail = n_devices if n_devices is not None else len(jax.devices())
     want = requested if requested > 0 else avail
-    picked = next(d for d in range(min(want, avail), 0, -1)
-                  if agents_per_round % d == 0)
+    return next(d for d in range(min(want, avail), 0, -1)
+                if agents_per_round % d == 0)
+
+
+def pick_agent_mesh_size(requested: int, agents_per_round: int,
+                         n_devices: int | None = None) -> int:
+    """`agent_mesh_size` for the program about to be built. A mesh smaller
+    than the one asked for says so: the run is otherwise indistinguishable
+    from the requested one except by its speed."""
+    avail = n_devices if n_devices is not None else len(jax.devices())
+    want = requested if requested > 0 else avail
+    picked = agent_mesh_size(requested, agents_per_round, avail)
     if picked < want:
         print(f"[mesh] WARNING: --mesh {requested} asked for {want} "
               f"device(s), using {picked}: {avail} available, and the mesh "

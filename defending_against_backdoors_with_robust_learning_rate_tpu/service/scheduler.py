@@ -95,18 +95,13 @@ class CapacityModel:
     def tenant_bytes(self, cfg) -> int:
         """Analytic per-tenant resident footprint (no device work: the
         param tree is shape-evaluated, never materialized)."""
-        import jax
         from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
             buffered)
         from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
-            get_model, init_params)
-        model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
-                          remat=cfg.remat, remat_policy=cfg.remat_policy)
-        shapes = jax.eval_shape(
-            lambda: init_params(model, cfg.image_shape,
-                                jax.random.PRNGKey(0)))
-        n_params = sum(int(l.size) for l in jax.tree_util.tree_leaves(
-            shapes))
+            abstract_params, get_model, param_count)
+        # remat changes what backward keeps, never the parameter tree
+        model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
+        n_params = param_count(abstract_params(model, cfg.image_shape))
         per = n_params * _DTYPE_BYTES.get(cfg.dtype, 4)
         mult = 1.0 + WORKSPACE_FACTOR
         if buffered.is_buffered(cfg):

@@ -240,10 +240,16 @@ class PackEngine:
                     f"stack exceeds the device-resident budget); "
                     f"running cells solo")
         self.fed = fed
-        model = get_model(rep.data, rep.model_arch, rep.dtype,
-                          remat=rep.remat, remat_policy=rep.remat_policy)
+        # the pack's E experiments train at once: E is in the rule, and
+        # the resolved policy goes into `rep`, which keys the *_mt families
+        remat = compile_cache.resolved_remat(rep, fed)
+        self.rep = rep = rep.replace(remat_policy=remat.policy)
+        if rep.remat:
+            print(f"[tenancy] {remat.describe()}")
+        self.model = model = get_model(
+            rep.data, rep.model_arch, rep.dtype, remat=rep.remat,
+            remat_policy=rep.remat_policy)
         norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)
-        self.model = model
         self.image_shape = fed.train.images.shape[2:]
         m = rep.agents_per_round
 

@@ -302,17 +302,21 @@ class RoundEngine:
                 cohort_src = fed = get_cohort_data(cfg)
             else:
                 fed = get_federated_data(cfg)
-        tracer.count("data_bytes_host", sum(
-            int(a.nbytes) for a in (
-                fed.train.images, fed.train.labels, fed.train.sizes,
-                fed.val_images, fed.val_labels, fed.pval_images,
-                fed.pval_labels)))
+        tracer.count("data_bytes_host", fed.nbytes)
         if fed.synthetic and cfg.data != "synthetic":
             print(f"[data] {cfg.data} files not found under "
                   f"{cfg.data_dir!r}; using the deterministic synthetic "
                   f"fallback")
 
         with tracer.span("setup/model_init"):
+            # what the backward pass recomputes under --remat is settled
+            # here, from the examples one device trains at once and what
+            # it has free (compile_cache.resolved_remat); from here on cfg
+            # carries the resolved policy, never 'auto', so the bank's
+            # fingerprint keys the program that is built
+            remat = compile_cache.resolved_remat(
+                cfg, fed, threshold=DEVICE_RESIDENT_BYTES)
+            cfg = self.cfg = cfg.replace(remat_policy=remat.policy)
             model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
                               remat=cfg.remat,
                               remat_policy=cfg.remat_policy)
@@ -320,6 +324,11 @@ class RoundEngine:
                                  jax.random.PRNGKey(cfg.seed))
             print(f"[model] {type(model).__name__}: "
                   f"{param_count(params):,} params")
+            if cfg.remat:
+                tracer.count("remat", policy=remat.policy)
+                tracer.count("remat_saved_bytes", remat.saved_bytes)
+                tracer.count("remat_limit_bytes", remat.limit_bytes or 0)
+                print(f"[model] {remat.describe()}")
             norm = make_normalizer(fed.mean, fed.std,
                                    fed.raw_is_normalized)
 

@@ -272,6 +272,132 @@ def resolved_train_layout(cfg) -> str:
     return layout
 
 
+# `--remat_policy auto`: the tagged convolution outputs of every example in
+# flight may take this share of what the device has free, and no more.
+# Beside them the program holds what `block` holds anyway. On the chip
+# (PERF.md section 6, PR 25; ResNet-9, f32, 16.91 GB limit) the `conv`
+# round took 1.95x the kept bytes of what was free at 10 agents of 256 a
+# chunk (3.86 GB kept, peak 8.75 GB) and 1.83x at 20 (7.72 GB kept, peak
+# 15.39 GB); XLA's memory analysis reads 2.3x for bf16. At a third the
+# `conv` program therefore needs 0.65 (f32) to 0.77 (bf16) of what is
+# free at the threshold. That errs towards `block`: chunks of 20 in f32 resolve
+# `block` (13.42 GB, 0.2365 rounds/s) where `conv` forced by hand still
+# fit, 1.5 GB under the limit, and ran 0.2566.
+REMAT_CONV_SHARE_DIVISOR = 3
+
+
+@dataclasses.dataclass(frozen=True)
+class RematChoice:
+    """What `resolved_remat` settled, and on what."""
+    policy: str                  # "block" | "conv": what get_model receives
+    saved_bytes: int             # conv outputs `conv` keeps on one device
+    limit_bytes: Optional[int]   # the device's limit less what is resident
+    #                              there; None: the backend reports none
+    chosen: bool = False         # by the rule, not by the user
+
+    def describe(self) -> str:
+        held = ("no memory limit reported by this backend"
+                if self.limit_bytes is None else
+                f"{self.limit_bytes / 1e9:.2f} GB free of the device's "
+                f"limit, a 1/{REMAT_CONV_SHARE_DIVISOR} share of it "
+                f"allowed")
+        return (f"remat policy {self.policy} "
+                f"({'auto' if self.chosen else 'as asked'}): keeping the "
+                f"convolution outputs takes {self.saved_bytes / 1e9:.2f} "
+                f"GB; {held}")
+
+
+def device_memory_limit() -> Optional[int]:
+    """`bytes_limit` of this process's first device, or None where the
+    backend keeps no such count (XLA:CPU)."""
+    stats = jax.local_devices()[0].memory_stats()
+    return stats.get("bytes_limit") if stats else None
+
+
+def remat_policy_for(bytes_per_example: int, examples_in_flight: int,
+                     free_bytes: Optional[int]) -> str:
+    """The rule of `--remat_policy auto`, on numbers alone: `conv` when the
+    convolution outputs of the examples in flight fit their share of
+    `free_bytes`, the device's limit less what is resident, else `block`.
+    A backend that reports no limit (None) gets `block`: nothing there
+    says they fit."""
+    if free_bytes is None:
+        return "block"
+    saved = bytes_per_example * examples_in_flight
+    return ("conv" if REMAT_CONV_SHARE_DIVISOR * saved <= free_bytes
+            else "block")
+
+
+@functools.lru_cache(maxsize=32)
+def _remat_shapes(data: str, arch: str, dtype: str,
+                  image_shape: Tuple[int, ...]) -> Tuple[int, int]:
+    """(conv_out bytes per example, parameter count) of the model."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+        registry)
+    model = registry.get_model(data, arch, dtype)
+    return (registry.named_activation_bytes(model, image_shape),
+            registry.param_count(
+                registry.abstract_params(model, image_shape)))
+
+
+def resolved_remat(cfg, fed=None,
+                   threshold: Optional[int] = None) -> RematChoice:
+    """Single source of what the backward pass recomputes under `--remat`
+    (ISSUE 25). `--remat_policy block|conv` is honoured; `auto` keeps the
+    convolution outputs (`conv`) when their bytes fit the device and
+    recomputes whole blocks (`block`) when they do not, from what the
+    program can observe without compiling anything:
+
+    - bytes of the tagged outputs per example, from the model's own
+      shapes in `--dtype`;
+    - examples in flight on one device: the agents it trains at once
+      (`agent_chunk`, else the device's share of the sampled agents under
+      `--mesh`, by the driver's blocking policy) x `bs` x the tenants of a
+      packed program;
+    - the device's `bytes_limit` less what is resident beside the
+      activations: the [agents, n_params] float32 update stack, and the
+      dataset `fed` where the run places it on the device (a host- or
+      cohort-sampled run keeps it on the host: `is_host_mode`,
+      `is_cohort_mode`, with the driver's `threshold`).
+
+    Every builder of a model (the engine, the pack engine, precompile,
+    bench, the jaxpr lint) resolves through here with its `fed` and writes
+    the policy back into its cfg, so that `get_model` and the bank's
+    fingerprint see `block` or `conv`, never `auto`; `fingerprint`
+    resolves a cfg still carrying `auto` through here as well, so that
+    `auto` is never a key of its own. Without `--remat` the policy selects
+    nothing and reads `block`."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
+        REMAT_POLICIES)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
+        agent_mesh_size)
+    if cfg.remat_policy != "auto" and cfg.remat_policy not in REMAT_POLICIES:
+        raise ValueError(
+            f"remat_policy must be 'auto' or one of {REMAT_POLICIES}, got "
+            f"{cfg.remat_policy!r}")
+    if not cfg.remat:
+        return RematChoice("block", 0, None)
+    per_example, n_params = _remat_shapes(
+        cfg.data, cfg.model_arch, cfg.dtype, tuple(cfg.image_shape))
+    tenants = max(1, cfg.tenants)
+    cohort = is_cohort_mode(cfg, fed, threshold)
+    agents = cfg.agents_per_round
+    if cfg.mesh != 1 and not (cohort and cfg.tenants > 0):
+        # a cohort pack has no sharded family and trains on one device
+        agents //= agent_mesh_size(cfg.mesh, agents)
+    at_once = cfg.agent_chunk if 0 < cfg.agent_chunk < agents else agents
+    in_flight = at_once * cfg.bs * tenants
+    limit = device_memory_limit()
+    if limit is not None:
+        limit -= 4 * n_params * agents * tenants
+        if not (fed is None or cohort or is_host_mode(cfg, fed, threshold)):
+            limit -= fed.nbytes
+    chosen = cfg.remat_policy == "auto"
+    policy = (remat_policy_for(per_example, in_flight, limit) if chosen
+              else cfg.remat_policy)
+    return RematChoice(policy, per_example * in_flight, limit, chosen)
+
+
 def family_suffix(cfg) -> str:
     """Program-family name suffix for the aggregation mode + resolved
     training layout + tenancy: buffered-async families (`round_async`,
@@ -316,6 +442,9 @@ def fingerprint(cfg, family: str, example_args) -> str:
     # the RESOLVED layout keys the cache (a diagnostics-degraded
     # megabatch config runs the vmap programs — same key, no split)
     fields["train_layout"] = resolved_train_layout(cfg)
+    # likewise the RESOLVED remat policy: `auto` shares the key of what
+    # it resolves to, and is never a key of its own
+    fields["remat_policy"] = resolved_remat(cfg).policy
     if fields.get("tenants", 0) > 0:
         # tenant packs (fl/tenancy.py): the per-tenant scalar knobs are
         # traced [E]-vector ARGUMENTS of the *_mt programs, so their

@@ -104,6 +104,10 @@ def main():
             fed = get_cohort_data(cfg)
         else:
             fed = get_federated_data(cfg)
+        # resolved as the engine resolves it, and written back: the keys
+        # banked here are the keys train.py will ask for
+        cfg = cfg.replace(
+            remat_policy=compile_cache.resolved_remat(cfg, fed).policy)
         model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
                           remat=cfg.remat, remat_policy=cfg.remat_policy)
         norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)

@@ -87,8 +87,7 @@ def main():
         print("[profile] CPU backend: reduced shapes (6k train) — timings "
               "are not comparable to TPU rows", flush=True)
     fed = get_federated_data(cfg)
-    model = get_model(cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
-                      remat_policy=cfg.remat_policy)
+    model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
     params = init_params(model, fed.train.images.shape[2:],
                          jax.random.PRNGKey(0))
     norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)

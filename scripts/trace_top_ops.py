@@ -70,8 +70,7 @@ def capture(trace_dir: str, rounds: int, platform: str = "",
         cfg = cfg.replace(bs=32, synth_train_size=640, synth_val_size=128,
                           data_dir="/nonexistent_use_synthetic")
     fed = get_federated_data(cfg)
-    model = get_model(cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
-                      remat_policy=cfg.remat_policy)
+    model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
     params = init_params(model, fed.train.images.shape[2:],
                          jax.random.PRNGKey(0))
     norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)
