@@ -342,6 +342,16 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
     specs["vmap_eval"] = CheckSpec(
         name="vmap_eval", family="eval_val", sharded=False,
         cfg_overrides={}, collective_budget=dict(zero))
+    # the folded round (ISSUE 27, ROADMAP R3): the same `round` family
+    # with the update stack replaced by a scan over chunks of clients that
+    # carries (weighted sum, sign sum, weight total). `agg_path` has no
+    # flag: the engine resolves it from the device's memory
+    # (compile_cache.resolved_agg); the spec sets it. Collective-free like
+    # its stacked twin, and no f64, no callback in the scan body.
+    specs["vmap_rlr_avg_fold"] = CheckSpec(
+        name="vmap_rlr_avg_fold", family="round", sharded=False,
+        cfg_overrides={"agg_path": "fold", "agent_chunk": 2},
+        collective_budget=dict(zero))
 
     # flagship sharded defense: avg + RLR — psums only, no transposes
     specs["sharded_rlr_avg"] = CheckSpec(
@@ -1100,6 +1110,12 @@ RUN_NAME_EXEMPT: Dict[str, str] = {
     "client_moment": _X_REFERENCE_VOCAB,
     "agent_chunk": _X_VALUE_PRESERVING,
     "agg_layout": _X_VALUE_PRESERVING,
+    "agg_path": _X_VALUE_PRESERVING,
+    "lm_config": _X_REFERENCE_VOCAB,
+    "lm_layers": _X_REFERENCE_VOCAB,
+    "lm_experts_held": _X_REFERENCE_VOCAB,
+    "lm_expert_offset": _X_REFERENCE_VOCAB,
+    "lm_vocab_held": _X_REFERENCE_VOCAB,
     "remat": _X_VALUE_PRESERVING,
     "remat_policy": _X_VALUE_PRESERVING,
     "use_pallas": _X_VALUE_PRESERVING,

@@ -61,7 +61,7 @@ class Config:
     coordinator: str = ""           # host:port of process 0
     num_processes: int = 0          # total processes in the job
     process_id: int = -1            # this process's id; -1 = auto
-    arch: str = "auto"              # auto | cnn | resnet9
+    arch: str = "auto"              # auto | cnn | resnet9 | lfm2_moe
     dtype: str = "f32"              # f32 | bf16 (compute dtype on the MXU)
     rng_impl: str = "auto"          # auto: hardware RNG (rbg) on TPU,
                                     # threefry elsewhere; threefry | rbg
@@ -481,6 +481,24 @@ class Config:
     # synthetic-data knobs (used when `data` is missing on disk or 'synthetic')
     synth_train_size: int = 2048
     synth_val_size: int = 512
+    # --- the token task (--data=tokens --arch=lfm2_moe; fl/task.py) ---
+    seq_len: int = 2048             # tokens a packed sequence feeds the model
+    lm_config: str = "lfm2-8b-a1b"  # the published widths: a name in
+                                    # models/lfm2_moe.PUBLISHED, or a file
+    lm_layers: str = ""             # the cut in depth: source layer indices
+                                    # held, comma-separated ("" = all)
+    lm_experts_held: int = 0        # experts of a sparse layer that live on
+                                    # this chip (0 = all); the router keeps
+                                    # its published width
+    lm_expert_offset: int = 0       # the first held expert's index
+    lm_vocab_held: int = 0          # vocabulary rows held (0 = all)
+    agg_path: str = "auto"          # stack | fold: whether the round holds
+                                    # the [m, n_params] update stack or folds
+                                    # each client's update into the vote as
+                                    # it arrives. No flag: auto is a rule
+                                    # over the device's memory
+                                    # (compile_cache.resolved_agg); tests and
+                                    # the static gate's specs set it
     synth_hardness: float = 0.0     # 0 = easy separable prototypes; >0 mixes
                                     # a shared background into the prototypes,
                                     # raises pixel noise and adds label noise
@@ -538,6 +556,9 @@ class Config:
             return (28, 28, 1)
         if self.data in ("cifar10", "synthetic"):
             return (32, 32, 3) if self.data == "cifar10" else (8, 8, 1)
+        if self.data == "tokens":
+            raise ValueError("the token task has no image shape "
+                             "(fl/task.input_shape gives a batch's)")
         raise ValueError(f"unknown dataset {self.data!r}")
 
     @property
@@ -746,6 +767,14 @@ FIELD_PROVENANCE = {
     "synth_train_size": "shape",
     "synth_val_size": "shape",
     "synth_hardness": "data",
+    "seq_len": "shape",           # pinned by the dataset's avals
+    "lm_config": "program",       # the model's widths
+    "lm_layers": "program",       # the cut: depth, experts, vocabulary
+    "lm_experts_held": "program",
+    "lm_expert_offset": "program",
+    "lm_vocab_held": "program",
+    "agg_path": "program",        # the fingerprint keys it as resolved
+                                  # by the engine (stack | fold)
 }
 
 
@@ -1205,6 +1234,23 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--no_tensorboard", action="store_true")
     p.add_argument("--synth_train_size", type=int, default=d.synth_train_size)
     p.add_argument("--synth_val_size", type=int, default=d.synth_val_size)
+    p.add_argument("--seq_len", type=int, default=d.seq_len,
+                   help="--data=tokens: tokens of a packed sequence")
+    p.add_argument("--lm_config", type=str, default=d.lm_config,
+                   help="--arch=lfm2_moe: the published widths, a name "
+                        "(lfm2-8b-a1b) or a JSON file")
+    p.add_argument("--lm_layers", type=str, default=d.lm_layers,
+                   help="the cut in depth: source layer indices held, "
+                        "comma-separated (empty = all)")
+    p.add_argument("--lm_experts_held", type=int, default=d.lm_experts_held,
+                   help="experts of a sparse layer held here (0 = all); "
+                        "the router keeps its published width and top-k")
+    p.add_argument("--lm_expert_offset", type=int,
+                   default=d.lm_expert_offset,
+                   help="index of the first held expert")
+    p.add_argument("--lm_vocab_held", type=int, default=d.lm_vocab_held,
+                   help="vocabulary rows held (0 = all); ids, logits and "
+                        "loss are over the slice")
     p.add_argument("--synth_hardness", type=float, default=d.synth_hardness,
                    help="0=easy separable synthetic task; 0..1 mixes "
                         "prototypes toward a shared background, raises pixel "

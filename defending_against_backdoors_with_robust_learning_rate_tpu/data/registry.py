@@ -454,6 +454,12 @@ def get_federated_data(cfg) -> FederatedData:
     from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
         spans)
 
+    if cfg.data == "tokens":
+        # the token task's generator and its backdoor (data/tokens.py)
+        from defending_against_backdoors_with_robust_learning_rate_tpu.data import (
+            tokens)
+        return tokens.get_federated_tokens(cfg)
+
     with spans.span("setup/data/load_or_generate"):
         train, val, synthetic = get_datasets(cfg)
 

@@ -28,6 +28,7 @@ import os
 import jax
 import jax.numpy as jnp
 
+from defending_against_backdoors_with_robust_learning_rate_tpu.fl import task
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.common import (
     masked_ce)
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops import (
@@ -37,10 +38,14 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.ops.sgd import (
 
 
 def make_local_train(model, cfg, normalize):
-    """Returns local_train(params0, images, labels, size, key) -> update pytree.
+    """Returns local_train(params0, images, labels, size, key) ->
+    (update pytree, per-client values: the mean epoch loss, or for a task
+    with per-step sums a dict of it and them, fl/task.per_client).
 
     images: [n_total, H, W, C] raw pixels, n_total a multiple of cfg.bs;
     labels: [n_total] int32; size: scalar int32 true shard size; key: PRNGKey.
+    The token task's `images` are [n_total, T + 1] ids and its `labels`
+    unused; what the batch means is the task's (fl/task.make_batch_loss).
 
     RLR_ABLATE (measurement-only, comma-separated): in-program ablations for
     the round-anatomy ladder (scripts/profile_round.py --ablate) — a
@@ -59,6 +64,8 @@ def make_local_train(model, cfg, normalize):
         print(f"[ABLATE] local training is running with {sorted(ablate)} "
               f"REMOVED — measurement mode, results are not real training",
               flush=True)
+    batch_loss = task.make_batch_loss(model, cfg, normalize,
+                                      deterministic="nodropout" in ablate)
 
     def _local_train(params0, images, labels, size, key, ep_budget):
         n_total = images.shape[0]
@@ -66,7 +73,8 @@ def make_local_train(model, cfg, normalize):
         # policy for ops/loops.maybe_unrolled_scan (XLA:CPU conv-in-while
         # slow path): trace short local loops as Python loops on CPU,
         # capped at 16 fwd+bwd steps to keep trace/compile time sane
-        py_loops = loops.cpu_backend() and cfg.local_ep * nb <= 16
+        py_loops = ((loops.cpu_backend() and cfg.local_ep * nb <= 16)
+                    or loops.carry_is_large(params0, cfg.local_ep * nb))
         params0 = tree.astype(params0, jnp.float32)
 
         def epoch_body(carry, xs):
@@ -102,16 +110,11 @@ def make_local_train(model, cfg, normalize):
                 w = ((b * bs + jnp.arange(bs)) < size) & ep_active
 
                 def loss_fn(p):
-                    if "nodropout" in ablate:
-                        logits = model.apply({"params": p}, normalize(x),
-                                             train=False)
-                    else:
-                        logits = model.apply(
-                            {"params": p}, normalize(x), train=True,
-                            rngs={"dropout": jax.random.fold_in(drop_key, b)})
-                    return masked_ce(logits, y, w)
+                    return batch_loss(p, x, y, w,
+                                      jax.random.fold_in(drop_key, b))
 
-                loss, grads = jax.value_and_grad(loss_fn)(params)
+                (loss, sums), grads = jax.value_and_grad(
+                    loss_fn, has_aux=True)(params)
                 grads = clip_by_global_norm(grads, 10.0)
                 w_n = jnp.sum(w)
                 params, mom = sgd_momentum_step(
@@ -119,23 +122,30 @@ def make_local_train(model, cfg, normalize):
                     w_n > 0)
                 if cfg.clip > 0:
                     params = pgd_project(params, params0, cfg.clip)
-                return (params, mom), (loss * w_n, w_n)
+                return (params, mom), (loss * w_n, w_n, sums)
 
-            (params, mom), (loss_sums, w_sums) = loops.maybe_unrolled_scan(
-                batch_body, (params, mom), jnp.arange(nb), py_loops)
+            (params, mom), (loss_sums, w_sums, sums) = \
+                loops.maybe_unrolled_scan(
+                    batch_body, (params, mom), jnp.arange(nb), py_loops)
             # sample-weighted epoch loss: padding batches contribute nothing
             ep_loss = jnp.sum(loss_sums) / jnp.maximum(jnp.sum(w_sums), 1.0)
-            return (params, mom), ep_loss
+            return (params, mom), (ep_loss, tree.map(
+                lambda a: jnp.sum(a, axis=0), sums))
 
         ep_keys = jax.random.split(key, cfg.local_ep)
-        (params, _), ep_losses = loops.maybe_unrolled_scan(
+        (params, _), (ep_losses, sums) = loops.maybe_unrolled_scan(
             epoch_body, (params0, tree.zeros_like(params0)),
             (ep_keys, jnp.arange(cfg.local_ep)), py_loops)
         update = tree.sub(params, params0)
-        return update, jnp.mean(ep_losses)
+        return update, task.per_client(
+            jnp.mean(ep_losses),
+            tree.map(lambda a: jnp.sum(a, axis=0), sums))
 
+    # fl/rounds.vmap_agents maps a block's clients instead of batching them
+    sequential = task.is_tokens(cfg)
     if cfg.straggler_rate > 0:
         # faults path: callers pass a per-agent epoch budget (6th arg)
+        _local_train.sequential = sequential
         return _local_train
 
     def local_train(params0, images, labels, size, key):
@@ -143,6 +153,7 @@ def make_local_train(model, cfg, normalize):
         return _local_train(params0, images, labels, size, key,
                             jnp.int32(cfg.local_ep))
 
+    local_train.sequential = sequential
     return local_train
 
 

@@ -20,17 +20,20 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.ops import loops
 
 def pad_eval_set(images: np.ndarray, labels: np.ndarray, bs: int
                  ) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
-    """Pad to a multiple of bs and reshape to [nb, bs, ...] + weight mask."""
+    """Pad to a multiple of bs and reshape to [nb, bs, ...] + weight mask.
+    `labels` may carry trailing axes (the token task's per-position
+    masks)."""
     n = len(labels)
     nb = max(1, -(-n // bs))
     pad = nb * bs - n
     if pad:
         images = np.concatenate([images, np.zeros((pad,) + images.shape[1:],
                                                   images.dtype)])
-        labels = np.concatenate([labels, np.zeros((pad,), labels.dtype)])
+        labels = np.concatenate([labels, np.zeros(
+            (pad,) + labels.shape[1:], labels.dtype)])
     w = (np.arange(nb * bs) < n).astype(np.float32)
     return (images.reshape((nb, bs) + images.shape[1:]),
-            labels.reshape(nb, bs).astype(np.int32),
+            labels.reshape((nb, bs) + labels.shape[1:]).astype(np.int32),
             w.reshape(nb, bs))
 
 

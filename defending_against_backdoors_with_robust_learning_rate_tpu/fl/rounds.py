@@ -25,14 +25,15 @@ import jax
 import jax.numpy as jnp
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
-    buffered)
+    buffered, task)
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.client import (
     make_local_train, make_local_train_megabatch)
 from defending_against_backdoors_with_robust_learning_rate_tpu.health import (
     sentinel as health_sentinel)
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops import loops
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops.aggregate import (
-    aggregate_updates, apply_aggregate, robust_lr)
+    aggregate_updates, apply_aggregate, fold_finish, fold_init, fold_updates,
+    robust_lr)
 
 # fault observability scalars (faults/model.fault_scalars) that chained
 # blocks carry through their lax.scan alongside train_loss
@@ -129,7 +130,20 @@ def vmap_agents(local_train, params, imgs, lbls, sizes, keys,
     straggler fault is configured — local_train then takes it as a sixth
     per-agent argument."""
     extra = () if ep_budget is None else (ep_budget,)
-    vt = jax.vmap(local_train, in_axes=(None,) + (0,) * (4 + len(extra)))
+    if getattr(local_train, "sequential", False):
+        # a model whose operations do not batch over a client axis (the
+        # token model's grouped products) trains a block's clients one
+        # after another; a block of one is traced flat (a rolled loop
+        # keeps its carry in buffers of its own: ops/loops.py)
+        def vt(params, *per_agent):
+            if per_agent[0].shape[0] == 1:
+                return jax.tree_util.tree_map(
+                    lambda a: a[None],
+                    local_train(params, *(a[0] for a in per_agent)))
+            return jax.lax.map(lambda a: local_train(params, *a), per_agent)
+    else:
+        vt = jax.vmap(local_train,
+                      in_axes=(None,) + (0,) * (4 + len(extra)))
     return _run_chunked(vt, params, imgs, lbls, sizes, keys, chunk, extra)
 
 
@@ -176,9 +190,10 @@ def _run_chunked(block_fn, params, imgs, lbls, sizes, keys, chunk, extra):
     _, (updates, losses) = loops.maybe_unrolled_scan(
         body, 0, tuple(resh(a) for a in (imgs, lbls, sizes, keys) + extra),
         loops.cpu_backend() and nc <= 16)
-    return (jax.tree_util.tree_map(
-        lambda u: u.reshape((m,) + u.shape[2:]), updates),
-        losses.reshape(m))
+    flat = functools.partial(
+        jax.tree_util.tree_map, lambda u: u.reshape((m,) + u.shape[2:]))
+    # `losses` is [m], or the task's dict of per-client values (fl/task.py)
+    return flat(updates), flat(losses)
 
 
 def make_block_trainer(model, cfg, normalize):
@@ -210,6 +225,69 @@ def make_block_trainer(model, cfg, normalize):
         return vmap_agents(local_train, params, imgs, lbls, sizes, keys,
                            chunk, ep_budget=ep_budget)
     return train_block
+
+
+def _fold_core(params, k_train, k_noise, imgs, lbls, sizes, *, train_block,
+               cfg, corrupt_flags=None, rnd=None):
+    """The round as a fold (ROADMAP R3): a scan over chunks of
+    `--agent_chunk` clients (one at a time where it is unset) that carries
+    (sum of n_k u_k, sum of sign(u_k), sum of n_k) and the per-client lanes
+    that are functions of one update, and never stacks; then the same tail
+    as the stacked round. `local_train` and `aggregate_rlr` are siblings in
+    the scan body, so a trace files the fold's adds under the server step.
+    What a fold cannot do is refused before any build
+    (utils/compile_cache.unsupported)."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.attack import (
+        registry as attack_registry)
+    m = imgs.shape[0]
+    chunk = cfg.agent_chunk if 0 < cfg.agent_chunk < m else 1
+    if m % chunk:
+        raise ValueError(
+            f"--agent_chunk {chunk} does not divide the {m} clients of a "
+            f"round")
+    nc = m // chunk
+    keys = jax.random.split(k_train, m)
+    flags = (jnp.zeros((m,), bool) if corrupt_flags is None
+             else corrupt_flags)
+    want_sign = cfg.robustLR_threshold > 0 or cfg.aggr == "sign"
+    health = health_sentinel.health_on(cfg)
+
+    def body(acc, args):
+        im, lb, sz, ky, fl = args
+        with jax.named_scope("local_train"):
+            updates, per = train_block(params, im, lb, sz, ky)
+        if attack_registry.in_jit(cfg):
+            updates = attack_registry.apply_update_attack(
+                cfg, updates, fl, attack_registry.schedule_active(cfg, rnd))
+        with jax.named_scope("aggregate_rlr"):
+            acc = fold_updates(acc, updates, sz)
+        lanes = ()
+        if health:
+            with jax.named_scope("health"):
+                lanes = health_sentinel._row_stats(updates)
+        return acc, (per, lanes)
+
+    def resh(a):
+        return a.reshape((nc, chunk) + a.shape[1:])
+
+    acc, (per, lanes) = loops.maybe_unrolled_scan(
+        body, fold_init(params, m, cfg.aggr == "avg", want_sign),
+        tuple(resh(a) for a in (imgs, lbls, sizes, keys, flags)),
+        loops.cpu_backend() and nc <= 16)
+    per, lanes = jax.tree_util.tree_map(
+        lambda a: a.reshape((m,) + a.shape[2:]), (per, lanes))
+    losses, sums = task.split_per_client(per)
+    with jax.named_scope("aggregate_rlr"):
+        lr, agg = fold_finish(
+            acc, cfg, k_noise,
+            float(cfg.robustLR_threshold) if cfg.robustLR_threshold > 0
+            else None, cfg.effective_server_lr)
+        new_params = apply_aggregate(params, lr, agg)
+    extras = task.round_counters(sums)
+    if health:
+        with jax.named_scope("health"):
+            extras.update(health_sentinel.from_row_stats(*lanes, new_params))
+    return new_params, jnp.mean(losses), extras
 
 
 def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
@@ -250,6 +328,16 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
     server_lr, the RLR threshold, the attack boost and the schedule
     window. None (every solo path) keeps the Python constants — the
     traced program is bit-for-bit the historical one."""
+    if cfg.agg_path == "fold":
+        # resolved by whoever built this cfg (the engine and the planner,
+        # through compile_cache.resolved_agg); `auto` that reaches here
+        # unresolved keeps the stack, as every round did before the fold
+        if astate is not None or knobs is not None:
+            raise ValueError("a folded round has no buffered or "
+                             "tenant-packed family")
+        return _fold_core(params, k_train, k_noise, imgs, lbls, sizes,
+                          train_block=train_block, cfg=cfg,
+                          corrupt_flags=corrupt_flags, rnd=rnd)
     m = imgs.shape[0]
     agent_keys = jax.random.split(k_train, m)
     draw = None
@@ -267,9 +355,10 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
             ep_budget = (draw.ep_budget if astate is None
                          else jnp.full((m,), cfg.local_ep, jnp.int32))
     with jax.named_scope("local_train"):
-        updates, losses = train_block(params, imgs, lbls, sizes,
-                                      agent_keys, cfg.agent_chunk,
-                                      ep_budget=ep_budget)
+        updates, per = train_block(params, imgs, lbls, sizes,
+                                   agent_keys, cfg.agent_chunk,
+                                   ep_budget=ep_budget)
+    losses, sums = task.split_per_client(per)
     from defending_against_backdoors_with_robust_learning_rate_tpu.attack import (
         registry as attack_registry)
     if attack_registry.in_jit(cfg):
@@ -291,7 +380,7 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
                 cfg, updates, corrupt_flags,
                 attack_registry.schedule_active(cfg, rnd))
     mask = None
-    extras = {}
+    extras = task.round_counters(sums)
     if draw is not None:
         from defending_against_backdoors_with_robust_learning_rate_tpu.faults import (
             masking, model as fmodel)
@@ -300,7 +389,7 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
                                             cfg.corrupt_mode)
         mask = draw.participate & fmodel.payload_valid(
             updates, cfg.payload_norm_cap)
-        extras = fmodel.fault_scalars(draw, mask)
+        extras.update(fmodel.fault_scalars(draw, mask))
     if churn_active is not None:
         from defending_against_backdoors_with_robust_learning_rate_tpu.faults import (
             masking)
@@ -318,7 +407,8 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
             if cfg.churn_enabled:
                 extras["churn_away"] = churn_mod.churn_away(churn_active)
         elif cfg.churn_enabled:
-            extras = churn_mod.churn_only_scalars(churn_active, mask)
+            extras.update(
+                churn_mod.churn_only_scalars(churn_active, mask))
     if astate is not None:
         # buffered-async tail (fl/buffered.py): this tick's updates fold
         # into the carried buffer by arrival level; params advance only

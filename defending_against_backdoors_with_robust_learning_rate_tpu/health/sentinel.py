@@ -153,7 +153,13 @@ def sentinel(cfg, updates, new_params, mask=None, agent_bad: bool = True):
     """The vmap-path sentinel dict (single-device, cohort, host,
     megabatch, buffered — every path whose updates hold the full [m]
     cohort). Pure jnp reductions, zero collectives."""
-    bad, nsq = _row_stats(updates, mask)
+    return from_row_stats(*_row_stats(updates, mask), new_params, agent_bad)
+
+
+def from_row_stats(bad, nsq, new_params, agent_bad: bool = True):
+    """The sentinel dict from per-client (bad bits, squared norms): what
+    `sentinel` reads off a stack, and what a folded round
+    (fl/rounds._fold_core) gathers chunk by chunk with `_row_stats`."""
     out = {"hlth_nonfinite": jnp.sum(bad.astype(jnp.float32)),
            "hlth_update_normsq": jnp.sum(nsq),
            "hlth_params_finite": params_finite_bit(new_params)}

@@ -2,6 +2,8 @@
 
 from __future__ import annotations
 
+import importlib
+
 import jax
 import jax.numpy as jnp
 
@@ -18,20 +20,30 @@ REMAT_POLICIES = ("block", "conv")
 
 def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
               n_classes: int = 10, remat: bool = False,
-              remat_policy: str = "block"):
+              remat_policy: str = "block", cfg=None):
     """fmnist/fedemnist -> CNN_MNIST; cifar10 -> CNN_CIFAR (src/models.py:4-8);
     arch='resnet9' selects the BASELINE north-star ResNet-9 extension.
     `remat` enables rematerialization (ResNet-9 only; the small CNNs'
     activations never pressure HBM); `remat_policy` picks full blockwise
     ("block") or selective save-conv-outputs ("conv") recompute. It takes
     the RESOLVED policy: `--remat_policy auto` is a rule over the device's
-    memory (utils/compile_cache.resolved_remat), not a model property."""
+    memory (utils/compile_cache.resolved_remat), not a model property.
+    An arch in `TOKEN_ARCHS` is a token task's model: it reads its widths
+    and its cut from `cfg`, which it needs, and under `remat` recomputes
+    block by block. This module is the one place that knows a model by its
+    name: the engine, the planner and the data layer ask the model (or
+    `arch_takes_tokens`, `token_vocab`) what it is."""
     if remat_policy not in REMAT_POLICIES:
         raise ValueError(
             f"remat_policy must be one of {REMAT_POLICIES}, got "
             f"{remat_policy!r} (resolve 'auto' with "
             f"compile_cache.resolved_remat first)")
     dt = _DTYPES[dtype]
+    if arch in TOKEN_ARCHS:
+        if cfg is None:
+            raise ValueError(f"arch {arch!r} reads its widths and its cut "
+                             f"from the configuration: pass cfg=")
+        return _token_module(arch).from_cfg(cfg, dtype=dt, remat=remat)
     if arch == "resnet9":
         return ResNet9(n_classes=n_classes, dtype=dt, remat=remat,
                        remat_policy=remat_policy)
@@ -42,9 +54,47 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     raise ValueError(f"no model for data={data!r} arch={arch!r}")
 
 
-def init_params(model, image_shape, key=None, batch: int = 2):
+# archs whose batch is `[bs, T + 1]` token ids, and the module of each: it
+# has `from_cfg(cfg, dtype, remat)` and `vocab_from_cfg(cfg)`, and its model
+# carries `takes_tokens = True`, `pairs_shape` and `build_counters()`
+TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe"}
+
+
+def _token_module(arch: str):
+    return importlib.import_module(
+        f"{__package__}.{TOKEN_ARCHS[arch]}")
+
+
+def arch_takes_tokens(arch: str) -> bool:
+    return arch in TOKEN_ARCHS
+
+
+def takes_tokens(model) -> bool:
+    return bool(getattr(model, "takes_tokens", False))
+
+
+def token_vocab(cfg) -> int:
+    """Vocabulary rows the configured token model holds: what the token
+    task's generator draws ids from (data/tokens.py)."""
+    if not arch_takes_tokens(cfg.model_arch):
+        raise ValueError(
+            f"--arch={cfg.model_arch} is no token model: pass one of "
+            f"--arch={'|'.join(TOKEN_ARCHS)}")
+    return _token_module(cfg.model_arch).vocab_from_cfg(cfg)
+
+
+def init_params(model, example_shape, key=None, batch: int = 2):
+    """Parameters from one abstract batch of the task: `example_shape` is
+    an image's [H, W, C], or a token sequence's [T]."""
     key = key if key is not None else jax.random.PRNGKey(0)
-    x = jnp.zeros((batch,) + tuple(image_shape), jnp.float32)
+    if takes_tokens(model):
+        # no shape of a parameter depends on T or on the batch, and the
+        # whole init is one program (506M parameters drawn eagerly would
+        # be a program a leaf)
+        x = jnp.zeros((1, min(8, int(example_shape[0]))), jnp.int32)
+        return jax.jit(lambda k: model.init(
+            {"params": k}, x, train=False)["params"])(key)
+    x = jnp.zeros((batch,) + tuple(example_shape), jnp.float32)
     return model.init({"params": key}, x, train=False)["params"]
 
 

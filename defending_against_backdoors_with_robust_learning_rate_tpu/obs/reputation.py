@@ -139,6 +139,10 @@ def reputation_on(cfg) -> bool:
         return False
     if cfg.reputation == "on":
         return True
+    if cfg.agg_path == "fold":
+        # a folded round never holds the updates beside the committed
+        # vote (fl/rounds._fold_core): `auto` stands down, `on` is refused
+        return False
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
         _pallas_applicable)
     # normalize diagnostics: the engine builds a plain/diag program PAIR

@@ -256,6 +256,67 @@ def aggregate_updates(stacked_updates, data_sizes, cfg, key, mask=None):
     return agg
 
 
+# ---- the fold: the sum-shaped rules without the [m, ...] stack -----------
+#
+# avg, sign and the RLR vote are sums over clients, so a round may add each
+# client's update to three accumulators as it arrives (weighted sum, sign
+# sum, weight total) and never hold the stack. The accumulators are float32
+# like the stack's reductions; chunks add in arrival order, so a folded
+# round agrees with the stacked one to float32 round-off of the sums and
+# exactly in the vote. The sign sum of up to 127 clients is held in int8 (a
+# byte a parameter where float32 takes four; exact, since |sum| <= m).
+SIGN_SUM_INT8_MAX_AGENTS = 127
+
+
+def fold_init(params, agents: int, want_avg: bool, want_sign: bool):
+    """Zeroed accumulators shaped like `params` for a round of `agents`
+    clients; a rule that needs no weighted sum (sign) or no sign sum (avg
+    without RLR) carries None."""
+    sdt = jnp.int8 if agents <= SIGN_SUM_INT8_MAX_AGENTS else jnp.float32
+
+    def zeros(dtype):
+        return tree.map(lambda p: jnp.zeros(p.shape, dtype), params)
+    return {"wsum": zeros(jnp.float32) if want_avg else None,
+            "ssum": zeros(sdt) if want_sign else None,
+            "n": jnp.float32(0.0)}
+
+
+def fold_updates(acc, stacked_updates, data_sizes):
+    """Add a chunk of clients ([c, ...] per leaf, sizes [c]) to the
+    accumulators."""
+    w = data_sizes.astype(jnp.float32)
+
+    def wleaf(a, u):
+        return a + jnp.sum(u * w.reshape((-1,) + (1,) * (u.ndim - 1)),
+                           axis=0)
+    return {
+        "wsum": (None if acc["wsum"] is None
+                 else tree.map(wleaf, acc["wsum"], stacked_updates)),
+        "ssum": (None if acc["ssum"] is None
+                 else tree.map(
+                     lambda a, u: a + jnp.sum(jnp.sign(u), axis=0).astype(
+                         a.dtype), acc["ssum"], stacked_updates)),
+        "n": acc["n"] + jnp.sum(w)}
+
+
+def fold_finish(acc, cfg, key, threshold, server_lr):
+    """(lr tree or scalar, aggregate) from the accumulators: the same tail
+    as the stacked round's `robust_lr` + `aggregate_updates`."""
+    lr = (tree.map(lambda s: rlr_from_sign_sum(s, threshold, server_lr),
+                   acc["ssum"]) if threshold is not None else server_lr)
+    if cfg.aggr == "avg":
+        agg = tree.map(lambda s: s / acc["n"], acc["wsum"])
+    elif cfg.aggr == "sign":
+        agg = tree.map(lambda s: jnp.sign(s).astype(jnp.float32),
+                       acc["ssum"])
+    else:
+        raise ValueError(f"aggr {cfg.aggr!r} is not a sum over clients")
+    if cfg.noise > 0:
+        agg = tree.add(agg, gaussian_noise_like(agg, key,
+                                                cfg.noise * cfg.clip))
+    return lr, agg
+
+
 def apply_aggregate(params, lr_tree_or_scalar, aggregated):
     """global <- global + lr ⊙ aggregate, f32 (src/aggregation.py:38-40)."""
     lr = lr_tree_or_scalar

@@ -52,6 +52,30 @@ def cpu_backend() -> bool:
     return jax.default_backend() == "cpu"
 
 
+# A rolled loop keeps its carry in buffers of its own, and the compiler
+# holds a second copy of each beside them: on the v5e a two-epoch client
+# loop over 2 GB of parameters and 2 GB of momentum took 4 GB more rolled
+# than traced flat (XLA's memory analysis, PERF.md section 6, PR 27). So a
+# short loop whose carried parameters are a sixteenth of the device or more
+# is traced flat; ResNet-9's 26 MB a client stay rolled.
+LARGE_CARRY_SHARE = 16
+LARGE_CARRY_MAX_STEPS = 8
+
+
+def carry_is_large(params, steps: int) -> bool:
+    """Whether a loop of `steps` steps that carries `params` (and as much
+    optimizer state again) should be traced flat on this device."""
+    if steps > LARGE_CARRY_MAX_STEPS:
+        return False
+    stats = jax.local_devices()[0].memory_stats()
+    limit = stats.get("bytes_limit") if stats else None
+    if not limit:
+        return False
+    nbytes = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree_util.tree_leaves(params))
+    return LARGE_CARRY_SHARE * nbytes >= limit
+
+
 def maybe_unrolled_scan(body, init, xs, python_mode: bool):
     """Drop-in for `jax.lax.scan(body, init, xs)` (no length/reverse args).
 

@@ -20,6 +20,7 @@ recovery possible: every step is re-enterable from restored state."""
 
 from __future__ import annotations
 
+import collections
 import os
 import time
 from typing import Dict, Optional
@@ -39,7 +40,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.fl.evaluate impor
 from defending_against_backdoors_with_robust_learning_rate_tpu.attack import (
     registry as attack_registry)
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
-    buffered as buffered_mod)
+    buffered as buffered_mod, task as task_mod)
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
     CHAINED_INFO_KEYS, FAULT_INFO_KEYS, host_takes_flags, make_round_fn,
     make_round_fn_host, step_takes_round)
@@ -51,7 +52,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
     reputation as obs_reputation, spans as obs_spans,
     telemetry as obs_telemetry)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
-    get_model, init_params, param_count)
+    abstract_params, get_model, init_params, param_count)
 from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
     checkpoint as ckpt, compile_cache)
 from defending_against_backdoors_with_robust_learning_rate_tpu.utils.guards import (
@@ -129,6 +130,26 @@ def apply_rng_impl(choice: str) -> str:
     return impl
 
 
+# Each unit's new parameters are allocated at dispatch, so the parameters of
+# units dispatched and not yet run may hold this share of the device's
+# memory. The dispatch loop never waits below two units in flight, and keeps
+# no count where more than UNCOUNTED_AHEAD units fit the share (ResNet-9: 81).
+DISPATCH_AHEAD_SHARE = 8
+UNCOUNTED_AHEAD = 8
+
+
+def units_ahead(param_bytes: int, limit_bytes: Optional[int]
+                ) -> Optional[int]:
+    """How many units the dispatch loop lets the host run ahead of the
+    device, from the bytes a unit's new parameters take against the
+    device's `bytes_limit`; None where it keeps no count (the backend
+    reports no limit, or the parameters are small)."""
+    if not limit_bytes:
+        return None
+    fit = limit_bytes // (DISPATCH_AHEAD_SHARE * max(param_bytes, 1))
+    return None if fit > UNCOUNTED_AHEAD else max(2, int(fit))
+
+
 class RoundEngine:
     """Resumable round engine: program building, restored state, and the
     loop body as explicit re-enterable steps.
@@ -188,6 +209,12 @@ class RoundEngine:
         # one-shot trainer) keeps newest-valid semantics. The producer
         # (prepare_crash_exact_resume) has already digest-validated that
         # round, so restore skips re-hashing it.
+        # what the token task, or a fold asked for by hand, cannot run is
+        # refused here, before anything is built (the rule itself never
+        # picks a fold the configuration could not run)
+        refused = compile_cache.unsupported(cfg, cfg.agg_path == "fold")
+        if refused:
+            raise ValueError(refused[0])
         if cfg.tenants > 0:
             # the tenant axis is the experiment QUEUE's pack knob
             # (service/queue.py --tenants routes shape-compatible cells
@@ -265,12 +292,6 @@ class RoundEngine:
         # before any build; the lane itself resolves after the pallas
         # decision (`auto` rides the jnp paths only)
         obs_reputation.check(cfg)
-        self._rep_on = obs_reputation.reputation_on(cfg)
-        if self._rep_on:
-            print(f"[reputation] per-client suspicion lanes: rep_agree + "
-                  f"rep_norm ride the round program (zero added "
-                  f"collectives); host ledger keyed by real client ids "
-                  f"(--reputation off disables)")
         # persistent XLA cache + AOT executable bank — must be configured
         # before the first compile so every program family persists
         bank = compile_cache.setup(cfg)
@@ -303,7 +324,7 @@ class RoundEngine:
             else:
                 fed = get_federated_data(cfg)
         tracer.count("data_bytes_host", fed.nbytes)
-        if fed.synthetic and cfg.data != "synthetic":
+        if fed.synthetic and cfg.data not in ("synthetic", "tokens"):
             print(f"[data] {cfg.data} files not found under "
                   f"{cfg.data_dir!r}; using the deterministic synthetic "
                   f"fallback")
@@ -319,8 +340,24 @@ class RoundEngine:
             cfg = self.cfg = cfg.replace(remat_policy=remat.policy)
             model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
                               remat=cfg.remat,
-                              remat_policy=cfg.remat_policy)
-            params = init_params(model, fed.train.images.shape[2:],
+                              remat_policy=cfg.remat_policy, cfg=cfg)
+            # what a model wants counted once at build (a token model: the
+            # experts and vocabulary rows it holds)
+            for name, value in getattr(model, "build_counters", dict)().items():
+                tracer.count(name, value)
+            example_shape = task_mod.input_shape(cfg, fed)
+            # stack or fold, settled here from the stack's bytes and what
+            # the device has free (compile_cache.resolved_agg), before a
+            # parameter exists; from here on cfg carries the resolved
+            # path, so the bank's fingerprint keys the program that is built
+            agg = compile_cache.resolved_agg(
+                cfg, param_count(abstract_params(model, example_shape)))
+            cfg = self.cfg = cfg.replace(agg_path=agg.path)
+            tracer.count("agg_path", path=agg.path)
+            tracer.count("agg_stack_bytes", agg.stack_bytes)
+            tracer.count("agg_limit_bytes", agg.limit_bytes or 0)
+            print(f"[agg] {agg.describe()}")
+            params = init_params(model, example_shape,
                                  jax.random.PRNGKey(cfg.seed))
             print(f"[model] {type(model).__name__}: "
                   f"{param_count(params):,} params")
@@ -328,9 +365,23 @@ class RoundEngine:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)
                 tracer.count("remat_limit_bytes", remat.limit_bytes or 0)
-                print(f"[model] {remat.describe()}")
+                print("[model] remat: every block recomputed in backward"
+                      if task_mod.is_tokens(cfg)
+                      else f"[model] {remat.describe()}")
             norm = make_normalizer(fed.mean, fed.std,
                                    fed.raw_is_normalized)
+        # the lane resolves after the aggregation path: a folded round
+        # never holds the updates beside the committed vote
+        self._rep_on = obs_reputation.reputation_on(cfg)
+        if self._rep_on:
+            print(f"[reputation] per-client suspicion lanes: rep_agree + "
+                  f"rep_norm ride the round program (zero added "
+                  f"collectives); host ledger keyed by real client ids "
+                  f"(--reputation off disables)")
+        elif agg.path == "fold" and obs_reputation.wants_vote(cfg) \
+                and cfg.reputation == "auto":
+            print("[reputation] off (auto): a folded round never holds the "
+                  "updates beside the committed vote")
 
         # single source with the precompile planner
         # (compile_cache.is_host_mode) so banked families always match what
@@ -844,7 +895,11 @@ class RoundEngine:
                       f"back to the jnp path")
 
         with tracer.span("setup/build_programs"):
-            eval_fn = make_eval_fn(model, norm, cfg.n_classes)
+            # the image task's builder stays this module's own name: the
+            # benchmark's defect tests patch `train.make_eval_fn`
+            eval_fn = (task_mod.make_eval_fn(model, norm, cfg)
+                       if task_mod.is_tokens(cfg)
+                       else make_eval_fn(model, norm, cfg.n_classes))
             self._fisher_fn = None
             if cfg.diagnostics:
                 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.diagnostics import (
@@ -1101,6 +1156,14 @@ class RoundEngine:
         self._chained_fn, self._host_chained_fn = chained_fn, host_chained_fn
         self._eval_val_fn, self._eval_pval_fn = eval_val_fn, eval_pval_fn
         self._last_info = {}
+        # a dispatch allocates the unit's new parameters at once, however
+        # many units the device still has ahead of it: the loop waits for
+        # an earlier unit where one more would not fit `units_ahead`
+        self._units_ahead = units_ahead(
+            sum(x.size * x.dtype.itemsize for x in
+                jax.tree_util.tree_leaves(self.model_params)),
+            compile_cache.device_memory_limit())
+        self._in_flight = collections.deque()
         self._last_unit_rounds = 1
         self._want_diag = False
         self._prev_params = None
@@ -1175,6 +1238,11 @@ class RoundEngine:
             # steady state: every hot-path program compiled during the
             # first unit, so the window never captures XLA working
             self.prof.maybe_start()
+        while self._units_ahead and len(
+                self._in_flight) >= self._units_ahead:
+            with tracer.span("round/wait_room"):
+                # static: ok(host-sync)
+                self._in_flight.popleft().block_until_ready()
         if len(unit) > 1:
             # chained block: fixed length => one compilation per shape
             with tracer.span("round/data_prep"):
@@ -1250,6 +1318,9 @@ class RoundEngine:
                                           info["rep_agree"],
                                           info["rep_norm"]))
         self._last_info = info
+        if self._units_ahead:
+            # the unit's loss: an output of its program that nothing donates
+            self._in_flight.append(info["train_loss"])
         if self.prof is not None:
             # accounts the unit toward the capture budget and polls the
             # HBM watermarks; closes the window (blocking on params first)
@@ -1353,10 +1424,17 @@ class RoundEngine:
             poison_loss_d, poison_acc_d, _ = self._eval_pval_fn(
                 self.model_params, *self.pval)
         vals.update(val_loss=val_loss_d, val_acc=val_acc_d,
-                    base_acc=per_class_d[cfg.base_class],
                     poison_loss=poison_loss_d,
                     poison_acc=poison_acc_d,
                     train_loss=info["train_loss"])
+        if task_mod.is_tokens(cfg):
+            # the eval's third value is the pairs its tokens were routed
+            # to, and the round's router counters ride the same fetch
+            vals["moe_eval_pairs"] = per_class_d
+            vals.update({k: info[k] for k in task_mod.MOE_ROUND_KEYS
+                         if k in info})
+        else:
+            vals["base_acc"] = per_class_d[cfg.base_class]
         if "fault_voters" in info:
             vals.update({k: info[k] for k in FAULT_INFO_KEYS})
         if "churn_away" in info:
@@ -1435,13 +1513,29 @@ class RoundEngine:
         # scalar names preserved from src/federated.py:81-91
         writer.scalar("Validation/Loss", val_loss, ernd)
         writer.scalar("Validation/Accuracy", val_acc, ernd)
-        writer.scalar("Poison/Base_Class_Accuracy",
-                      float(vals["base_acc"]), ernd)
+        if "base_acc" in vals:
+            writer.scalar("Poison/Base_Class_Accuracy",
+                          float(vals["base_acc"]), ernd)
         writer.scalar("Poison/Poison_Accuracy", poison_acc, ernd)
         writer.scalar("Poison/Poison_Loss", poison_loss, ernd)
         writer.scalar("Poison/Cumulative_Poison_Accuracy_Mean",
                       cum_poison_acc / ernd, ernd)
         writer.scalar("Train/Loss", float(vals["train_loss"]), ernd)
+        if "moe_eval_pairs" in vals:
+            # router load (fl/task.py): the validation tokens' pairs by
+            # sparse layer and held expert (last column: experts not held
+            # here), and the round's counters, which also enter the tracer
+            for li, row in enumerate(np.asarray(vals["moe_eval_pairs"])):
+                for e, c in enumerate(row[:-1]):
+                    writer.scalar(f"Moe/Eval_Pairs/L{li}E{e}", float(c),
+                                  ernd)
+                writer.scalar(f"Moe/Eval_Pairs/L{li}Absent", float(row[-1]),
+                              ernd)
+            for k in task_mod.MOE_ROUND_KEYS:
+                if k in vals:
+                    writer.scalar("Moe/" + k[4:].title(), float(vals[k]),
+                                  ernd)
+                    self.tracer.count(k, float(vals[k]))
         if "fault_voters" in vals:
             # degradation observability (faults/ + service/churn.py): who
             # failed this round, and how thin the electorate got

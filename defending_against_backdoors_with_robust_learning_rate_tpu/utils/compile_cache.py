@@ -81,6 +81,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 # config fields that do not change the compiled program (pure IO/driver
 # knobs). `snap`/`rounds`/`seed`/`chain` only alter which/how many
@@ -375,7 +376,9 @@ def resolved_remat(cfg, fed=None,
         raise ValueError(
             f"remat_policy must be 'auto' or one of {REMAT_POLICIES}, got "
             f"{cfg.remat_policy!r}")
-    if not cfg.remat:
+    if not cfg.remat or cfg.data == "tokens":
+        # the token model tags no tensor: under --remat it recomputes block
+        # by block, whatever the device has free
         return RematChoice("block", 0, None)
     per_example, n_params = _remat_shapes(
         cfg.data, cfg.model_arch, cfg.dtype, tuple(cfg.image_shape))
@@ -396,6 +399,132 @@ def resolved_remat(cfg, fed=None,
     policy = (remat_policy_for(per_example, in_flight, limit) if chosen
               else cfg.remat_policy)
     return RematChoice(policy, per_example * in_flight, limit, chosen)
+
+
+# The round folds where the update stack would take more than this share of
+# what the device has free beside one chunk of clients in training. A
+# stack that takes half of it leaves the other half to the step's
+# activations; `cifar-resnet9` (1.05 GB of 16 GB free) is far below it, a
+# language model's stack (20 GB at 508M parameters and ten clients) far
+# above.
+AGG_STACK_SHARE_DIVISOR = 2
+AGG_PATHS = ("stack", "fold")
+
+
+@dataclasses.dataclass(frozen=True)
+class AggChoice:
+    """What `resolved_agg` settled, and on what."""
+    path: str                    # "stack" | "fold"
+    stack_bytes: int             # the [m, n_params] float32 update stack
+    limit_bytes: Optional[int]   # the device's limit less what a step
+    #                              holds; None: the backend reports none
+    chosen: bool = False         # by the rule, not by the caller
+
+    def describe(self) -> str:
+        held = ("no memory limit reported by this backend"
+                if self.limit_bytes is None else
+                f"{self.limit_bytes / 1e9:.2f} GB free of the device's "
+                f"limit, a 1/{AGG_STACK_SHARE_DIVISOR} share of it allowed")
+        return (f"aggregation path {self.path} "
+                f"({'auto' if self.chosen else 'as asked'}): the update "
+                f"stack would take {self.stack_bytes / 1e9:.2f} GB; {held}")
+
+
+def agg_path_for(stack_bytes: int, free_bytes: Optional[int]) -> str:
+    """The rule on numbers alone: `fold` when the stack does not fit its
+    share of `free_bytes`; a backend that reports no limit (None) keeps the
+    stack, as it always did."""
+    if free_bytes is None:
+        return "stack"
+    return ("stack" if AGG_STACK_SHARE_DIVISOR * stack_bytes <= free_bytes
+            else "fold")
+
+
+def resolved_agg(cfg, n_params: int) -> AggChoice:
+    """Single source of whether a round holds the `[m, n_params]` update
+    stack or folds each chunk of clients into the vote as it arrives
+    (ROADMAP R3), from what the program can observe, as `resolved_remat`
+    does: the stack's bytes against the device's `bytes_limit` less what a
+    client step holds beside it (the global parameters, and parameters,
+    gradient and momentum of each client trained at once). `cfg.agg_path`
+    `stack` or `fold` is honoured (it has no flag: the engine writes the
+    resolved path back, tests and the static gate's specs set it). `auto`
+    never picks a fold the configuration could not run (`unsupported`):
+    such a round keeps the stack and fits or fails as before."""
+    if cfg.agg_path not in ("auto",) + AGG_PATHS:
+        raise ValueError(f"agg_path must be 'auto' or one of {AGG_PATHS}, "
+                         f"got {cfg.agg_path!r}")
+    agents = cfg.agents_per_round
+    stack = 4 * n_params * agents
+    if cfg.agg_path != "auto":
+        return AggChoice(cfg.agg_path, stack, None)
+    limit = device_memory_limit()
+    if limit is None or unsupported(cfg.replace(agg_path="fold"),
+                                    folded=True):
+        return AggChoice("stack", stack, limit, True)
+    at_once = cfg.agent_chunk if 0 < cfg.agent_chunk < agents else agents
+    free = limit - 4 * n_params * (1 + 3 * at_once)
+    return AggChoice(agg_path_for(stack, free), stack, free, True)
+
+
+def unsupported(cfg, folded: bool) -> List[str]:
+    """One sentence for each thing `cfg` asks for that the token task or a
+    folded round cannot do; empty where all of it runs. The engine raises
+    on the first entry before it builds anything."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        buffered, task)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+        registry as models)
+    tokens = task.is_tokens(cfg)
+    token_model = models.arch_takes_tokens(cfg.model_arch)
+    if token_model and not tokens:
+        return [f"--arch={cfg.model_arch} takes token ids: pass "
+                f"--data=tokens."]
+    if not (tokens or folded):
+        return []
+    who = "the token task (--data=tokens)" if tokens else "a folded round"
+    fold = "a folded round"
+    rules = [
+        (tokens and not token_model, who,
+         f"trains a token model: pass --arch={'|'.join(models.TOKEN_ARCHS)}."),
+        (tokens and resolved_train_layout(cfg) == "megabatch", who,
+         "has no megabatch trainer (fl/client.make_local_train_megabatch "
+         "folds image batches): use --train_layout vmap."),
+        (cfg.mesh != 1, who,
+         "does not run under --mesh: the sharded body (parallel/rounds.py) "
+         "takes the update stack and image shards."),
+        (cfg.chain > 1, who,
+         "is dispatched a round at a time: drop --chain (the chained scan "
+         "carries the stack's per-round lanes)."),
+        (cfg.host_sampled == "on" or is_cohort_mode(cfg), who,
+         "runs device-resident only: the host-sampled and cohort surfaces "
+         "gather image rows (--host_sampled off, no --cohort_size)."),
+        (cfg.tenants > 0, who,
+         "has no tenant-packed family: drop --tenants."),
+        (buffered.is_buffered(cfg), who,
+         "does not buffer: use --agg_mode sync."),
+        (cfg.use_pallas, who,
+         "has no Pallas server step (the kernel reads the whole stack): "
+         "drop --use_pallas."),
+        (cfg.diagnostics, who,
+         "writes no --diagnostics (they need the learning-rate vector and "
+         "every client's norms against it)."),
+        (folded and cfg.aggr not in task.FOLD_RULES, fold,
+         f"sums clients as they arrive, and --aggr={cfg.aggr} needs every "
+         f"update at once (comed, trmean, krum and rfa keep the stack)."),
+        (folded and cfg.telemetry != "off", fold,
+         "never holds the updates beside the committed vote: --telemetry's "
+         "per-client rows need the stack."),
+        (folded and cfg.reputation == "on", fold,
+         "never holds the updates beside the committed vote: --reputation "
+         "on needs the stack (auto resolves off)."),
+        (folded and (cfg.faults_enabled or cfg.churn_enabled
+                     or cfg.traffic_enabled or bool(cfg.quarantine)), fold,
+         "has no participation mask yet: faults, churn, diurnal traffic "
+         "and --quarantine ride the stacked round."),
+    ]
+    return [f"{subject} {sentence}" for cond, subject, sentence in rules
+            if cond]
 
 
 def family_suffix(cfg) -> str:
@@ -445,6 +574,17 @@ def fingerprint(cfg, family: str, example_args) -> str:
     # likewise the RESOLVED remat policy: `auto` shares the key of what
     # it resolves to, and is never a key of its own
     fields["remat_policy"] = resolved_remat(cfg).policy
+    # and the RESOLVED aggregation path, from the parameters the program
+    # takes (its lead argument; in buffered mode the carry's first half):
+    # the engine writes `stack` or `fold` into its cfg, a planner may leave
+    # `auto`, and both must ask for the same key
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        buffered)
+    lead = example_args[0] if example_args else ()
+    if buffered.is_buffered(cfg) and isinstance(lead, tuple):
+        lead = lead[0]
+    fields["agg_path"] = resolved_agg(cfg, sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(lead))).path
     if fields.get("tenants", 0) > 0:
         # tenant packs (fl/tenancy.py): the per-tenant scalar knobs are
         # traced [E]-vector ARGUMENTS of the *_mt programs, so their
@@ -815,7 +955,7 @@ def plan_programs(cfg, model, norm, fed,
     only (their executables embed the live mesh) and are not planned here.
     """
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.evaluate import (
-        make_eval_fn, pad_eval_set)
+        pad_eval_set)
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
         host_takes_flags, make_chained_cohort_round_fn,
         make_chained_round_fn, make_chained_round_fn_host,
@@ -832,11 +972,18 @@ def plan_programs(cfg, model, norm, fed,
     cohort_mode = is_cohort_mode(cfg, fed)
     if host_mode is None:
         host_mode = (not cohort_mode) and is_host_mode(cfg, fed)
-    image_shape = fed.train.images.shape[2:]
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        task)
     params_aval = jax.eval_shape(
-        lambda k: init_params(model, image_shape, k), jax.random.PRNGKey(0))
+        lambda k: init_params(model, task.input_shape(cfg, fed), k),
+        jax.random.PRNGKey(0))
     # buffered mode: round programs take the (params, buffer-state)
     # carry as their lead argument; eval programs keep bare params
+    # stack or fold, resolved here as the engine resolves it (it hands its
+    # cfg over resolved): the programs built below read `cfg.agg_path`
+    cfg = cfg.replace(agg_path=resolved_agg(cfg, sum(
+        int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params_aval))
+    ).path)
     lead_aval = carry_aval(cfg, params_aval)
     key_aval = abstractify(jax.random.PRNGKey(0))
     data_avals = abstractify((fed.train.images, fed.train.labels,
@@ -974,7 +1121,7 @@ def plan_programs(cfg, model, norm, fed,
                                       *data_avals).jitted,
                 (lead_aval, key_aval, ids_aval) + data_avals))
 
-    eval_fn = make_eval_fn(model, norm, cfg.n_classes)
+    eval_fn = task.make_eval_fn(model, norm, cfg)
     for family, (imgs, lbls) in (
             ("eval_val", (fed.val_images, fed.val_labels)),
             ("eval_poison", (fed.pval_images, fed.pval_labels))):
@@ -1006,9 +1153,11 @@ def plan_sharded_programs(cfg, model, norm, fed, mesh,
     # below must agree with the engine's diagnostics degrade)
     cfg = cfg.replace(train_layout=resolved_train_layout(cfg))
     sfx = family_suffix(cfg)
-    image_shape = fed.train.images.shape[2:]
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        task)
     params_aval = jax.eval_shape(
-        lambda k: init_params(model, image_shape, k), jax.random.PRNGKey(0))
+        lambda k: init_params(model, task.input_shape(cfg, fed), k),
+        jax.random.PRNGKey(0))
     # buffered mode: the sharded round programs take the (params,
     # buffer-state) carry — the sharded layout never carries the per-bin
     # telemetry accumulators (fl/buffered.init_state)

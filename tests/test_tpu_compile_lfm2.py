@@ -1,0 +1,97 @@
+"""The token model's layers compiled for a described TPU v5e at the
+published widths, with no chip attached: what interpret-free CPU tests
+cannot show (the TPU compiler takes `jax.lax.ragged_dot` as its own grouped
+product, and the step's temporaries fit). Nothing runs, so nothing here is a
+time or a result. All of it lives in this one file: only the worker that is
+given the file loads the TPU's library, inside the fixture."""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+    lfm2_moe as lm)
+
+TOKENS = 8192          # a client's step: 4 sequences of 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:    # noqa: BLE001 - any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def quiet_cache():
+    """A compile for a described device is written to the persistent cache
+    and cannot be read back without a chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _aval(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("what", ["sparse_ffn", "short_conv", "attention"])
+def test_layer_compiles_for_the_v5e_at_published_widths(one_chip,
+                                                        quiet_cache, what):
+    spec = lm.spec_from("lfm2-8b-a1b", "0,2,3,4,5", 8, 0, 16384)
+    d, f, e = spec.hidden, spec.moe_ffn, spec.experts_held
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if what == "sparse_ffn":
+        p = {"gate": (d, spec.n_experts), "experts_w1": (e, d, f),
+             "experts_w3": (e, d, f), "experts_w2": (e, f, d)}
+        x = _aval((TOKENS, d), bf16, one_chip)
+
+        def fn(p, x):
+            return lm.sparse_ffn(p, x, spec, 2, bf16)
+    elif what == "short_conv":
+        p = {"conv_in_proj": (d, 3 * d), "conv_weight": (spec.conv_taps, d),
+             "conv_out_proj": (d, d)}
+        x = _aval((4, 2048, d), bf16, one_chip)
+
+        def fn(p, x):
+            return lm.short_conv(p, x, spec, bf16), ()
+    else:
+        hq, hkv = spec.heads * spec.head_dim, spec.kv_heads * spec.head_dim
+        p = {"q_proj": (d, hq), "k_proj": (d, hkv), "v_proj": (d, hkv),
+             "o_proj": (hq, d), "q_norm": (spec.head_dim,),
+             "k_norm": (spec.head_dim,)}
+        x = _aval((4, 2048, d), bf16, one_chip)
+
+        def fn(p, x):
+            return lm.attention(p, x, spec, bf16), ()
+    p = {k: _aval(s, f32, one_chip) for k, s in p.items()}
+
+    def loss(p, x):
+        out, _aux = fn(p, x)
+        return jnp.sum(out.astype(f32))
+
+    # the suite runs at matmul precision `highest` (conftest.py) and the
+    # TPU's grouped product takes no bfloat16 operands at float32
+    # precision: the layer asks for the default itself
+    compiled = jax.jit(jax.grad(loss)).lower(p, x).compile()
+    text = compiled.as_text()
+    if what == "sparse_ffn":
+        # forward and both transposes of three grouped products
+        assert text.count('op_name="ragged-dot') >= 3 or \
+            text.count("ragged-dot") >= 3
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    # one layer's backward, without the round's accumulators: well under
+    # the 4.7 GB the cut leaves for a step's activations
+    assert 0 < temp < 3 * 2 ** 30, temp
