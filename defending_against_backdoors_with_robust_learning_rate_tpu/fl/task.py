@@ -30,6 +30,9 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.ops import loops
 # steps: [sparse layers, experts_held + 1] (token, expert) pairs, the last
 # column those routed to experts this chip does not hold
 MOE_PAIRS = "moe_pairs"
+# and how many of its sparse-layer forwards held more pairs than the sorted
+# buffer's first pass takes (`model.dispatch_rows`): the second pass ran
+MOE_OVERFLOW = "moe_overflow"
 # the aggregation rules a folded round can run: sums over clients
 FOLD_RULES = ("avg", "sign")
 
@@ -55,8 +58,11 @@ def make_batch_loss(model, cfg, normalize, deterministic: bool = False):
                 logits, x[:, 1:])
             wf = w.astype(jnp.float32)
             n = jnp.maximum(jnp.sum(wf) * ce.shape[1], 1.0)
+            over = (jnp.sum(pairs[:, :-1], axis=1)
+                    > model.dispatch_rows(ce.size))
             return (jnp.sum(ce * wf[:, None]) / n,
-                    {MOE_PAIRS: pairs.astype(jnp.float32)})
+                    {MOE_PAIRS: pairs.astype(jnp.float32),
+                     MOE_OVERFLOW: jnp.sum(over, dtype=jnp.float32)})
         return token_loss
 
     def image_loss(params, x, y, w, rng):
@@ -86,8 +92,9 @@ def split_per_client(per):
 
 def round_counters(sums: Dict) -> Dict:
     """The round's drained counters from the per-client sums: pairs
-    computed here, pairs routed to absent experts, and the largest and the
-    mean load of a held expert (over layers, clients and steps)."""
+    computed here, pairs routed to absent experts, the largest and the
+    mean load of a held expert (over layers, clients and steps), and the
+    sparse-layer forwards that took the second pass."""
     if MOE_PAIRS not in sums:
         return {}
     pairs = jnp.sum(sums[MOE_PAIRS], axis=0)          # [layers, held + 1]
@@ -95,11 +102,12 @@ def round_counters(sums: Dict) -> Dict:
     return {"moe_pairs_held": jnp.sum(held),
             "moe_pairs_absent": jnp.sum(pairs[:, -1]),
             "moe_load_max": jnp.max(held) if held.size else jnp.float32(0),
-            "moe_load_mean": jnp.mean(held) if held.size else jnp.float32(0)}
+            "moe_load_mean": jnp.mean(held) if held.size else jnp.float32(0),
+            "moe_overflow_steps": jnp.sum(sums[MOE_OVERFLOW])}
 
 
 MOE_ROUND_KEYS = ("moe_pairs_held", "moe_pairs_absent", "moe_load_max",
-                  "moe_load_mean")
+                  "moe_load_mean", "moe_overflow_steps")
 
 
 def make_eval_fn(model, normalize, cfg):

@@ -21,8 +21,11 @@ vocabulary. Equations as the source's `lfm2_moe` implementation has them:
   `w = s[sel] / (sum s[sel] + 1e-6)`, and of `y = sum_e w_e expert_e(x)` the
   terms whose expert is held here. That partial sum goes on to the next
   layer; nothing stands in for the experts of other chips. No (token,
-  expert) pair is dropped: pairs are sorted by expert and the held experts'
-  products are three `jax.lax.ragged_dot` calls over the sorted rows.
+  expert) pair is dropped: pairs are sorted by expert, those held first,
+  and the held experts' products are three `jax.lax.ragged_dot` calls over
+  the first `dispatch_rows` sorted rows, twice the share of the pairs that
+  the held experts draw in expectation; a step that holds more computes
+  the rest in a second pass under a `jax.lax.cond`.
 
 Precision (`--dtype bf16`): parameters stay float32; matrix products take
 bfloat16 operands; router logits, sigmoid, softmax, norms and the logits
@@ -46,6 +49,11 @@ import numpy as np
 _HERE = os.path.dirname(os.path.abspath(__file__))
 PUBLISHED = {"lfm2-8b-a1b": os.path.join(_HERE, "lfm2_8b_a1b.json")}
 ATTN_QUERY_BLOCK = 512     # attention runs over query blocks of this many
+# the sorted buffer of a sparse layer holds MOE_ROWS_OVER_EXPECTED times the
+# pairs its held experts draw when every expert draws alike, in whole tiles
+# of MOE_ROWS_TILE rows (`dispatch_rows`)
+MOE_ROWS_OVER_EXPECTED = 2
+MOE_ROWS_TILE = 512
 EXPERT_BIAS_SCALE = 0.05
 
 
@@ -205,29 +213,80 @@ def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK):
     return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, h * d)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _take_rows(x, idx, inv, k):
-    """x[idx // k] for `idx` a permutation of range(len(x) * k) with
-    inverse `inv`: backward is a gather through `inv` and a sum over the k
-    copies, where autodiff would scatter-add."""
-    return jnp.take(x, idx // k, axis=0)
+def dispatch_rows(sp: LMSpec, n_tokens: int) -> int:
+    """Rows of the sorted buffer a sparse layer's first pass computes for
+    `n_tokens` tokens: all `n_tokens * top_k` pairs where every expert is
+    held, else MOE_ROWS_OVER_EXPECTED times the held experts' share."""
+    worst = n_tokens * sp.top_k
+    tiles = -(-MOE_ROWS_OVER_EXPECTED * worst * sp.experts_held
+              // (sp.n_experts * MOE_ROWS_TILE))
+    return min(worst, tiles * MOE_ROWS_TILE)
 
 
-def _take_rows_fwd(x, idx, inv, k):
-    return _take_rows(x, idx, inv, k), (inv, x.shape[0])
+def _rows(src, idx):
+    """src[idx], an index outside clipped to the nearest row: `jnp.take`'s
+    default is a second pass over the output that fills such rows in."""
+    return jnp.take(src, idx, axis=0, mode="clip")
 
 
-def _take_rows_bwd(k, res, g):
-    inv, n = res
-    back = jnp.take(g, inv, axis=0).reshape((n, k) + g.shape[1:])
-    return jnp.sum(back, axis=1), None, None
+def _inside(at, src):
+    """Which of the rows `at` are rows of `src`."""
+    return (at >= 0) & (at < src.shape[0])
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _gather_sum(src, at, wt):
+    """sum_j wt[j, t] * src[at[j, t]] over the pairs whose row `at` lies in
+    `src`: [rows, d], [k, n], [k, n] -> [n, d]."""
+    wt = jnp.where(_inside(at, src), wt, 0).astype(src.dtype)
+    y = _rows(src, at.reshape(-1))
+    return jnp.sum(y.reshape(at.shape + src.shape[1:]) * wt[:, :, None],
+                   axis=0)
 
 
-def _permute_rows(x, idx, inv):
-    return _take_rows(x, idx, inv, 1)
+@jax.custom_vjp
+def _dispatch(x, idx, at):
+    """x[idx % n], the token rows of the sorted pairs `idx` [rows]; `at`
+    [k, n] is the row of every pair (outside [0, rows) where it is not
+    among `idx`): backward is a gather through `at` and a sum over a
+    token's k pairs, where autodiff would scatter-add."""
+    return _rows(x, idx % at.shape[1])
+
+
+def _dispatch_fwd(x, idx, at):
+    return _dispatch(x, idx, at), at
+
+
+def _dispatch_bwd(at, g):
+    return _gather_sum(g, at, jnp.ones(at.shape, g.dtype)), None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(ys, wk, idx, at):
+    """A token's weighted sum of its pairs' rows of `ys` [rows, d], with
+    `idx` and `at` as `_dispatch` has them. Backward works on the sorted
+    rows: the output's gradient gathered by token, times the pair's weight
+    for `ys`, times `ys` and summed for the weight."""
+    return _gather_sum(ys, at, wk)
+
+
+def _combine_fwd(ys, wk, idx, at):
+    return _combine(ys, wk, idx, at), (ys, wk, idx, at)
+
+
+def _combine_bwd(res, g):
+    ys, wk, idx, at = res
+    gs = _rows(g, idx % at.shape[1])
+    d_ys = gs * _rows(wk.reshape(-1), idx)[:, None].astype(gs.dtype)
+    d_rows = jnp.sum(gs.astype(jnp.float32) * ys.astype(jnp.float32),
+                     axis=-1)
+    d_wk = jnp.where(_inside(at, ys), _rows(d_rows, at), 0)
+    return d_ys, d_wk.astype(wk.dtype), None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def _mm(x, w, dtype):
@@ -266,6 +325,105 @@ def dense_ffn(p, x, dtype):
                    * _mm(x, p["w3"], dtype), p["w2"], dtype)
 
 
+def _expert_rows(lo, hi, trained, sort):
+    """The held experts' weighted outputs over the sorted rows [lo, hi) of
+    the pairs, summed by token: [n, d]. `trained` is (tokens [n, d], pair
+    weights [k, n], the three expert matrices), all in the products'
+    dtype; `sort` (order, inverse, held experts' group sizes) of the
+    pairs."""
+    x, wk, w1, w3, w2 = trained
+    order, inv, sizes = sort
+    with jax.named_scope("moe_router"):
+        idx, at = order[lo:hi], inv.reshape(wk.shape) - lo
+        # the part of every expert's group that lies in these rows
+        end = jnp.cumsum(sizes)
+        part = jnp.clip(jnp.minimum(end, hi) - jnp.maximum(end - sizes, lo),
+                        0)
+        # rows past the held pairs belong to no group: a grouped product
+        # leaves them undefined, so they are zeroed going in and coming out
+        valid = (jnp.arange(lo, hi) < end[-1])[:, None]
+        xs = jnp.where(valid, _dispatch(x, idx, at), 0)
+    with jax.named_scope("moe_experts"):
+        # bfloat16 operands go at the default precision whatever the
+        # process-wide setting: the TPU's grouped product refuses them at
+        # float32 precision
+        grouped = functools.partial(
+            jax.lax.ragged_dot, group_sizes=part,
+            precision=(jax.lax.Precision.DEFAULT if x.dtype == jnp.bfloat16
+                       else None))
+        h1 = grouped(xs, w1)
+        h3 = grouped(xs, w3)
+        ys = grouped(jax.nn.silu(h1) * h3, w2)
+    with jax.named_scope("moe_router"):
+        return _combine(jnp.where(valid, ys, 0), wk, idx, at)
+
+
+def _cast(trained, dtype):
+    """`trained` with the expert matrices, float32 parameters, in the
+    products' dtype."""
+    with jax.named_scope("moe_experts"):
+        return trained[:2] + tuple(w.astype(dtype) for w in trained[2:])
+
+
+def _overflows(rows, sort):
+    """Whether a step's held pairs number more than the first pass takes."""
+    return jnp.sum(sort[2]) > rows
+
+
+def _rest_rows(rows, trained, sort):
+    return _expert_rows(rows, sort[0].shape[0], trained, sort)
+
+
+def _add_rest(rows, dtype, out, trained, sort):
+    with jax.named_scope("moe_router"):
+        return jax.lax.cond(
+            _overflows(rows, sort),
+            lambda o: o + _rest_rows(rows, _cast(trained, dtype), sort),
+            lambda o: o, out)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _experts(rows, dtype, trained, sort):
+    """`_expert_rows` over every sorted row: the first `rows` always, the
+    rest only in a step whose held pairs number more. The second pass saves
+    nothing: its backward recomputes it from the arguments and adds to the
+    first pass's gradients in place, so a step that does not take it
+    carries no buffer of its size. The expert matrices come as the float32
+    parameters and each pass casts them itself, so that their gradients
+    cross the backward's `cond` in the products' dtype, as the grouped
+    products give them, and become float32 after it: crossing it as
+    float32 they made XLA lay the float32 expert parameters of the whole
+    client loop out the other way round beside copies the usual way (the
+    round program's temporaries, compiled for a described v5e: 11.8 GB,
+    8.1 GB this way, 7.4 GB before the buffer was cut)."""
+    out = _expert_rows(0, rows, _cast(trained, dtype), sort)
+    return _add_rest(rows, dtype, out, trained, sort)
+
+
+def _experts_fwd(rows, dtype, trained, sort):
+    out, first_vjp = jax.vjp(lambda t: _expert_rows(0, rows, t, sort),
+                             _cast(trained, dtype))
+    return (_add_rest(rows, dtype, out, trained, sort),
+            (first_vjp, trained, sort))
+
+
+def _experts_bwd(rows, dtype, res, g):
+    first_vjp, trained, sort = res
+
+    def with_rest(grads):
+        _, rest_vjp = jax.vjp(lambda t: _rest_rows(rows, t, sort),
+                              _cast(trained, dtype))
+        return jax.tree_util.tree_map(jnp.add, grads, rest_vjp(g)[0])
+
+    with jax.named_scope("moe_router"):
+        grads = jax.lax.cond(_overflows(rows, sort), with_rest,
+                             lambda grads: grads, first_vjp(g)[0])
+    return tuple(d.astype(t.dtype) for d, t in zip(grads, trained)), None
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
 def sparse_ffn(p, x, sp: LMSpec, src_layer: int, dtype):
     """(partial output of the held experts, [experts_held + 1] pairs routed
     to each held expert and, last, to experts not held here)."""
@@ -273,6 +431,7 @@ def sparse_ffn(p, x, sp: LMSpec, src_layer: int, dtype):
     shape = x.shape
     x = x.reshape(-1, shape[-1])
     n = x.shape[0]
+    rows = dispatch_rows(sp, n)
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32), p["gate"],
                          precision=jax.lax.Precision.HIGHEST)
@@ -285,34 +444,22 @@ def sparse_ffn(p, x, sp: LMSpec, src_layer: int, dtype):
         w = w * sp.routed_scale
         local = sel - sp.expert_offset
         held = (local >= 0) & (local < e_held)
-        # pairs sorted by held expert, those of absent experts last
-        key = jnp.where(held, local, e_held).reshape(-1)
+        # pairs sorted by held expert, those of absent experts last. Pair
+        # j * n + t is token t's j-th expert: what is gathered back by pair
+        # is then k slabs of [n, d] to add, in the layout the rows have
+        key = jnp.where(held, local, e_held).T.reshape(-1)
         order = jnp.argsort(key, stable=True)
         inv = jnp.argsort(order)
         counts = jnp.sum(key[:, None] == jnp.arange(e_held + 1)[None, :],
                          axis=0, dtype=jnp.int32)
-        sizes = counts[:e_held]
-        # rows past the held pairs belong to no group: a grouped product
-        # leaves them undefined, so they are zeroed going in and coming out
-        valid = (jnp.arange(n * top_k) < jnp.sum(sizes))[:, None]
-        xs = jnp.where(valid, _take_rows(x.astype(dtype), order, inv, top_k),
-                       0)
-    with jax.named_scope("moe_experts"):
-        # bfloat16 operands go at the default precision whatever the
-        # process-wide setting: the TPU's grouped product refuses them at
-        # float32 precision
-        grouped = functools.partial(
-            jax.lax.ragged_dot, group_sizes=sizes,
-            precision=(jax.lax.Precision.DEFAULT if dtype == jnp.bfloat16
-                       else None))
-        h1 = grouped(xs, p["experts_w1"].astype(dtype))
-        h3 = grouped(xs, p["experts_w3"].astype(dtype))
-        ys = grouped(jax.nn.silu(h1) * h3, p["experts_w2"].astype(dtype))
-    with jax.named_scope("moe_router"):
-        ys = jnp.where(valid, ys, 0)
-        y = _permute_rows(ys, inv, order).reshape(n, top_k, shape[-1])
-        wk = jnp.where(held, w, 0.0).astype(dtype)
-        out = jnp.sum(y * wk[:, :, None], axis=1)
+        wk = jnp.where(held, w, 0.0).T.astype(dtype)     # [k, n]
+    trained = (x.astype(dtype), wk, p["experts_w1"], p["experts_w3"],
+               p["experts_w2"])
+    sort = (order, inv, counts[:e_held])
+    if rows == n * top_k:     # every pair has its row: one pass is all
+        out = _expert_rows(0, rows, _cast(trained, dtype), sort)
+    else:
+        out = _experts(rows, dtype, trained, sort)
     return out.reshape(shape), counts
 
 
@@ -383,10 +530,16 @@ class LFM2MoE(nn.Module):
         """Shape of the (token, expert) pair counts a forward returns."""
         return (n_sparse_layers(self.spec), self.spec.experts_held + 1)
 
-    def build_counters(self):
-        """Counted once when an engine is built (obs/spans.py)."""
+    def dispatch_rows(self, n_tokens: int) -> int:
+        return dispatch_rows(self.spec, n_tokens)
+
+    def build_counters(self, n_tokens: int):
+        """Counted once when an engine is built (obs/spans.py), for a step
+        of `n_tokens` tokens."""
         return {"experts_held": self.spec.experts_held,
-                "vocab_held": self.spec.vocab_held}
+                "vocab_held": self.spec.vocab_held,
+                "moe_rows": self.dispatch_rows(n_tokens),
+                "moe_rows_worst": n_tokens * self.spec.top_k}
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False):

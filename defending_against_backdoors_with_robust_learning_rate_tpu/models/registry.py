@@ -56,7 +56,8 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
 
 # archs whose batch is `[bs, T + 1]` token ids, and the module of each: it
 # has `from_cfg(cfg, dtype, remat)` and `vocab_from_cfg(cfg)`, and its model
-# carries `takes_tokens = True`, `pairs_shape` and `build_counters()`
+# carries `takes_tokens = True`, `pairs_shape`, `dispatch_rows(n_tokens)` and
+# `build_counters(n_tokens)`
 TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe"}
 
 

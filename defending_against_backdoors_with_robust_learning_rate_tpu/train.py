@@ -341,11 +341,14 @@ class RoundEngine:
             model = get_model(cfg.data, cfg.model_arch, cfg.dtype,
                               remat=cfg.remat,
                               remat_policy=cfg.remat_policy, cfg=cfg)
-            # what a model wants counted once at build (a token model: the
-            # experts and vocabulary rows it holds)
-            for name, value in getattr(model, "build_counters", dict)().items():
-                tracer.count(name, value)
             example_shape = task_mod.input_shape(cfg, fed)
+            # what a model wants counted once at build (a token model: the
+            # experts and vocabulary rows it holds, and the rows a sparse
+            # layer's first pass takes of a step's sorted pairs)
+            built = (model.build_counters(cfg.bs * example_shape[0])
+                     if task_mod.is_tokens(cfg) else {})
+            for name, value in built.items():
+                tracer.count(name, value)
             # stack or fold, settled here from the stack's bytes and what
             # the device has free (compile_cache.resolved_agg), before a
             # parameter exists; from here on cfg carries the resolved
@@ -361,6 +364,11 @@ class RoundEngine:
                                  jax.random.PRNGKey(cfg.seed))
             print(f"[model] {type(model).__name__}: "
                   f"{param_count(params):,} params")
+            if "moe_rows" in built:
+                print(f"[model] moe rows {built['moe_rows']} of "
+                      f"{built['moe_rows_worst']}: the first pass over a "
+                      f"step's sorted pairs, {built['experts_held']} "
+                      f"experts held; what it cannot hold takes a second")
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

@@ -268,3 +268,128 @@ def test_reference_counts_one_expert_a_token_at_the_cut():
     assert abs(macs - scores - 199.5e6) < 0.1e6
     assert ref.moe_expert_flops(1024, ref.dims_of(config)) == \
         3 * 6 * 2048 * 1792 * 1024
+
+
+def worst_case_sparse_ffn(p, x, sp, src_layer, dtype=jnp.float32):
+    """`sparse_ffn` as it stood before the sorted buffer was cut: every
+    one of the n * top_k pairs sorted into a row of its own, in plain
+    `jax.numpy` and differentiated as written."""
+    e_held, top_k = sp.experts_held, sp.top_k
+    shape = x.shape
+    x = x.reshape(-1, shape[-1])
+    n = x.shape[0]
+    s = jax.nn.sigmoid(jnp.dot(x.astype(jnp.float32), p["gate"],
+                               precision=jax.lax.Precision.HIGHEST))
+    pick = (s + lm.expert_bias(sp, src_layer)) if sp.use_expert_bias else s
+    _, sel = jax.lax.top_k(pick, top_k)
+    w = jnp.take_along_axis(s, sel, axis=-1)
+    if sp.norm_topk:
+        w = w / (jnp.sum(w, axis=-1, keepdims=True) + 1e-6)
+    w = w * sp.routed_scale
+    local = sel - sp.expert_offset
+    held = (local >= 0) & (local < e_held)
+    key = jnp.where(held, local, e_held).reshape(-1)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.argsort(order)
+    counts = jnp.sum(key[:, None] == jnp.arange(e_held + 1)[None, :],
+                     axis=0, dtype=jnp.int32)
+    sizes = counts[:e_held]
+    valid = (jnp.arange(n * top_k) < jnp.sum(sizes))[:, None]
+    xs = jnp.where(valid, x.astype(dtype)[order // top_k], 0)
+    h1 = jax.lax.ragged_dot(xs, p["experts_w1"].astype(dtype), sizes)
+    h3 = jax.lax.ragged_dot(xs, p["experts_w3"].astype(dtype), sizes)
+    ys = jax.lax.ragged_dot(jax.nn.silu(h1) * h3,
+                            p["experts_w2"].astype(dtype), sizes)
+    y = jnp.where(valid, ys, 0)[inv].reshape(n, top_k, shape[-1])
+    wk = jnp.where(held, w, 0.0).astype(dtype)
+    return jnp.sum(y * wk[:, :, None], axis=1).reshape(shape), counts
+
+
+CUT_TOKENS = 64        # 128 pairs; a quarter of the experts held: 64 rows
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """At toy size the rule's 512-row tile covers every pair: whole tiles
+    of 8 rows make the cut real. The rule itself is the module's."""
+    monkeypatch.setattr(lm, "MOE_ROWS_TILE", 8)
+
+
+def layer_of(spec, seed):
+    """A sparse layer's parameters, the gate scaled up so that the tokens
+    and not the fixed bias decide the selection."""
+    model = lm.LFM2MoE(spec=spec)
+    p = init_params(model, (T,), jax.random.PRNGKey(seed))["layer_0"]
+    return dict(p, gate=40.0 * p["gate"])
+
+
+def value_and_grads(fn, p, x, spec):
+    """(output, pairs, gradients of a fixed weighting of the output with
+    respect to the layer's parameters and its input)."""
+    tilt = jax.random.normal(jax.random.PRNGKey(21), x.shape)
+
+    def scalar(p, x):
+        out, pairs = fn(p, x, spec, 2, jnp.float32)
+        return jnp.sum(out * tilt), (out, pairs)
+
+    (_, (out, pairs)), grads = jax.value_and_grad(
+        scalar, argnums=(0, 1), has_aux=True)(p, x)
+    return out, pairs, grads
+
+
+def only_held_experts(spec, p, x):
+    """A gate under which every token picks held experts only, with
+    weights that still differ by token: the held experts' columns read the
+    input's common offset, the others its negative."""
+    x = x + 3.0
+    lean = jnp.where(jnp.arange(spec.n_experts) < spec.experts_held, 1.0,
+                     -1.0) / spec.hidden
+    return dict(p, gate=lean[None, :] + 0.01 * p["gate"]), x
+
+
+@pytest.mark.parametrize("remat", [False, True])
+@pytest.mark.parametrize("held,overflow", [(2, False), (8, False),
+                                           (2, True)])
+def test_cut_buffer_equals_the_worst_case_formulation(small_tiles, held,
+                                                      overflow, remat):
+    """A quarter of the experts held: 64 rows for 128 pairs, with the held
+    pairs inside them, and with every pair held, so that the second pass
+    computes half of them; all experts held: the single pass."""
+    spec = lm.spec_from(TINY, "2", held, 0, 0)
+    n_pairs = CUT_TOKENS * spec.top_k
+    rows = lm.dispatch_rows(spec, CUT_TOKENS)
+    assert rows == (n_pairs if held == 8 else 64)
+    p = layer_of(spec, 6)
+    x = jax.random.normal(jax.random.PRNGKey(12),
+                          (2, CUT_TOKENS // 2, spec.hidden))
+    if overflow:
+        p, x = only_held_experts(spec, p, x)
+    fn = lm.sparse_ffn
+    if remat:
+        fn = jax.checkpoint(fn, static_argnums=(2, 3, 4))
+    out, pairs, grads = value_and_grads(fn, p, x, spec)
+    want, want_pairs, want_grads = value_and_grads(worst_case_sparse_ffn, p,
+                                                   x, spec)
+    np.testing.assert_array_equal(np.asarray(pairs), np.asarray(want_pairs))
+    assert int(pairs.sum()) == n_pairs
+    if overflow:
+        assert int(pairs[:-1].sum()) == n_pairs > rows
+    else:
+        assert 0 < int(pairs[:-1].sum()) <= rows
+    _close(out, want, 1e-6)
+    for g, w in zip(jax.tree_util.tree_leaves(grads),
+                    jax.tree_util.tree_leaves(want_grads), strict=True):
+        _close(g, w, 1e-5)
+    # every held expert and the router are reached, by both passes
+    assert float(jnp.abs(grads[0]["experts_w1"]).max(axis=(1, 2)).min()) > 0
+    assert float(jnp.abs(grads[0]["gate"]).max()) > 0
+
+
+@pytest.mark.parametrize("tokens,held,want", [
+    (8192, 8, 16384), (8192, 32, 32768), (8192, 1, 2048), (8192, 16, 32768),
+    (24, 4, 96), (1000, 8, 2048)])
+def test_dispatch_rows_follow_the_share_of_experts_held(tokens, held, want):
+    spec = lm.spec_from("lfm2-8b-a1b", "2", held, 0, 16384)
+    assert lm.dispatch_rows(spec, tokens) == want
+    assert want <= tokens * spec.top_k and (
+        want == tokens * spec.top_k or want % lm.MOE_ROWS_TILE == 0)

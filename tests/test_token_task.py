@@ -164,3 +164,88 @@ def test_folded_round_matches_stacked_round_on_the_token_model(fed):
     assert float(f_info["moe_pairs_held"] + f_info["moe_pairs_absent"]) == \
         4 * 2 * 2 * T * 2 * 2
     assert float(f_info["moe_load_max"]) >= float(f_info["moe_load_mean"]) > 0
+
+
+@pytest.fixture
+def every_pair_held(monkeypatch):
+    """Steering for the sorted buffer's second pass, in the test alone: a
+    routing bias under which every token picks held experts only, and
+    whole tiles of 8 rows (at toy size the module's 512 cover every pair,
+    and with them the rule gives the single pass)."""
+    def bias(spec, _src_layer):
+        e = np.arange(spec.n_experts) - spec.expert_offset
+        return np.where((e >= 0) & (e < spec.experts_held), 8.0,
+                        0.0).astype(np.float32)
+    monkeypatch.setattr(lm, "expert_bias", bias)
+    monkeypatch.setattr(lm, "MOE_ROWS_TILE", 8)
+
+
+def test_round_counts_the_forwards_that_took_the_second_pass(
+        fed, every_pair_held):
+    cfg = cfg_of(lm_experts_held=2)
+    model = get_model(cfg.data, cfg.model_arch, "f32", remat=True, cfg=cfg)
+    n_pairs = cfg.bs * T * model.spec.top_k
+    assert model.dispatch_rows(cfg.bs * T) == n_pairs // 2
+    params = init_params(model, (T,), jax.random.PRNGKey(0))
+    arrays = tuple(map(jnp.asarray, (fed.train.images, fed.train.labels,
+                                     fed.train.sizes)))
+    fn = make_round_fn(cfg.replace(agg_path="fold"), model, None, *arrays)
+    new_params, info = fn(params, jax.random.PRNGKey(5))
+    # 4 clients x 2 steps x 2 sparse layers, each with all its pairs held
+    assert float(info["moe_overflow_steps"]) == 4 * 2 * 2
+    assert float(info["moe_pairs_absent"]) == 0
+    assert float(info["moe_pairs_held"]) == 4 * 2 * 2 * n_pairs
+    assert all(bool(jnp.all(jnp.isfinite(leaf)))
+               for leaf in jax.tree_util.tree_leaves(new_params))
+    # the experts learned from both passes' pairs
+    moved = jax.tree_util.tree_map(lambda a, b: float(jnp.abs(a - b).max()),
+                                   new_params, params)
+    assert moved["layer_1"]["experts_w2"] > 0 and moved["layer_1"]["gate"] > 0
+
+
+@pytest.mark.parametrize("held,overflow", [(2, True), (4, False)])
+def test_engine_counts_the_buffers_rows_and_its_overflow(
+        tmp_path, capsys, monkeypatch, request, held, overflow):
+    """The counters' way out of the program: once at build the rows of the
+    first pass and of the worst case, every round the forwards that took
+    the second pass, in the tracer and in the `Moe/*` rows."""
+    import json
+
+    from defending_against_backdoors_with_robust_learning_rate_tpu import (
+        train)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.config import (
+        args_parser)
+    if overflow:
+        request.getfixturevalue("every_pair_held")
+    cfg = args_parser([
+        "--platform=cpu", "--data=tokens", "--arch=lfm2_moe",
+        f"--lm_config={TINY}", "--lm_layers=1,2", f"--lm_experts_held={held}",
+        f"--lm_vocab_held={VOCAB}", "--seq_len=16", "--num_agents=2",
+        "--bs=2", "--local_ep=1", "--synth_train_size=8",
+        "--synth_val_size=4", "--eval_bs=2", "--num_corrupt=1",
+        "--poison_frac=0.5", "--robustLR_threshold=2", "--agent_chunk=1", "--remat", "--rounds=1",
+        "--snap=1", "--no_tensorboard", "--no_compile_cache",
+        f"--log_dir={tmp_path}", "--data_dir=/nonexistent"])
+    eng = train.RoundEngine(cfg)
+    try:
+        for unit in eng.schedule():
+            eng.dispatch(unit)
+            eng.eval_boundary(eng.rnd)
+            eng.post_unit()
+        eng.drain.flush()
+    finally:
+        eng.close()
+    pairs = 2 * 16 * 2                    # a step: 2 sequences x 16 x top-2
+    rows = pairs // 2 if overflow else pairs
+    counted = {name: n for name, n, _labels in eng.tracer.counted()}
+    assert counted["moe_rows"] == rows and counted["moe_rows_worst"] == pairs
+    assert counted["experts_held"] == held
+    # 2 clients x 2 steps x 1 sparse layer
+    assert counted["moe_overflow_steps"] == (4 if overflow else 0)
+    assert f"[model] moe rows {rows} of {pairs}" in capsys.readouterr().out
+    run_dirs = [d for d in tmp_path.iterdir() if d.is_dir()]
+    with open(run_dirs[0] / "metrics.jsonl") as fh:
+        written = [json.loads(line) for line in fh]
+    steps = [r["value"] for r in written
+             if r.get("tag") == "Moe/Overflow_Steps"]
+    assert steps == [counted["moe_overflow_steps"]]

@@ -95,3 +95,13 @@ def test_layer_compiles_for_the_v5e_at_published_widths(one_chip,
     # one layer's backward, without the round's accumulators: well under
     # the 4.7 GB the cut leaves for a step's activations
     assert 0 < temp < 3 * 2 ** 30, temp
+    if what == "sparse_ffn":
+        # 8 of 32 experts held: the first pass sorts into 16384 rows of the
+        # 32768 pairs, and the rest hangs on a conditional that carries no
+        # buffer of its size (one `cond` around the expert section,
+        # differentiated as written, read 2600 MiB here; the uncut 612)
+        assert lm.dispatch_rows(spec, TOKENS) == 16384 == TOKENS * 2
+        assert " conditional(" in text
+        entry = text[text.index("\nENTRY "):]
+        assert f"[{TOKENS * spec.top_k},{f}]" not in entry
+        assert temp < 700 * 2 ** 20, temp
