@@ -150,8 +150,6 @@ def main():
     ap.add_argument("--rng_impl", choices=("auto", "threefry", "rbg"),
                     default="auto",
                     help="PRNG bit generator (auto = hardware rbg on TPU)")
-    ap.add_argument("--use_pallas", action="store_true",
-                    help="fused Pallas RLR+FedAvg server step")
     ap.add_argument("--faults", action="store_true",
                     help="also measure rounds/sec at 30%% client dropout "
                          "(faults/ masking path) and report the masking "
@@ -169,10 +167,9 @@ def main():
                     default="auto",
                     help="in-program reputation lanes (obs/reputation.py: "
                          "per-sampled-client rep_agree + rep_norm rows, "
-                         "default auto = on whenever a sign vote exists "
-                         "and the fused Pallas commit is not in use). "
-                         "'off' re-points the headline at the lane-free "
-                         "program; 'both' keeps the auto headline and "
+                         "default auto = on whenever a sign vote "
+                         "exists). 'off' re-points the headline at the "
+                         "lane-free program; 'both' keeps the auto headline and "
                          "ALSO measures the off twin (reputation_ab in "
                          "the output JSON — the ISSUE-20 <1%% overhead "
                          "acceptance A/B)")
@@ -208,27 +205,6 @@ def main():
                     help="samples per client on the ladder rungs (0 = "
                          "auto clamp; the SAME value lands on every rung, "
                          "so rung rounds/sec are compute-comparable)")
-    ap.add_argument("--train_layout", choices=("vmap", "megabatch", "both"),
-                    default="",
-                    help="A/B the local-training compute layout (ISSUE "
-                         "10, fl/client.py): vmap = per-client batched "
-                         "steps; megabatch = the client axis folded into "
-                         "one [m*bs, ...] pass with client-segmented "
-                         "loss/grad reductions. 'both' measures each "
-                         "layout's steady rounds/sec + analytic-FLOP "
-                         "MFU (train_layout_ab in the output JSON; the "
-                         "headline value stays the vmap number); a "
-                         "single value re-runs the headline under that "
-                         "layout")
-    ap.add_argument("--agg_layout", choices=("leaf", "bucket", "both"),
-                    default="",
-                    help="A/B the sharded aggregation collective shape "
-                         "(ISSUE 8, parallel/buckets.py): measure "
-                         "rounds/sec of the shard_map round program under "
-                         "the per-leaf psum plan and/or the bucketed "
-                         "reduce-scatter plan on the local mesh, with "
-                         "jaxpr + compiled-HLO collective counts per "
-                         "layout in the output JSON (agg_layout_ab)")
     ap.add_argument("--agg_mode", choices=("sync", "buffered", "both"),
                     default="sync",
                     help="aggregation mode (ISSUE 12, fl/buffered.py): "
@@ -345,15 +321,10 @@ def main():
     from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
         get_model, init_params)
 
-    extra = {"use_pallas": args.use_pallas,
-             "compile_cache": not args.no_compile_cache,
+    extra = {"compile_cache": not args.no_compile_cache,
              "compile_cache_dir": args.compile_cache_dir}
     if args.dtype:
         extra["dtype"] = args.dtype
-    if args.train_layout in ("vmap", "megabatch"):
-        # a single layout re-points the HEADLINE; 'both' keeps the vmap
-        # headline and adds the A/B block below
-        extra["train_layout"] = args.train_layout
     if args.health == "off":
         # 'off' re-points the headline; 'both' keeps the (default-on)
         # headline and adds the health_ab block below
@@ -551,17 +522,8 @@ def main():
         # (dropped agents still train — shapes are static — so compute
         # doesn't shrink with the electorate)
         r0 = rounds_per_sec
-        if cfg.use_pallas:
-            # the faults path can't take the fused Pallas server step, so a
-            # pallas-on 0% baseline would fold the kernel's win into
-            # "masking overhead" — re-measure the baseline unfused
-            log("[bench] --faults: re-measuring the 0% baseline without "
-                "the Pallas kernel for a like-for-like overhead figure")
-            _, r0, _, _ = measure(cfg.replace(use_pallas=False),
-                                  label="[faults dropout=0, no pallas]")
-        _, r30, c30, _ = measure(
-            cfg.replace(dropout_rate=0.3, use_pallas=False),
-            label="[faults dropout=0.3]")
+        _, r30, c30, _ = measure(cfg.replace(dropout_rate=0.3),
+                                 label="[faults dropout=0.3]")
         faults_out = {
             "dropout0_rounds_per_sec": round(r0, 4),
             "dropout30_rounds_per_sec": round(r30, 4),
@@ -578,16 +540,8 @@ def main():
         # delta vs the off run is the cost of the extra on-device stats
         # (the headline `value` stays the off number)
         r_base = rounds_per_sec
-        if cfg.use_pallas:
-            # telemetry falls back off the fused Pallas server step, so a
-            # pallas-on baseline would fold the kernel's win into
-            # "telemetry overhead" — re-measure unfused
-            log("[bench] --telemetry: re-measuring the off baseline "
-                "without the Pallas kernel for a like-for-like overhead")
-            _, r_base, _, _ = measure(cfg.replace(use_pallas=False),
-                                      label="[telemetry off, no pallas]")
         _, r_tel, c_tel, _ = measure(
-            cfg.replace(telemetry=args.telemetry, use_pallas=False),
+            cfg.replace(telemetry=args.telemetry),
             label=f"[telemetry {args.telemetry}]")
         telemetry_out = {
             "level": args.telemetry,
@@ -776,21 +730,10 @@ def main():
 
         # (1) equal-cohort A/B on the flagship: same population, same
         # shards (label_shards), same shapes — cohort machinery only.
-        # The cohort program always carries the active mask, so it never
-        # takes the fused Pallas server step; a pallas-on dense baseline
-        # would fold the kernel's win into "cohort overhead" (same
-        # re-measure the faults/telemetry probes do)
         r_dense = rounds_per_sec
-        if cfg.use_pallas:
-            log("[bench] --population_ladder: re-measuring the dense "
-                "baseline without the Pallas kernel for a like-for-like "
-                "cohort-overhead figure")
-            _, r_dense, _, _ = measure(cfg.replace(use_pallas=False),
-                                       label="[dense, no pallas]")
         ab_cfg = cfg.replace(cohort_sampled="on",
                              cohort_size=cfg.agents_per_round,
-                             partitioner="label_shards",
-                             use_pallas=False)
+                             partitioner="label_shards")
         r_ab, c_ab, _, _ = measure_cohort(
             ab_cfg, f"[cohort K={cfg.num_agents}]")
         population_out = {
@@ -884,50 +827,6 @@ def main():
         log(f"[bench] analytic {analytic_round/1e12:.2f} TFLOP/round "
             f"({cfg.agents_per_round}x{cfg.local_ep}x{nb_an}x{cfg.bs} "
             f"examples, 3x fwd)")
-
-    def layout_row(r, c_s):
-        """Per-layout A/B record: throughput + the analytic-FLOP MFU
-        fields (mfu only when the chip's peak is known — on CPU the
-        trackable trajectory number is analytic_tflops_per_sec)."""
-        row = {"rounds_per_sec": round(r, 4), "compile_s": round(c_s, 1)}
-        if analytic_round:
-            tps = analytic_round * r / 1e12
-            row["analytic_tflops_per_sec"] = round(tps, 3)
-            if peak:
-                row["mfu"] = round(tps / peak, 4)
-        return row
-
-    layout_ab_out = None
-    if args.train_layout == "both":
-        # train-layout A/B (ISSUE 10): the SAME flagship config through
-        # the chained round program under each local-training layout —
-        # the vmap headline above is reused as its own cell, megabatch
-        # measured fresh (distinct chained_mb program family, its own
-        # AOT entry)
-        hb.update(phase="train_layout_ab", force=True)
-        # the megabatch cell gets ITS OWN capture dir: the headline's
-        # --profile_rounds trace above profiled the vmap program, and an
-        # attribution labeled megabatch but measured on vmap would lie
-        # to the r11 MFU judgment
-        mb_profile = (args.profile_trace_dir + "_mb"
-                      if args.profile_rounds > 0 else None)
-        _, r_mb, c_mb, _ = measure(cfg.replace(train_layout="megabatch"),
-                                   label="[train_layout megabatch]",
-                                   profile_dir=mb_profile)
-        layout_ab_out = {"vmap": layout_row(rounds_per_sec, compile_s),
-                         "megabatch": layout_row(r_mb, c_mb),
-                         "megabatch_vs_vmap": round(
-                             r_mb / rounds_per_sec, 4)}
-        if mb_profile:
-            from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
-                attribution as _attr)
-            mb_attr = _attr.attribute(mb_profile)
-            if mb_attr is not None:
-                # the vmap layout's attribution is the top-level
-                # `attribution` field (the headline capture)
-                layout_ab_out["megabatch"]["attribution"] = mb_attr
-        log(f"[bench] megabatch/vmap throughput ratio: "
-            f"{layout_ab_out['megabatch_vs_vmap']:.3f}x")
 
     # performance anatomy (VERDICT r2 weak #1): FLOPs/round from XLA's own
     # cost analysis of the compiled client step, and MFU against the chip's
@@ -1056,97 +955,6 @@ def main():
             f"{tenancy_ab_out['packed']['cells_per_hour']:.1f} cells/hour"
             f" ({tenancy_ab_out['speedup']:.2f}x at E={args.tenants})")
 
-    agg_ab_out = None
-    if args.agg_layout:
-        # sharded-layout A/B (ISSUE 8): the SAME flagship config through
-        # the shard_map round program under each aggregation layout, on
-        # the largest local mesh dividing m. Per-round dispatch (no
-        # chain: XLA:CPU's conv-in-while slow path would swamp the
-        # collective delta on a --platform cpu run); each layout reports
-        # steady rounds/sec plus its jaxpr + compiled-HLO collective
-        # counts, so the A/B carries the communication-plan evidence
-        # next to the throughput it buys.
-        from defending_against_backdoors_with_robust_learning_rate_tpu.analysis import (
-            jaxpr_lint)
-        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
-            make_mesh, pick_agent_mesh_size)
-        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-            make_sharded_round_fn)
-        from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-            _pallas_applicable)
-        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
-            _bucket_applicable)
-        d = pick_agent_mesh_size(0, cfg.agents_per_round)
-        layouts = (("leaf", "bucket") if args.agg_layout == "both"
-                   else (args.agg_layout,))
-        if d <= 1:
-            agg_ab_out = {"note": f"needs >1 devices dividing "
-                                  f"agents_per_round={cfg.agents_per_round}"
-                                  f" (have {jax.device_count()})"}
-            log(f"[bench] agg-layout A/B skipped: {agg_ab_out['note']}")
-        elif _pallas_applicable(cfg) or not _bucket_applicable(
-                cfg.replace(agg_layout="bucket")):
-            # the bucket flag would be a no-op here (the fused pallas
-            # step wins the plan precedence exactly when
-            # _pallas_applicable holds; non-avg/sign rules keep their
-            # transpose plans) — measuring two identical programs as an
-            # A/B would be a lie
-            agg_ab_out = {"note": f"config never buckets "
-                                  f"(pallas={_pallas_applicable(cfg)}, "
-                                  f"aggr={cfg.aggr!r}); both layouts "
-                                  f"would trace the same program"}
-            log(f"[bench] agg-layout A/B skipped: {agg_ab_out['note']}")
-        else:
-            mesh = make_mesh(d)
-            agg_ab_out = {"mesh": d}
-            n_rounds = args.blocks * chain
-            hb.update(phase="agg_ab", force=True)
-            for lay in layouts:
-                lcfg = cfg.replace(agg_layout=lay)
-                sp = init_params(model, fed.train.images.shape[2:],
-                                 jax.random.PRNGKey(0))
-                fn = make_sharded_round_fn(lcfg, model, norm, mesh,
-                                           *arrays)
-                ab = compile_cache.abstractify
-                ex = (ab(sp), ab(jax.random.PRNGKey(0))) + arrays
-                closed = compile_cache.trace_program(fn.jitted, ex)
-                counts = {k: v for k, v in
-                          jaxpr_lint.collective_counts(closed).items()
-                          if v}
-                # ONE compile per layout: the Compiled that yields the
-                # HLO counts also drives the measurement (calling the
-                # bound fn instead would jit-compile the same program a
-                # second time)
-                compiled = compile_cache.lower_program(
-                    fn.jitted, ex).compile()
-                hcounts = jaxpr_lint.hlo_collective_counts(
-                    compiled.as_text())
-                with tracer.span("bench/agg_ab_first", layout=lay):
-                    key = jax.random.PRNGKey(1)
-                    sp, _ = compiled(sp, key, *arrays)
-                    jax.block_until_ready(sp)
-                t0 = time.perf_counter()
-                with tracer.span("bench/agg_ab_steady", layout=lay,
-                                 rounds=n_rounds):
-                    for r in range(n_rounds):
-                        key = jax.random.fold_in(jax.random.PRNGKey(1), r)
-                        sp, _ = compiled(sp, key, *arrays)
-                    jax.block_until_ready(sp)
-                rps = n_rounds / (time.perf_counter() - t0)
-                agg_ab_out[lay] = {
-                    "rounds_per_sec": round(rps, 4),
-                    "jaxpr_collectives": counts,
-                    "hlo_collectives": hcounts,
-                }
-                log(f"[bench] agg_layout={lay}: {rps:.3f} rounds/sec on "
-                    f"the {d}-way mesh | jaxpr {counts} | hlo {hcounts}")
-            if len(layouts) == 2:
-                agg_ab_out["bucket_vs_leaf"] = round(
-                    agg_ab_out["bucket"]["rounds_per_sec"]
-                    / agg_ab_out["leaf"]["rounds_per_sec"], 4)
-                log(f"[bench] bucket/leaf throughput ratio: "
-                    f"{agg_ab_out['bucket_vs_leaf']:.3f}x")
-
     vs_baseline = None
     base_path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                              "BASELINE_MEASURED.json")
@@ -1193,7 +1001,6 @@ def main():
         # only when a comparable measured baseline exists (fmnist config);
         # resnet9 has no reference counterpart, so no 1.0x placeholder
         out["vs_baseline"] = round(vs_baseline, 2)
-    out["train_layout"] = cfg.train_layout
     if flops_round is not None:
         out["tflop_per_round"] = round(flops_round / 1e12, 4)
         out["tflops_per_sec"] = round(tflops_sec, 2)
@@ -1209,8 +1016,6 @@ def main():
             mfu = analytic_round * rounds_per_sec / 1e12 / peak
     if mfu is not None:
         out["mfu"] = round(mfu, 4)
-    if layout_ab_out is not None:
-        out["train_layout_ab"] = layout_ab_out
     if faults_out is not None:
         out["faults"] = faults_out
     if telemetry_out is not None:
@@ -1227,8 +1032,6 @@ def main():
         out["population"] = population_out
     if attribution_out is not None:
         out["attribution"] = attribution_out
-    if agg_ab_out is not None:
-        out["agg_layout_ab"] = agg_ab_out
     out["agg_mode"] = cfg.agg_mode
     if agg_mode_ab is not None:
         out["agg_mode_ab"] = agg_mode_ab

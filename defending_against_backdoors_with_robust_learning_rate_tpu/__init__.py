@@ -24,7 +24,7 @@ Package layout::
     data/       dataset registry, label-sorted partitioner, padded agent stacks
     attack/     trojan pattern mask library + poisoning
     models/     Flax CNN_MNIST / CNN_CIFAR / ResNet-9
-    ops/        numeric building blocks (sgd, clipping, aggregation rules, pallas)
+    ops/        numeric building blocks (sgd, clipping, aggregation rules)
     fl/         client local training, server aggregation, round step, eval
     faults/     fault injection: dropout/straggler/corrupt-payload sampling
                 + the participation-mask aggregation protocol

@@ -118,11 +118,6 @@ ALLOW: Dict[Tuple[str, str], Dict[str, str]] = {
         "host-sync": "host-side set algebra on flat vectors at snap "
                      "cadence (--diagnostics is synchronous by design)",
     },
-    (f"{PKG}/ops/pallas_rlr.py", "_fused_leaf"): {
-        "host-sync": "float(threshold)/float(server_lr) convert Python "
-                     "config scalars into kernel kwargs at build time — "
-                     "no device value is touched",
-    },
     (f"{PKG}/data/registry.py", "make_synthetic.gen"): {
         "jit-side-effect": "host-side numpy dataset synthesis; `gen` is "
                            "a data generator the builder calls eagerly, "
@@ -131,8 +126,8 @@ ALLOW: Dict[Tuple[str, str], Dict[str, str]] = {
     },
     (f"{PKG}/fl/tenancy.py", "knob_vectors"): {
         "host-sync": "host-side knob-vector construction from Python "
-                     "config scalars at pack-build time (the pallas "
-                     "_fused_leaf idiom); no device value is touched",
+                     "config scalars at pack-build time; no device "
+                     "value is touched",
     },
     (f"{PKG}/fl/buffered.py", "host_latency_draw"): {
         "host-sync": "host MIRROR of the in-program arrival draw (the "
@@ -214,7 +209,7 @@ DONATED_CALLS: Dict[str, Tuple[int, ...]] = {
 # the chained lax.scan blocks are the throughput hot path, and without
 # donation every dispatched block would hold two full parameter buffers
 # (and XLA may insert a copy for the carry). The donation-audit pin
-# (ISSUE 10, tests/test_megabatch.py::test_chained_families_donate_params)
+# (tests/test_compile_cache.py::test_chained_families_donate_params)
 # lowers each family through the compile-cache planners and asserts the
 # StableHLO input-output aliasing attribute on arg 0 — a regression (a
 # refactor dropping donate_argnums) fails tier-1/CI. The per-round
@@ -223,22 +218,18 @@ DONATED_CALLS: Dict[str, Tuple[int, ...]] = {
 # one buffer, and the service supervisor may retry a dispatch whose
 # donated input a partially-executed call already consumed.
 DONATED_FAMILIES: Tuple[str, ...] = (
-    "chained", "chained_mb", "chained_host", "chained_host_mb",
-    "chained_cohort", "chained_cohort_mb",
-    "chained_sharded", "chained_sharded_mb",
+    "chained", "chained_host", "chained_cohort", "chained_sharded",
     # tenant-pack twins (ISSUE 13): the chained scan donates the whole
     # [E, ...]-stacked parameter carry — without it every dispatched
     # block would hold two copies of E experiments' params
-    "chained_mt", "chained_mb_mt",
+    "chained_mt",
     # buffered-async twins (ISSUE 12): the chained scan donates the whole
     # (params, buffer) carry — without it every dispatched block would
     # hold two copies of the buffer state on top of the params pair
-    "chained_async", "chained_async_mb", "chained_cohort_async",
-    "chained_cohort_async_mb", "chained_sharded_async",
-    "chained_sharded_async_mb",
+    "chained_async", "chained_cohort_async", "chained_sharded_async",
     # buffered tenant packs (ISSUE 16): the chained scan donates the
     # [E]-stacked (params, buffer) carry
-    "chained_async_mt", "chained_async_mb_mt",
+    "chained_async_mt",
 )
 
 # --------------------------------------------------------------------------
@@ -261,8 +252,7 @@ COLLECTIVE_PRIMITIVES = ("psum", "all_gather", "all_to_all", "ppermute",
 # mesh-axis sizes the sharded contracts are traced and judged at: single
 # chip, the 8-way CI mesh, and a 16-way pod shape. Counts are the same at
 # every topology BY DESIGN (the communication plans are topology-free);
-# tracing each size proves it — the bucketed reduce-scatter plan must not
-# grow collectives with the mesh. The reference topology keeps the
+# tracing each size proves it. The reference topology keeps the
 # historical (unsuffixed) baseline keys; other sizes record as
 # `<name>@<d>w`. Topologies above the process's faked device count are
 # skipped (tier-1 runs under 8; scripts/check_static.py forces 16).
@@ -446,117 +436,6 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
                            "all_gather": 1},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
 
-    # bucketed reduce-scatter layout (ISSUE 8, parallel/buckets.py): the
-    # pod-shape plan for the psum-shaped rules. avg + RLR costs ONE
-    # reduce-scatter per bucket (the weighted sum and the sign vote ride
-    # the SAME collective as stacked rows) + ONE all_gather of the
-    # already-LR-scaled result + the scalar weight-total psum + the loss
-    # pmean — 4 collectives total on the flagship (1 bucket) vs the leaf
-    # layout's 2L+2 = 18 psums. sign + RLR drops the weight psum (3).
-    # Faults still add exactly the one [m]-bit validation all_gather.
-    # Telemetry: the flip/vote stats ride the result all_gather (zero
-    # extra collectives); full adds the SAME 3 tiny all_gathers as the
-    # leaf plan (norms + two cosine accumulators). The HLO ceilings keep
-    # the measured +3 GSPMD constant; XLA's combiner may merge the two
-    # scalar psums below it (baseline pins the exact counts).
-    bucket = {"agg_layout": "bucket"}
-    rs_budget = {**zero, "psum": 2, "reduce_scatter": 1, "all_gather": 1}
-    specs["sharded_rlr_avg_bucket"] = CheckSpec(
-        name="sharded_rlr_avg_bucket", family="round_sharded",
-        sharded=True, cfg_overrides=dict(bucket),
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_sign_bucket"] = CheckSpec(
-        name="sharded_rlr_sign_bucket", family="round_sharded",
-        sharded=True,
-        cfg_overrides={**bucket, "aggr": "sign", "server_lr": 1.0},
-        collective_budget={**rs_budget, "psum": 1},
-        hlo_all_reduce_max=1 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_faults"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_faults", family="round_sharded",
-        sharded=True,
-        cfg_overrides={**bucket, "dropout_rate": 0.3,
-                       "payload_norm_cap": 100.0,
-                       "faults_spare_corrupt": True},
-        collective_budget={**rs_budget, "all_gather": 2},
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_tel_full"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_tel_full", family="round_sharded",
-        sharded=True, cfg_overrides={**bucket, "telemetry": "full"},
-        collective_budget={**rs_budget, "all_gather": 4},
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_sign_bucket_tel_full"] = CheckSpec(
-        name="sharded_rlr_sign_bucket_tel_full", family="round_sharded",
-        sharded=True,
-        cfg_overrides={**bucket, "aggr": "sign", "server_lr": 1.0,
-                       "telemetry": "full"},
-        collective_budget={**rs_budget, "psum": 1, "all_gather": 4},
-        hlo_all_reduce_max=1 + spmd_overhead)
-    # the bucketed body rides every dispatch surface unchanged: the
-    # host-sampled variant, the chained lax.scan block, and the
-    # cohort-sampled family keep the identical plan
-    specs["sharded_host_rlr_avg_bucket"] = CheckSpec(
-        name="sharded_host_rlr_avg_bucket", family="round_sharded_host",
-        sharded=True, host_mode=True, cfg_overrides=dict(bucket),
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_chained_rlr_avg_bucket"] = CheckSpec(
-        name="sharded_chained_rlr_avg_bucket", family="chained_sharded",
-        sharded=True, cfg_overrides={**bucket, "chain": 2, "snap": 2},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_cohort"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_cohort",
-        family="round_sharded_cohort", sharded=True,
-        cfg_overrides={**bucket, "cohort_sampled": "on"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-
-    # megabatch training layout (ISSUE 10, fl/client.py): folding the
-    # client axis into the batch is a COMPUTE-layout change only — the
-    # acceptance claim is the IDENTICAL collective plan as the vmap twin
-    # of every family (the fold happens inside each device's local
-    # block, before any aggregation collective). The specs below pin
-    # that at jaxpr and compiled-HLO level across the vmap family (zero
-    # collectives), the flagship sharded plan (2L+2 psums), the faults
-    # variant (+ exactly the one [m]-bit validation all_gather), the
-    # chained scan, the cohort family, and the bucketed reduce-scatter
-    # plan (4 collectives) — megabatch composes with the pod shape.
-    mb = {"train_layout": "megabatch"}
-    specs["vmap_rlr_avg_mb"] = CheckSpec(
-        name="vmap_rlr_avg_mb", family="round_mb", sharded=False,
-        cfg_overrides=dict(mb), collective_budget=dict(zero))
-    specs["sharded_rlr_avg_mb"] = CheckSpec(
-        name="sharded_rlr_avg_mb", family="round_sharded_mb",
-        sharded=True, cfg_overrides=dict(mb),
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_faults"] = CheckSpec(
-        name="sharded_rlr_avg_mb_faults", family="round_sharded_mb",
-        sharded=True,
-        cfg_overrides={**mb, "dropout_rate": 0.3,
-                       "payload_norm_cap": 100.0,
-                       "faults_spare_corrupt": True},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2,
-                           "all_gather": 1},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_chained_rlr_avg_mb"] = CheckSpec(
-        name="sharded_chained_rlr_avg_mb", family="chained_sharded_mb",
-        sharded=True, cfg_overrides={**mb, "chain": 2, "snap": 2},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_mb"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_mb", family="round_sharded_mb",
-        sharded=True, cfg_overrides={**mb, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_cohort"] = CheckSpec(
-        name="sharded_rlr_avg_mb_cohort",
-        family="round_sharded_cohort_mb", sharded=True,
-        cfg_overrides={**mb, "cohort_sampled": "on"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-
     # adaptive-adversary attack registry (ISSUE 11, attack/registry.py):
     # the in-jit strategies (boost / signflip) are an elementwise per-row
     # scale on the stacked updates, with corrupt flags derived from real
@@ -601,18 +480,6 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         cfg_overrides={**atk_sched, "chain": 2, "snap": 2},
         collective_budget={**zero, "psum": 2 * n_leaves + 2},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_atk_signflip"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_atk_signflip",
-        family="round_sharded", sharded=True,
-        cfg_overrides={**atk_s, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_atk_boost"] = CheckSpec(
-        name="sharded_rlr_avg_mb_atk_boost", family="round_sharded_mb",
-        sharded=True,
-        cfg_overrides={**atk_b, "train_layout": "megabatch"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
     specs["sharded_rlr_avg_cohort_atk_sched"] = CheckSpec(
         name="sharded_rlr_avg_cohort_atk_sched",
         family="round_sharded_cohort", sharded=True,
@@ -621,8 +488,8 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
 
     # buffered-async aggregation (ISSUE 12, fl/buffered.py): the carried
-    # buffer fold is elementwise on the replicated (leaf) or bucketed
-    # (reduce-scatter) shard, and the per-level contribution sums RIDE
+    # buffer fold is elementwise on the replicated trees, and the
+    # per-level contribution sums RIDE
     # the sync plan's collectives — per-leaf psums carry [S+1]-stacked
     # partials instead of plain leaves (a shape change, not a count
     # change), and the tiny count/weight/loss lanes pack into ONE vector
@@ -630,20 +497,14 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
     # The acceptance claim is therefore ZERO collectives beyond each
     # mode's pinned plan: vmap stays collective-free, avg+RLR stays
     # within 2L+2 psums (measured 2L+1: the packing saves one), sign+RLR
-    # within L+1, faults still add exactly the one [m]-bit validation
-    # all_gather, and the bucket layout keeps its reduce-scatter 1 /
-    # all_gather 1 / psum<=2 shape. The `_stale` spec runs WITH
+    # within L+1, and faults still add exactly the one [m]-bit
+    # validation all_gather. The `_stale` spec runs WITH
     # stragglers so the level-stacked (pending-ladder) shape is the one
     # being judged, not just the staleness-0 fast path.
     buf = {"agg_mode": "buffered"}
     specs["vmap_rlr_avg_async"] = CheckSpec(
         name="vmap_rlr_avg_async", family="round_async", sharded=False,
         cfg_overrides=dict(buf), collective_budget=dict(zero))
-    specs["vmap_rlr_avg_async_mb"] = CheckSpec(
-        name="vmap_rlr_avg_async_mb", family="round_async_mb",
-        sharded=False,
-        cfg_overrides={**buf, "train_layout": "megabatch"},
-        collective_budget=dict(zero))
     specs["sharded_rlr_avg_async"] = CheckSpec(
         name="sharded_rlr_avg_async", family="round_sharded_async",
         sharded=True, cfg_overrides=dict(buf),
@@ -672,11 +533,6 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         collective_budget={**zero, "psum": 2 * n_leaves + 2,
                            "all_gather": 1},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_async"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_async", family="round_sharded_async",
-        sharded=True, cfg_overrides={**buf, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
     specs["sharded_chained_rlr_avg_async"] = CheckSpec(
         name="sharded_chained_rlr_avg_async",
         family="chained_sharded_async", sharded=True,
@@ -730,10 +586,9 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
     # make_sharded_round_fn_mt), so every collective batches over the
     # tenant axis instead of multiplying: ONE psum of an [E, ...]
     # payload, not E psums. The acceptance claim is ZERO collectives
-    # beyond each layout's pinned plan at 1/8/16-way — leaf avg+RLR
-    # stays 2L+2 psums, sign+RLR L+1, faults still exactly the one
-    # [m]-bit validation all_gather, and the bucketed reduce-scatter
-    # keeps its 4-collective shape; the vmap tenant family stays
+    # beyond the pinned plan at 1/8/16-way — avg+RLR stays 2L+2 psums,
+    # sign+RLR L+1, faults still exactly the one [m]-bit validation
+    # all_gather; the vmap tenant family stays
     # collective-free. Per-tenant knobs are traced [E]-vector inputs and
     # add nothing to the communication plan.
     mt = {"tenants": 2}
@@ -760,20 +615,14 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         collective_budget={**zero, "psum": 2 * n_leaves + 2,
                            "all_gather": 1},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_mt"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_mt", family="round_sharded_mt",
-        sharded=True, cfg_overrides={**mt, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
 
     # buffered tenant packs (ISSUE 16): the carried (params, buffer)
     # state stacks as a leading [E] axis and the async fold batches over
     # tenants under the vmap — the contribution sums still ride the sync
     # plan's collectives (per-leaf psums of [E, S+1, ...] payloads, one
     # packed lane psum), so the claim is the async budget UNCHANGED by
-    # the tenant axis at 1/8/16-way: vmap collective-free, leaf avg+RLR
-    # within 2L+2 psums, sign+RLR within L+1, the bucket layout keeps
-    # its 4-collective reduce-scatter shape. The cohort-tenant twin pins
+    # the tenant axis at 1/8/16-way: vmap collective-free, avg+RLR
+    # within 2L+2 psums, sign+RLR within L+1. The cohort-tenant twin pins
     # gap 3 (one shared bank gather per round): the in-program cohort
     # draw batches over tenants collective-free.
     buf_mt = {**buf, **mt}
@@ -792,12 +641,6 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         cfg_overrides={**buf_mt, "aggr": "sign", "server_lr": 1.0},
         collective_budget={**zero, "psum": n_leaves + 1},
         hlo_all_reduce_max=n_leaves + 1 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_async_mt"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_async_mt",
-        family="round_sharded_async_mt", sharded=True,
-        cfg_overrides={**buf_mt, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
     specs["vmap_rlr_avg_cohort_mt"] = CheckSpec(
         name="vmap_rlr_avg_cohort_mt", family="round_cohort_mt",
         sharded=False, cfg_overrides={**coh, **mt},
@@ -830,21 +673,10 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         sharded=True, cfg_overrides=dict(hlth),
         collective_budget={**zero, "psum": 2 * n_leaves + 2},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_hlth"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_hlth", family="round_sharded",
-        sharded=True, cfg_overrides={**hlth, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
     specs["sharded_rlr_avg_cohort_hlth"] = CheckSpec(
         name="sharded_rlr_avg_cohort_hlth",
         family="round_sharded_cohort", sharded=True,
         cfg_overrides={**hlth, "cohort_sampled": "on"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_hlth"] = CheckSpec(
-        name="sharded_rlr_avg_mb_hlth", family="round_sharded_mb",
-        sharded=True,
-        cfg_overrides={**hlth, "train_layout": "megabatch"},
         collective_budget={**zero, "psum": 2 * n_leaves + 2},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
     specs["sharded_rlr_avg_async_hlth"] = CheckSpec(
@@ -861,13 +693,11 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
     # in-jit reputation lane (ISSUE 20, obs/reputation.py): per-sampled-
     # client sign-agreement vs the committed vote. The acceptance claim
     # is ZERO added collectives on every dispatch surface at 1/8/16-way:
-    # the vmap/megabatch/tenant paths compute rep_agree as collective-
-    # free [m]/[E,m] reductions, the sharded leaf paths re-read the
-    # vote's existing sign-sum psums and stitch the sharded [m/d] row
-    # through the P(AGENTS_AXIS) out_spec, the bucketed layout rides the
-    # sign shard on its existing result all_gather (a widened payload,
-    # never a new collective), and the buffered fold compares against
-    # the replicated vote the commit already holds. Every `*_rep` twin
+    # the vmap/tenant paths compute rep_agree as collective-free
+    # [m]/[E,m] reductions, the sharded paths re-read the vote's existing
+    # sign-sum psums and stitch the sharded [m/d] row through the
+    # P(AGENTS_AXIS) out_spec, and the buffered fold compares against the
+    # replicated vote the commit already holds. Every `*_rep` twin
     # therefore pins its plain counterpart's budget UNCHANGED; the
     # `_off` twin pins that the A/B arm really removes the lane.
     rep = {"reputation": "on"}
@@ -889,21 +719,10 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         cfg_overrides={**rep, "aggr": "sign", "server_lr": 1.0},
         collective_budget={**zero, "psum": n_leaves + 1},
         hlo_all_reduce_max=n_leaves + 1 + spmd_overhead)
-    specs["sharded_rlr_avg_bucket_rep"] = CheckSpec(
-        name="sharded_rlr_avg_bucket_rep", family="round_sharded",
-        sharded=True, cfg_overrides={**rep, "agg_layout": "bucket"},
-        collective_budget=dict(rs_budget),
-        hlo_all_reduce_max=2 + spmd_overhead)
     specs["sharded_rlr_avg_cohort_rep"] = CheckSpec(
         name="sharded_rlr_avg_cohort_rep",
         family="round_sharded_cohort", sharded=True,
         cfg_overrides={**rep, "cohort_sampled": "on"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_rep"] = CheckSpec(
-        name="sharded_rlr_avg_mb_rep", family="round_sharded_mb",
-        sharded=True,
-        cfg_overrides={**rep, "train_layout": "megabatch"},
         collective_budget={**zero, "psum": 2 * n_leaves + 2},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
     specs["sharded_rlr_avg_async_rep"] = CheckSpec(
@@ -917,54 +736,11 @@ def collective_budgets(n_leaves: int) -> Dict[str, "CheckSpec"]:
         collective_budget={**zero, "psum": 2 * n_leaves + 2},
         hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
 
-    # lattice cross-terms the coverage pass (analysis/coverage.py)
-    # surfaced as reachable-but-unpinned: the suffix algebra composes
-    # (_async x _mb x _mt, each mechanism individually pinned above),
-    # and composition must not change any layout's communication plan —
-    # avg+RLR stays within 2L+2 psums on every sharded cross-term.
-    # Measured at 1/8/16-way like every sharded family.
-    specs["sharded_rlr_avg_async_mb"] = CheckSpec(
-        name="sharded_rlr_avg_async_mb", family="round_sharded_async_mb",
-        sharded=True,
-        cfg_overrides={**buf, "train_layout": "megabatch"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_mb_mt"] = CheckSpec(
-        name="sharded_rlr_avg_mb_mt", family="round_sharded_mb_mt",
-        sharded=True,
-        cfg_overrides={"train_layout": "megabatch", "tenants": 2},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_async_mb_mt"] = CheckSpec(
-        name="sharded_rlr_avg_async_mb_mt",
-        family="round_sharded_async_mb_mt", sharded=True,
-        cfg_overrides={**buf, "train_layout": "megabatch", "tenants": 2},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_rlr_avg_cohort_async_mb"] = CheckSpec(
-        name="sharded_rlr_avg_cohort_async_mb",
-        family="round_sharded_cohort_async_mb", sharded=True,
-        cfg_overrides={**buf, "cohort_sampled": "on",
-                       "train_layout": "megabatch"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_host_rlr_avg_mb"] = CheckSpec(
-        name="sharded_host_rlr_avg_mb", family="round_sharded_host_mb",
-        sharded=True, host_mode=True,
-        cfg_overrides={"train_layout": "megabatch"},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    specs["sharded_chained_rlr_avg_async_mb"] = CheckSpec(
-        name="sharded_chained_rlr_avg_async_mb",
-        family="chained_sharded_async_mb", sharded=True,
-        cfg_overrides={**buf, "train_layout": "megabatch",
-                       "chain": 2, "snap": 2},
-        collective_budget={**zero, "psum": 2 * n_leaves + 2},
-        hlo_all_reduce_max=2 * n_leaves + 2 + spmd_overhead)
-    # --diagnostics sharded twin: the ONLY addition to the plan is one
-    # all_gather collecting the per-client loss diagnostics across
-    # shards — pinned so a diagnostics refactor cannot silently grow
-    # the round program's communication
+    # --diagnostics sharded twin (the coverage pass, analysis/coverage.py,
+    # surfaced it as reachable-but-unpinned): the ONLY addition to the
+    # plan is one all_gather collecting the per-client loss diagnostics
+    # across shards — pinned so a diagnostics refactor cannot silently
+    # grow the round program's communication
     specs["sharded_rlr_avg_diag"] = CheckSpec(
         name="sharded_rlr_avg_diag", family="round_sharded_diag",
         sharded=True, cfg_overrides={"diagnostics": True},
@@ -1039,7 +815,6 @@ PROVENANCE_CLASSES = ("program", "shape", "data", "runtime")
 # teaching the coverage pass how to reach it.
 SUFFIX_DRIVERS: Dict[str, Dict[str, object]] = {
     "_async": {"agg_mode": "buffered"},       # fl/buffered.is_buffered
-    "_mb": {"train_layout": "megabatch"},     # resolved_train_layout
     "_mt": {"tenants": 2},                    # tenant packs (fl/tenancy)
 }
 
@@ -1069,16 +844,10 @@ _W_EVAL_TWIN = (
     "vmap_eval's zero pin")
 WAIVED_FAMILIES: Dict[str, str] = {
     **{f: _W_CHAINED_VMAP for f in (
-        "chained", "chained_async", "chained_async_mb",
-        "chained_async_mb_mt", "chained_async_mt", "chained_cohort",
-        "chained_cohort_async", "chained_cohort_async_mb",
-        "chained_cohort_mb", "chained_host", "chained_host_mb",
-        "chained_mb", "chained_mb_mt", "chained_mt")},
+        "chained", "chained_async", "chained_async_mt", "chained_cohort",
+        "chained_cohort_async", "chained_host", "chained_mt")},
     **{f: _W_VMAP_CROSS for f in (
-        "round_async_mb_mt", "round_cohort_async",
-        "round_cohort_async_mb", "round_cohort_async_mb_mt",
-        "round_cohort_async_mt", "round_cohort_mb", "round_cohort_mb_mt",
-        "round_host", "round_host_mb", "round_mb_mt")},
+        "round_cohort_async", "round_cohort_async_mt", "round_host")},
     **{f: _W_VMAP_DIAG for f in (
         "round_diag", "round_cohort_diag", "round_host_diag")},
     **{f: _W_EVAL_TWIN for f in (
@@ -1109,7 +878,6 @@ RUN_NAME_EXEMPT: Dict[str, str] = {
     "client_lr": _X_REFERENCE_VOCAB,
     "client_moment": _X_REFERENCE_VOCAB,
     "agent_chunk": _X_VALUE_PRESERVING,
-    "agg_layout": _X_VALUE_PRESERVING,
     "agg_path": _X_VALUE_PRESERVING,
     "lm_config": _X_REFERENCE_VOCAB,
     "lm_layers": _X_REFERENCE_VOCAB,
@@ -1118,11 +886,15 @@ RUN_NAME_EXEMPT: Dict[str, str] = {
     "lm_vocab_held": _X_REFERENCE_VOCAB,
     "remat": _X_VALUE_PRESERVING,
     "remat_policy": _X_VALUE_PRESERVING,
-    "use_pallas": _X_VALUE_PRESERVING,
     "debug_nan": (
         "checkify instrumentation only observes — values are identical, "
         "and a debugging rerun must land in the dir of the run it is "
         "debugging"),
+    "diagnostics": (
+        "the Norms/* and Sign/* research scalars only ADD outputs at snap "
+        "rounds; the update math is the plain program's (the engine "
+        "builds the plain/diag pair from one config) — a diagnostics "
+        "rerun must land in the dir of the run it explains"),
     "telemetry": (
         "telemetry levels change which scalars are computed, never the "
         "model update (the telemetry-off bit-identity contract, pinned "

@@ -1,9 +1,9 @@
 """Program-family coverage fixpoint: the lattice the planners can emit
 vs. the contracts that pin it.
 
-The family lattice (sync/buffered x vmap/megabatch x dense/cohort/host x
-tenant, each with vmap and shard_map twins) long ago outgrew the
-hand-enumerated CheckSpec matrix — a new `family_suffix` branch or a new
+The family lattice (sync/buffered x dense/cohort/host x tenant, each
+with vmap and shard_map twins) long ago outgrew the hand-enumerated
+CheckSpec matrix — a new `family_suffix` branch or a new
 planner surface can silently ship with no collective-budget pin, and a
 deleted spec leaves its baseline records rotting in
 `analysis_baseline.json`. This pass closes the loop structurally:
@@ -315,8 +315,7 @@ def run_name_fields(repo_root: str) -> Set[str]:
                     and isinstance(node.args[0], ast.Name) \
                     and node.args[0].id in cfg_names \
                     and isinstance(node.args[1], ast.Constant):
-                # getattr(cfg, "field", default) — the is_buffered /
-                # resolved_train_layout idiom
+                # getattr(cfg, "field", default) — the is_buffered idiom
                 attrs.add(node.args[1].value)
             elif isinstance(node, ast.Call):
                 passes_cfg = any(

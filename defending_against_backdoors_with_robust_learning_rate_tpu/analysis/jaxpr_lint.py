@@ -260,25 +260,20 @@ def telemetry_off_findings(sharded: bool = False) -> List[Finding]:
     """Trace the round families with EVERY obs.telemetry entry point
     replaced by a tripwire: --telemetry off lowering must not touch the
     telemetry module at all (the bit-identity contract, made
-    structural). The sharded pass traces the leaf AND the bucketed
-    aggregation programs — the bucket path has its own telemetry hooks
-    (shard_vote_stats / compute_sharded_bucket) that must stay equally
-    dead under off."""
+    structural)."""
     from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
         telemetry)
     from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
         compile_cache)
     path = f"{contracts.PKG}/obs/telemetry.py"
     specs = contracts.check_specs()
-    names = (("sharded_rlr_avg", "sharded_rlr_avg_bucket",
-              "sharded_rlr_avg_async") if sharded
+    names = (("sharded_rlr_avg", "sharded_rlr_avg_async") if sharded
              else ("vmap_rlr_avg", "vmap_rlr_avg_async"))
 
     def tripwire(*_a, **_k):
         raise AssertionError("telemetry computed under --telemetry off")
 
-    hooks = ("compute", "compute_sharded", "compute_sharded_bucket",
-             "shard_vote_stats")
+    hooks = ("compute", "compute_sharded")
     orig = {h: getattr(telemetry, h) for h in hooks}
     for h in hooks:
         setattr(telemetry, h, tripwire)

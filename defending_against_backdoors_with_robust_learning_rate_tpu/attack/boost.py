@@ -22,8 +22,7 @@ What the defenses see:
   interaction is real.
 
 The transform is a per-row multiplicative scale on the stacked updates —
-elementwise, layout-blind (vmap and megabatch hand over the same
-[m, ...] tree) and collective-free (the corrupt flags and the schedule
+elementwise and collective-free (the corrupt flags and the schedule
 gate arrive replicated on every device of a mesh).
 """
 

@@ -27,7 +27,7 @@ Two hook kinds, both collective-free by construction:
   sampling, cohort recomputation, or the host-sampled flag argument), and
   the schedule gate (attack/schedule.py) is a pure function of the traced
   round index — so the transform adds ZERO collectives on the vmap,
-  shard_map, bucket, cohort and megabatch paths alike (pinned by the
+  shard_map and cohort paths alike (pinned by the
   ``*_atk_*`` specs in analysis/contracts.py).
 
 Adding a strategy: one module with its scale/stamp function, one
@@ -119,8 +119,8 @@ def check(cfg) -> None:
 
 def in_jit(cfg) -> bool:
     """Does this config transform updates inside the round program?
-    (Drives host_takes_flags, the pallas fallback and the host-mode
-    chaining budget — single source for every builder.)"""
+    (Drives host_takes_flags and the host-mode chaining budget — single
+    source for every builder.)"""
     return get(cfg).in_jit
 
 

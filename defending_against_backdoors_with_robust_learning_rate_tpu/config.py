@@ -71,38 +71,6 @@ class Config:
                                     # must resume under the impl that
                                     # wrote it (key data shapes differ).
     mesh: int = 1                   # devices on the `agents` mesh axis; 0 = all
-    agg_layout: str = "leaf"        # leaf | bucket — sharded aggregation
-                                    # collective shape (parallel/rounds.py):
-                                    # leaf = one psum per parameter leaf
-                                    # (2L+2 on the flagship; free on one
-                                    # chip); bucket = flatten updates into
-                                    # fixed-size buckets, ONE reduce-
-                                    # scatter per bucket, avg + RLR vote
-                                    # computed on the scattered shard, one
-                                    # all-gather of the LR-scaled result
-                                    # (parallel/buckets.py — the pod
-                                    # shape). leaf stays the default until
-                                    # the TPU A/B lands (bench.py
-                                    # --agg_layout)
-    train_layout: str = "vmap"      # vmap | megabatch — local-training
-                                    # compute layout (fl/client.py):
-                                    # vmap = per-client [bs, ...] steps
-                                    # batched by jax.vmap (the historical
-                                    # path); megabatch = the client axis
-                                    # folds into the batch — one
-                                    # [m*bs, ...] gather + normalize
-                                    # pass per minibatch step, step
-                                    # masks folded into per-client
-                                    # segment weights, the parameter
-                                    # chains advancing as one stacked
-                                    # [m, ...] tree (grads from the
-                                    # client-batched backward — see
-                                    # fl/client.py for why not a single
-                                    # grad-of-vmap). Parity is ulp-bounded in
-                                    # f32 (tests/test_megabatch.py);
-                                    # collective plan unchanged. vmap
-                                    # stays the default until the TPU
-                                    # A/B lands (bench.py --train_layout)
     chain: int = 1                  # rounds fused per dispatch via lax.scan
                                     # (capped at `snap`; >1 kills per-round
                                     # host dispatch overhead, bit-identical)
@@ -161,7 +129,7 @@ class Config:
                                     # with staleness T (no epoch
                                     # truncation in buffered mode).
                                     # avg/sign (± RLR) only; refuses
-                                    # pallas/--diagnostics/host-sampled.
+                                    # --diagnostics/host-sampled.
     async_buffer_k: int = 0         # arrivals per commit (FedBuff's K);
                                     # 0 = auto: the cohort size m (then
                                     # staleness-0 runs commit every tick,
@@ -277,12 +245,10 @@ class Config:
                                     # buffered-mode staleness draw
                                     # (heavier tail = more very-late
                                     # uploads), clipped to max_staleness
-    # --- multi-tenant megabatched sweeps (fl/tenancy.py, ISSUE 13) ---
+    # --- multi-tenant packed sweeps (fl/tenancy.py, ISSUE 13) ---
     tenants: int = 0                # >0: this config is a TENANT PACK of E
                                     # independent experiment replicas run
-                                    # as one resident program — the
-                                    # experiment axis folded the way
-                                    # megabatch folded the client axis.
+                                    # as one resident program.
                                     # Per-tenant scalar knobs (seed,
                                     # server_lr, robustLR_threshold,
                                     # attack_boost, schedule gates) enter
@@ -399,10 +365,10 @@ class Config:
                                     # (Reputation/* rows, rep/* events).
                                     # auto = on whenever a sign vote
                                     # exists (robustLR_threshold > 0 or
-                                    # aggr='sign') and the fused Pallas
-                                    # server step is not in use; off
-                                    # removes the lane — training and
-                                    # every metrics surface bit-identical
+                                    # aggr='sign') and the round is not
+                                    # a fold; off removes the lane —
+                                    # training and every metrics
+                                    # surface bit-identical
     rep_population_cap: int = 100000  # dense per-client dict up to this
                                     # population; above it the tracker
                                     # switches to a count-min sketch +
@@ -474,7 +440,6 @@ class Config:
                                     # Device/* + Memory/* attribution rows
                                     # (obs/attribution.py) and the run
                                     # report; 0 = off, bit-identical
-    use_pallas: bool = False        # fused RLR+aggregate TPU kernel
     debug_nan: bool = False         # checkify float guards in the round fn
     diagnostics: bool = False       # Norms/* + Sign/* research scalars (C13)
     tensorboard: bool = True        # JSONL metrics always; TB optional
@@ -617,17 +582,6 @@ FIELD_PROVENANCE = {
     "mesh": "runtime",            # sharded families are never banked; the
                                   # mesh-independent eval/vmap programs
                                   # should be shared across mesh settings
-    "agg_layout": "program",      # selects the sharded aggregation
-                                  # collective plan (per-leaf psums vs
-                                  # bucketed reduce-scatter) — a traced
-                                  # program difference
-    "train_layout": "program",    # selects the local-training compute
-                                  # layout (vmapped per-client steps vs
-                                  # the megabatched [m*bs] fold) — a
-                                  # traced program difference; the
-                                  # fingerprint keys the RESOLVED layout
-                                  # (compile_cache.resolved_train_layout
-                                  # normalizes the --diagnostics degrade)
     "chain": "shape",             # round_ids aval pins the block length
     "host_prefetch": "runtime",
     "host_sampled": "runtime",    # selects the family; family names key
@@ -759,7 +713,6 @@ FIELD_PROVENANCE = {
     "profile_dir": "runtime",
     "profile_rounds": "runtime",  # sampled profiler window; observation
                                   # only, never shapes the program
-    "use_pallas": "program",
     "debug_nan": "program",       # checkify instruments the program (AOT
                                   # bank is off, but the XLA cache is not)
     "diagnostics": "program",     # per-family normalization in fingerprint()
@@ -850,24 +803,6 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                    help="multi-host: this process's id; -1 = auto")
     p.add_argument("--mesh", type=int, default=d.mesh,
                    help="devices on the `agents` mesh axis (0=all local devices)")
-    p.add_argument("--agg_layout", choices=("leaf", "bucket"),
-                   default=d.agg_layout,
-                   help="sharded aggregation collective shape: leaf = one "
-                        "psum per parameter leaf (single-chip shape); "
-                        "bucket = bucketed reduce-scatter + all-gather of "
-                        "the LR-scaled result with the RLR vote computed "
-                        "on the scattered shard (pod shape, "
-                        "parallel/buckets.py)")
-    p.add_argument("--train_layout", choices=("vmap", "megabatch"),
-                   default=d.train_layout,
-                   help="local-training compute layout: vmap = per-client "
-                        "[bs, ...] steps batched by jax.vmap; megabatch = "
-                        "fold the client axis into the batch — one "
-                        "[m*bs, ...] pass per minibatch step with a "
-                        "client-segmented loss/grad reduction "
-                        "(fl/client.py; ulp-bounded parity, identical "
-                        "collective plan). Degrades to vmap under "
-                        "--diagnostics")
     p.add_argument("--chain", type=int, default=d.chain,
                    help="rounds fused into one compiled lax.scan dispatch "
                         "(capped at --snap so eval cadence is unchanged)")
@@ -1114,8 +1049,8 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "sampled client with zero added collectives, "
                         "folded into a longitudinal suspicion ledger "
                         "(Reputation/* rows, rep/* events). auto = on "
-                        "when a sign vote exists and pallas is off; off "
-                        "is bit-identical")
+                        "when a sign vote exists and the round is not a "
+                        "fold; off is bit-identical")
     p.add_argument("--rep_population_cap", type=int,
                    default=d.rep_population_cap,
                    help="population above which the reputation tracker "
@@ -1224,7 +1159,6 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "many steady rounds and attribute device time "
                         "(obs/attribution.py: Device/* + Memory/* rows, "
                         "run report input); 0 = off")
-    p.add_argument("--use_pallas", action="store_true")
     p.add_argument("--debug_nan", action="store_true",
                    help="instrument the round program with checkify float "
                         "checks (raises on the first NaN/inf)")

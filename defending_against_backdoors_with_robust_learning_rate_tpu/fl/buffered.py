@@ -37,10 +37,10 @@ Design properties, inherited from the faults/churn idiom:
   checkpoint (crash-exact recovery of a mid-buffer kill is the chaos
   drill's acceptance).
 - **zero extra collectives**: the fold is elementwise on the replicated
-  (leaf layout) or scattered (bucket layout) shard; the sharded paths
-  reuse the sync plan's psums on the per-level stacked partial sums and
-  pack the tiny count/weight/loss lanes into one vector psum, so the
-  ``*_async`` contract specs pin the SAME budgets as the sync families.
+  trees; the sharded paths reuse the sync plan's psums on the per-level
+  stacked partial sums and pack the tiny count/weight/loss lanes into
+  one vector psum, so the ``*_async`` contract specs pin the SAME
+  budgets as the sync families.
 - **degenerate-case parity**: with K=m, staleness 0 (no stragglers) and
   ``async_staleness_exp=0``, every tick's arrivals are the full cohort,
   the commit gate fires every tick, and the fold arithmetic degenerates
@@ -50,9 +50,9 @@ Design properties, inherited from the faults/churn idiom:
 Unsupported compositions refuse loudly (``check``): the order-statistic
 aggregators (comed/trmean/krum/rfa) need the individual updates a running
 sum cannot reconstruct; ``--diagnostics`` needs per-round lr/update trees
-of a committed round; the fused Pallas kernel never materializes the
-buffer; host-sampled mode has no cohort-id channel for the arrival draw
-(cohort-sampled mode is the supported large-population surface).
+of a committed round; host-sampled mode has no cohort-id channel for the
+arrival draw (cohort-sampled mode is the supported large-population
+surface).
 """
 
 from __future__ import annotations
@@ -121,8 +121,8 @@ def has_pending(cfg) -> bool:
 
 
 def check(cfg) -> None:
-    """Loud refusals for unsupported compositions, before any build —
-    the megabatch/bucket refusal idiom (each names its remediation)."""
+    """Loud refusals for unsupported compositions, before any build
+    (each names its remediation)."""
     if not is_buffered(cfg):
         return
     if cfg.aggr not in ("avg", "sign"):
@@ -137,12 +137,6 @@ def check(cfg) -> None:
             "Norms/Sign research scalars describe one committed round's "
             "lr/update trees, which a partially-filled buffer never "
             "has); re-run with --agg_mode sync, or drop --diagnostics")
-    if cfg.use_pallas:
-        raise ValueError(
-            "--agg_mode buffered does not support --use_pallas (the "
-            "fused server kernel consumes the round's updates in one "
-            "pass and never materializes the carried buffer); re-run "
-            "with --agg_mode sync, or drop --use_pallas")
     # (host-sampled mode is refused by the step builders and the engine
     # — fl/rounds.make_host_step, parallel/rounds.make_sharded_host_step,
     # train.RoundEngine — which own the host_sampled resolution; reading

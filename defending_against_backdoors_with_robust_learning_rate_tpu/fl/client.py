@@ -29,8 +29,6 @@ import jax
 import jax.numpy as jnp
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl import task
-from defending_against_backdoors_with_robust_learning_rate_tpu.fl.common import (
-    masked_ce)
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops import (
     loops, tree)
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops.sgd import (
@@ -156,140 +154,3 @@ def make_local_train(model, cfg, normalize):
     local_train.sequential = sequential
     return local_train
 
-
-def make_local_train_megabatch(model, cfg, normalize):
-    """Megabatched local training (ISSUE 10, `--train_layout megabatch`):
-    the whole client block advances through ONE traced step schedule with
-    the client axis folded into the batch.
-
-    mb_train(params0, images [m, n, ...], labels [m, n], sizes [m],
-             keys [m, ...][, ep_budget [m]]) -> (updates [m, ...]-stacked
-    pytree, losses [m]) — the exact output contract of
-    `vmap(local_train)` over the same block.
-
-    What folds, per minibatch step (vs the vmap layout's m logical
-    [bs, ...] client programs):
-
-    - the minibatch row gather runs ONCE over the [m*n, ...] flattened
-      shard block (per-client perm indices offset into one flat index
-      space) — one fat gather instead of m;
-    - normalize runs over the folded [m*bs, ...] batch, and the
-      per-client step masks (padding + straggler truncation) are
-      constructed ON the fold as [m, bs] segment weights — each
-      client's loss mean, loss mask and step-validity bit all read the
-      same segment reduction (row sums of the folded weights), so
-      masked-step semantics are preserved arithmetically;
-    - the per-client parameter chains advance as ONE stacked [m, ...]
-      tree through a shared optimizer tail (global-norm clip, masked
-      momentum step, PGD projection — exact per-client arithmetic over
-      the stacked trees).
-
-    The model forward/backward stays batched over the client axis —
-    per-client parameter chains and per-client dropout key streams make
-    a shared-weight flat pass mathematically wrong after the first SGD
-    step, and the measured XLA:CPU lowering of a single grad THROUGH
-    the client-batched apply hits a ~6x slower grouped-conv backward
-    path, so the grads come from the client-batched `value_and_grad`
-    (identical math and keys; dropout masks are bit-identical). Parity
-    with the vmap layout is ulp-bounded in f32
-    (tests/test_megabatch.py). RLR_ABLATE measurement ablations apply
-    to the vmap layout only."""
-    bs = cfg.bs
-
-    def client_loss(p, x, y, w, r):
-        logits = model.apply({"params": p}, x, train=True,
-                             rngs={"dropout": r})
-        return masked_ce(logits, y, w)
-
-    grad_clients = jax.vmap(jax.value_and_grad(client_loss))
-
-    def client_opt_step(params0):
-        """Per-client optimizer tail, vmapped over the stacked chains —
-        the same clip/step/project ops the vmap layout runs per client."""
-        def step(p, mom, g, valid):
-            g = clip_by_global_norm(g, 10.0)
-            p, mom = sgd_momentum_step(p, mom, g, cfg.client_lr,
-                                       cfg.client_moment, valid)
-            if cfg.clip > 0:
-                p = pgd_project(p, params0, cfg.clip)
-            return p, mom
-        return jax.vmap(step, in_axes=(0, 0, 0, 0))
-
-    def _mb_train(params0, images, labels, sizes, keys, ep_budget):
-        m, n_total = images.shape[0], images.shape[1]
-        nb = n_total // bs
-        # same XLA:CPU conv-in-while policy (and cap) as the vmap layout
-        py_loops = loops.cpu_backend() and cfg.local_ep * nb <= 16
-        params0 = tree.astype(params0, jnp.float32)
-        stack0 = tree.map(lambda p: jnp.broadcast_to(p, (m,) + p.shape),
-                          params0)
-        flat_images = images.reshape((m * n_total,) + images.shape[2:])
-        flat_labels = labels.reshape(m * n_total)
-        offsets = (jnp.arange(m, dtype=jnp.int32) * n_total)[:, None]
-        opt_step = client_opt_step(params0)
-
-        def epoch_body(carry, xs):
-            ep_keys, ep_idx = xs              # [m, ...] keys, scalar idx
-            params, mom = carry               # [m, ...]-stacked chains
-            ep_active = ep_idx < ep_budget    # [m] straggler truncation
-            sk_dk = jax.vmap(jax.random.split)(ep_keys)
-            shuffle_keys, drop_keys = sk_dk[:, 0], sk_dk[:, 1]
-            # per-client shuffle: real samples first, shuffled — the
-            # identical draw as the vmap layout (same keys, same ops)
-            r = jax.vmap(lambda k: jax.random.uniform(k, (n_total,)))(
-                shuffle_keys)
-            r = jnp.where(jnp.arange(n_total)[None, :] < sizes[:, None],
-                          r, 2.0)
-            perms = jnp.argsort(r, axis=1)    # [m, n_total]
-
-            def batch_body(carry, b):
-                params, mom = carry
-                idx = jax.lax.dynamic_slice_in_dim(perms, b * bs, bs, 1)
-                flat_idx = (idx + offsets).reshape(m * bs)
-                # ONE gather over the flat [m*n, ...] block, normalized
-                # as one [m*bs, ...] megabatch (elementwise — identical
-                # values to the per-client pipeline)
-                x = normalize(jnp.take(flat_images, flat_idx, axis=0))
-                y = jnp.take(flat_labels, flat_idx, axis=0)
-                w = ((b * bs + jnp.arange(bs))[None, :] < sizes[:, None]) \
-                    & ep_active[:, None]      # [m, bs] segment weights
-                rngs = jax.vmap(jax.random.fold_in, in_axes=(0, None))(
-                    drop_keys, b)
-                # client-batched fwd/bwd over the folded rows (see the
-                # builder docstring for why the grad is NOT a single
-                # grad-of-vmap); per-client means come out per segment
-                per_client, grads = grad_clients(
-                    params, x.reshape((m, bs) + x.shape[1:]),
-                    y.reshape(m, bs), w, rngs)
-                # segment reduction of the folded step masks: the same
-                # [m] weights drive the loss bookkeeping AND the
-                # masked-step validity bit
-                w_n = jnp.sum(w.astype(jnp.float32), axis=1)
-                params, mom = opt_step(params, mom, grads, w_n > 0)
-                return (params, mom), (per_client * w_n, w_n)
-
-            (params, mom), (loss_sums, w_sums) = loops.maybe_unrolled_scan(
-                batch_body, (params, mom), jnp.arange(nb), py_loops)
-            ep_loss = (jnp.sum(loss_sums, axis=0)
-                       / jnp.maximum(jnp.sum(w_sums, axis=0), 1.0))
-            return (params, mom), ep_loss
-
-        ep_keys = jax.vmap(
-            lambda k: jax.random.split(k, cfg.local_ep))(keys)
-        (params, _), ep_losses = loops.maybe_unrolled_scan(
-            epoch_body, (stack0, tree.zeros_like(stack0)),
-            (jnp.swapaxes(ep_keys, 0, 1), jnp.arange(cfg.local_ep)),
-            py_loops)
-        return tree.sub(params, stack0), jnp.mean(ep_losses, axis=0)
-
-    if cfg.straggler_rate > 0:
-        # faults path: callers pass the per-client epoch budgets (6th arg)
-        return _mb_train
-
-    def mb_train(params0, images, labels, sizes, keys):
-        # dense path: the static full budget constant-folds away
-        return _mb_train(params0, images, labels, sizes, keys,
-                         jnp.full((images.shape[0],), cfg.local_ep,
-                                  jnp.int32))
-
-    return mb_train

@@ -29,30 +29,3 @@ def masked_ce(logits, labels, weights):
     w = weights.astype(jnp.float32)
     return jnp.sum(ce * w) / jnp.maximum(jnp.sum(w), 1.0)
 
-
-def masked_ce_segments(logits, labels, weights, num_segments):
-    """`masked_ce` over a client-folded [m*bs, ...] megabatch (ISSUE 10):
-    ONE cross-entropy pass over the flat batch, then the per-client
-    means recovered by segment-sum over the batch axis. Segments are
-    the m equal [bs]-sized client blocks of the fold, so the
-    segment-sum specializes to a reshape + row reduction.
-
-    Per-client step masks (padding, straggler truncation) arrive
-    already folded into `weights`, so a masked-out sample contributes
-    nothing to its client's mean — the same arithmetic as the
-    per-client `masked_ce`, reorganized (reduction order may differ at
-    the ulp level).
-
-    NOTE this is the LOSS-side fold only: differentiating one summed
-    loss through the client-batched apply measured ~6x slower on
-    XLA:CPU (grouped-conv backward), so fl/client.py's megabatch
-    trainer takes its grads from the client-batched `value_and_grad`
-    and uses this reduction for parity oracles and loss bookkeeping.
-
-    Returns (total_loss, per_client_loss [m], per_client_weight [m])."""
-    ce = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-    w = weights.astype(jnp.float32)
-    seg_ce = jnp.sum((ce * w).reshape(num_segments, -1), axis=1)
-    seg_w = jnp.sum(w.reshape(num_segments, -1), axis=1)
-    per_client = seg_ce / jnp.maximum(seg_w, 1.0)
-    return jnp.sum(per_client), per_client, seg_w

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
     buffered, task)
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.client import (
-    make_local_train, make_local_train_megabatch)
+    make_local_train)
 from defending_against_backdoors_with_robust_learning_rate_tpu.health import (
     sentinel as health_sentinel)
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops import loops
@@ -43,45 +43,6 @@ FAULT_INFO_KEYS = ("fault_dropped", "fault_straggled", "fault_voters")
 # buffered-async fill/commit/staleness scalars (fl/buffered.py)
 CHAINED_INFO_KEYS = (FAULT_INFO_KEYS + ("churn_away",)
                      + buffered.ASYNC_INFO_KEYS)
-
-
-def _pallas_applicable(cfg) -> bool:
-    """The fused Pallas server step covers the (weighted-FedAvg or signSGD
-    [+ RLR], no server noise) paths — the paper's headline configurations.
-    Diagnostics need the explicit lr tree, which the fused kernel never
-    materializes; the faults path — and the churn path, which rides the
-    same participation mask — needs the mask threaded through the vote,
-    which the fused kernel does not take; defense telemetry
-    (obs/telemetry.py) likewise needs the explicit lr/aggregate trees, so
-    any --telemetry level falls back to the jnp path."""
-    from defending_against_backdoors_with_robust_learning_rate_tpu.attack import (
-        registry as attack_registry)
-    from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
-        compile_cache)
-    # cohort-sampled rounds always carry the active mask (duplicate /
-    # churn-absent padding slots must be excluded from aggregation), which
-    # the fused kernel does not take — same fallback as faults/churn.
-    # In-jit attack strategies transform the updates BEFORE the server
-    # step, which the fused kernel's one-pass read would skip.
-    # tenant packs (fl/tenancy.py) carry per-tenant thresholds/LRs as
-    # traced knobs, which the fused kernel bakes as Python floats
-    # a quarantine set (health/monitor.py QUARANTINE rung) rides the
-    # participation mask, which the fused kernel does not take — same
-    # fallback as faults/churn
-    return (bool(cfg.use_pallas) and cfg.aggr in ("avg", "sign")
-            and cfg.noise == 0 and not cfg.diagnostics
-            and not cfg.faults_enabled and not cfg.churn_enabled
-            and not attack_registry.in_jit(cfg)
-            and not compile_cache.is_cohort_mode(cfg)
-            and not buffered.is_buffered(cfg)
-            and cfg.tenants == 0
-            and not health_sentinel.has_quarantine(cfg)
-            and cfg.telemetry == "off"
-            # the reputation lane (obs/reputation.py) reads the explicit
-            # sign-sum tree the fused kernel never materializes — an
-            # EXPLICIT --reputation on falls back like telemetry ("auto"
-            # instead resolves the lane off and keeps the kernel)
-            and cfg.reputation != "on")
 
 
 def host_takes_flags(cfg) -> bool:
@@ -123,8 +84,7 @@ def vmap_agents(local_train, params, imgs, lbls, sizes, keys,
     ResNet-9 stashes ~19 GB — over a v5e chip's 16 GB), so `--agent_chunk c`
     trades a factor m/c of round latency for a factor m/c of activation
     memory. Results are independent of the chunking (each agent's training
-    is independent); chunk must divide the (per-device) agent count, else
-    the full vmap runs.
+    is independent); chunk must divide the (per-device) agent count.
 
     `ep_budget` ([m] int32, faults/) rides the same agents axis when the
     straggler fault is configured — local_train then takes it as a sixth
@@ -144,28 +104,6 @@ def vmap_agents(local_train, params, imgs, lbls, sizes, keys,
     else:
         vt = jax.vmap(local_train,
                       in_axes=(None,) + (0,) * (4 + len(extra)))
-    return _run_chunked(vt, params, imgs, lbls, sizes, keys, chunk, extra)
-
-
-def megabatch_agents(mb_train, params, imgs, lbls, sizes, keys,
-                     chunk: int = 0, ep_budget=None):
-    """Run the megabatched block trainer (fl/client.py,
-    `--train_layout megabatch`) over the [m, ...] client block,
-    optionally in sequential chunks of `chunk` clients — the same HBM
-    lever (and the same divisibility rule) as `vmap_agents`: each chunk
-    group megabatches its own [chunk*bs, ...] fold, so peak activation
-    memory scales with `chunk` while results stay independent of the
-    chunking."""
-    extra = () if ep_budget is None else (ep_budget,)
-    return _run_chunked(mb_train, params, imgs, lbls, sizes, keys, chunk,
-                        extra)
-
-
-def _run_chunked(block_fn, params, imgs, lbls, sizes, keys, chunk, extra):
-    """The chunk-scan scaffold shared by BOTH training layouts:
-    `block_fn(params, imgs, lbls, sizes, keys, *extra)` over the whole
-    [m, ...] block, or over sequential [chunk, ...] groups — one policy
-    (divisor rule, CPU unroll cap) so the layouts can never drift."""
     m = imgs.shape[0]
     if 0 < chunk < m and m % chunk != 0:
         # falling back to the full block would reproduce the exact
@@ -175,14 +113,14 @@ def _run_chunked(block_fn, params, imgs, lbls, sizes, keys, chunk, extra):
             f"(per-device agent count); pick a divisor or 0 for the full "
             f"block")
     if chunk <= 0 or chunk >= m:
-        return block_fn(params, imgs, lbls, sizes, keys, *extra)
+        return vt(params, imgs, lbls, sizes, keys, *extra)
     nc = m // chunk
 
     def resh(a):
         return a.reshape((nc, chunk) + a.shape[1:])
 
     def body(carry, args):
-        return carry, block_fn(params, *args)
+        return carry, vt(params, *args)
 
     # routed through maybe_unrolled_scan: XLA:CPU executes convs inside
     # while-loops via a slow reference path (ops/loops.py), so short chunk
@@ -197,27 +135,11 @@ def _run_chunked(block_fn, params, imgs, lbls, sizes, keys, chunk, extra):
 
 
 def make_block_trainer(model, cfg, normalize):
-    """The layout-dispatched client-block trainer (ISSUE 10):
+    """The client-block trainer every round builder (vmap/sharded/host/
+    cohort x per-round/chained) takes:
     train_block(params, imgs, lbls, sizes, keys, chunk=0, ep_budget=None)
-    -> (updates [m, ...]-stacked, losses [m]).
-
-    `vmap` (default) batches the per-client local_train with jax.vmap;
-    `megabatch` folds the client axis into the batch
-    (fl/client.make_local_train_megabatch). Selection consults
-    compile_cache.resolved_train_layout — the single source that also
-    degrades megabatch to vmap under --diagnostics — so every round
-    builder (vmap/sharded/host/cohort x per-round/chained) picks the
-    layout through one door."""
-    from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
-        compile_cache)
-    if compile_cache.resolved_train_layout(cfg) == "megabatch":
-        mb_train = make_local_train_megabatch(model, cfg, normalize)
-
-        def train_block(params, imgs, lbls, sizes, keys, chunk=0,
-                        ep_budget=None):
-            return megabatch_agents(mb_train, params, imgs, lbls, sizes,
-                                    keys, chunk, ep_budget=ep_budget)
-        return train_block
+    -> (updates [m, ...]-stacked, losses [m]), `vmap_agents` over the
+    per-client local_train."""
     local_train = make_local_train(model, cfg, normalize)
 
     def train_block(params, imgs, lbls, sizes, keys, chunk=0,
@@ -450,21 +372,6 @@ def _round_core(params, k_train, k_noise, imgs, lbls, sizes, *,
                 extras.update(health_sentinel.sentinel(
                     cfg, updates, new_params, mask=mask))
         return new_params, jnp.mean(losses), extras, new_astate
-    if _pallas_applicable(cfg):   # never taken when faults are configured
-        from defending_against_backdoors_with_robust_learning_rate_tpu.ops.pallas_rlr import (
-            fused_rlr_avg_apply)
-        new_params = fused_rlr_avg_apply(
-            params, updates, sizes.astype(jnp.float32),
-            float(cfg.robustLR_threshold), cfg.effective_server_lr,
-            interpret=jax.default_backend() != "tpu", mode=cfg.aggr)
-        extras = {}
-        if health_sentinel.health_on(cfg):
-            # the sentinel reads the stacked updates + committed params
-            # with plain jnp reductions OUTSIDE the fused kernel — the
-            # kernel's one-pass HBM property is untouched
-            with jax.named_scope("health"):
-                extras = health_sentinel.sentinel(cfg, updates, new_params)
-        return new_params, jnp.mean(losses), extras
     slr = (cfg.effective_server_lr if knobs is None
            else knobs.server_lr)
     with jax.named_scope("aggregate_rlr"):

@@ -3,13 +3,12 @@ into one resident program (ISSUE 13).
 
 The scenario matrix (scripts/sweep_scenarios.py) is thousands of small
 cells, and the experiment queue used to run them strictly back-to-back:
-one small CNN per dispatch leaves the chip idle exactly the way
-per-client vmap did before the PR-10 megabatch. This module applies the
-megabatch trick one level up — the Podracer play (arXiv:2104.06272:
-saturate accelerators by stacking many small workloads into one resident
-program): E independent experiment replicas that SHARE program shapes
-(same dataset, model, aggregation rule, fault/churn/attack structure)
-run as a leading tenant axis of ONE jitted round program. Per-tenant
+one small CNN per dispatch leaves the chip idle. This module makes the
+Podracer play (arXiv:2104.06272: saturate accelerators by stacking many
+small workloads into one resident program): E independent experiment
+replicas that SHARE program shapes (same dataset, model, aggregation
+rule, fault/churn/attack structure) run as a leading tenant axis of ONE
+jitted round program. Per-tenant
 params advance as a stacked [E, ...] pytree; cohorts are sampled, locally
 trained, fault-injected and aggregated together; metrics fan back out per
 tenant through the existing MetricsDrain (service/tenancy.py).
@@ -32,7 +31,7 @@ pack AND every pack of the same shape:
                   triple evaluates to always-on)
 
 Knobs that change SHAPES or program structure (dataset, m, bs, aggr,
-telemetry level, fault rates, churn process, attack strategy, layouts)
+telemetry level, fault rates, churn process, attack strategy)
 stay queue-level: the pack key (utils/compile_cache.tenant_pack_key) is
 derived from the AOT fingerprint's own field algebra, so shape- or
 program-incompatible cells can never share a pack.
@@ -40,7 +39,7 @@ program-incompatible cells can never share a pack.
 Exactness semantics: the tenant programs run the SAME ops with the same
 keys as the solo paths — per-tenant metrics are ulp-close to solo runs
 (vmap batching may re-associate reductions), and integer sign-vote
-arithmetic is exact where the megabatch precedent pins it. Dataset
+arithmetic is exact. Dataset
 CONTENT is built once from the pack's base config: for disk-backed
 datasets it is seed-free; the synthetic fallback draws from the base
 seed, so per-tenant seeds vary the key streams, not the data
@@ -165,10 +164,6 @@ def ineligible_reason(cfg) -> str:
     if cfg.diagnostics:
         return ("--diagnostics needs the per-tenant research scalars the "
                 "pack never materializes; run those cells solo")
-    if cfg.use_pallas:
-        return ("--use_pallas bakes threshold/server_lr as kernel "
-                "constants; the pack's per-tenant knobs are traced — "
-                "run pallas cells solo")
     if cfg.debug_nan:
         return "--debug_nan (checkify) runs solo"
     # buffered (agg_mode) packs stack the carried (params, state) buffer

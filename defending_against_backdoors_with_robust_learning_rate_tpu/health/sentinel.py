@@ -150,9 +150,9 @@ def _row_stats(updates, mask=None):
 
 
 def sentinel(cfg, updates, new_params, mask=None, agent_bad: bool = True):
-    """The vmap-path sentinel dict (single-device, cohort, host,
-    megabatch, buffered — every path whose updates hold the full [m]
-    cohort). Pure jnp reductions, zero collectives."""
+    """The vmap-path sentinel dict (single-device, cohort, host, buffered
+    — every path whose updates hold the full [m] cohort). Pure jnp
+    reductions, zero collectives."""
     return from_row_stats(*_row_stats(updates, mask), new_params, agent_bad)
 
 

@@ -19,16 +19,12 @@ agreement), but a boosting client scales its update without changing a
 single sign — and a coordinated boosted pair WINS contested coordinates,
 so its agreement is indistinguishable-to-anticorrelated. The norm lane
 is what sees it. Collective cost is ZERO everywhere — the
-vmap/megabatch/cohort/host/buffered paths compute both as collective-
-free [m] reductions (the tenant pack as [E, m]); the sharded leaf path
-compares each device's local agent block against the REPLICATED
-sign-sum tree the vote's own psums already produced and lets shard_map's
-``P(AGENTS_AXIS)`` out_spec stitch the [m] rows; the bucketed path rides
-the sign-sum shard on the payload all_gather the layout already pays (a
-shape change on an existing collective, never a new one — and the norm
-is local there too: each device's flat block holds its clients' FULL
-flattened updates). Pinned by the ``*_rep`` CheckSpecs in
-analysis/contracts.py at 1/8/16-way.
+vmap/cohort/host/buffered paths compute both as collective-free [m]
+reductions (the tenant pack as [E, m]); the sharded path compares each
+device's local agent block against the REPLICATED sign-sum tree the
+vote's own psums already produced and lets shard_map's
+``P(AGENTS_AXIS)`` out_spec stitch the [m] rows. Pinned by the ``*_rep``
+CheckSpecs in analysis/contracts.py at 1/8/16-way.
 
 **Host** — ``ReputationTracker`` folds the drained [m] rows into
 longitudinal per-client state keyed by REAL client ids. Each fold turns
@@ -130,26 +126,14 @@ def check(cfg) -> None:
 
 
 def reputation_on(cfg) -> bool:
-    """Is the lane compiled into cfg's round program? ``on`` forces it
-    (and gates the fused Pallas server step off, the telemetry
-    precedent); ``auto`` resolves on exactly when a sign vote exists and
-    the Pallas fused commit is NOT in use (the fused kernel owns the
-    vote internals, so there is no sign-sum tree to ride)."""
+    """Is the lane compiled into cfg's round program? ``on`` forces it;
+    ``auto`` resolves on exactly when a sign vote exists and the round
+    holds the updates beside it (not a fold)."""
     if cfg.reputation == "off" or not wants_vote(cfg):
         return False
-    if cfg.reputation == "on":
-        return True
-    if cfg.agg_path == "fold":
-        # a folded round never holds the updates beside the committed
-        # vote (fl/rounds._fold_core): `auto` stands down, `on` is refused
-        return False
-    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-        _pallas_applicable)
-    # normalize diagnostics: the engine builds a plain/diag program PAIR
-    # per run (train.py plain_cfg) and gates pallas on the PLAIN variant,
-    # so `auto` must resolve identically for both or snap rounds would
-    # carry a lane their off-snap twins lack
-    return not _pallas_applicable(cfg.replace(diagnostics=False))
+    # a folded round never holds the updates beside the committed vote
+    # (fl/rounds._fold_core): `auto` stands down, `on` is refused
+    return cfg.reputation == "on" or cfg.agg_path != "fold"
 
 
 def rep_keys(cfg):
@@ -196,30 +180,14 @@ def agree_rows(updates, sign_sums, mask=None):
         return agree
 
 
-def agree_rows_flat(flat_updates, flat_sign, real_mask, total_coords):
-    """The bucketed layout's variant: ``flat_updates`` is this device's
-    [rows, P] padded flattened agent block, ``flat_sign`` the [P] signed
-    vote vector reassembled from the payload all_gather the layout
-    already pays, ``real_mask`` the [P] real-coordinate mask (explicit
-    padding must never count as agreement or disagreement),
-    ``total_coords`` the real coordinate count. Elementwise only."""
-    with jax.named_scope("reputation"):
-        sf = jnp.sign(flat_sign.astype(jnp.float32))
-        hit = ((jnp.sign(flat_updates.astype(jnp.float32)) * sf[None, :]) > 0)
-        hit = hit & real_mask[None, :]
-        return jnp.sum(hit.astype(jnp.float32), axis=1) / total_coords
-
-
 def norm_rows(updates, mask=None):
     """[rows] rep_norm: each slot's update L2 norm over every parameter
     coordinate — the magnitude signal the sign vote cannot carry
     (``sign(5u) == sign(u)``: a boosting attacker is invisible to
     agreement but 5x the cohort's norm). ``updates`` is a pytree of
-    [rows, ...] leaves OR a single [rows, P] array (the bucketed flat
-    block, whose padding coordinates are explicit zeros and so cost
-    nothing). Masked slots read the ``MASKED`` sentinel. Pure local
-    reductions: zero collectives on every path — on sharded layouts each
-    device's block holds its clients' full coordinate set."""
+    [rows, ...] leaves. Masked slots read the ``MASKED`` sentinel. Pure
+    local reductions: zero collectives on every path — on the sharded
+    path each device's block holds its clients' full coordinate set."""
     with jax.named_scope("reputation"):
         leaves = jax.tree_util.tree_leaves(updates)
         rows = leaves[0].shape[0]

@@ -104,28 +104,22 @@ def _flip_fraction(lr_tree):
     return neg / total
 
 
-def _bucketize_margins(s, m: int, weights=None):
+def _bucketize_margins(s, m: int):
     """[B] coordinate counts of the vote margins s (values in [0, m]),
     plus their sum (for the mean): bucket i covers margins in
     [i*(m+1)/B, (i+1)*(m+1)/B). THE single source of the bucketing
-    formula for every layout; `weights` ([len(s)] f32, optional) scales
-    each coordinate's contribution — the bucketed aggregation path
-    passes its real-coordinate mask so explicit padding (margin 0) never
-    pollutes bucket 0 or the sum."""
+    formula."""
     flat = s.reshape(-1)
     idx = jnp.clip((flat.astype(jnp.int32) * N_MARGIN_BUCKETS) // (m + 1),
                    0, N_MARGIN_BUCKETS - 1)
-    counts = jnp.bincount(idx, weights=weights,
-                          length=N_MARGIN_BUCKETS).astype(jnp.float32)
-    flat = flat.astype(jnp.float32)
-    msum = jnp.sum(flat if weights is None else flat * weights)
-    return counts, msum
+    counts = jnp.bincount(idx, length=N_MARGIN_BUCKETS).astype(jnp.float32)
+    return counts, jnp.sum(flat.astype(jnp.float32))
 
 
 def _cosine_accumulators(updates_leaves, agg_leaves, mb: int):
     """([mb] dot(u_k, agg), [mb] ||u_k||^2) accumulated leaf-by-leaf —
-    the shared cosine-split arithmetic of the sharded leaf and bucketed
-    paths (their parity depends on accumulating in the same order)."""
+    the shared cosine-split arithmetic of the vmap and sharded paths
+    (their parity depends on accumulating in the same order)."""
     dots = jnp.zeros((mb,), jnp.float32)
     usq = jnp.zeros((mb,), jnp.float32)
     for u, a in zip(updates_leaves, agg_leaves, strict=True):
@@ -275,84 +269,6 @@ def compute_sharded(cfg, updates_local, lr, agg, axis_name,
                    else corrupt_full)
         valid = jnp.ones((m,), bool) if mask_full is None else mask_full
         out.update(_finish_cosine(dots, usq, _agg_sqnorm(agg),
-                                  corrupt, valid))
-        return out
-
-
-# --- bucketed (reduce-scatter) layout ------------------------------------
-
-def shard_vote_stats(cfg, sign_shard, real_mask, lr_shard, m: int):
-    """Per-device vote/flip statistics computed on the SCATTERED sign-sum
-    shard of the bucketed aggregation layout (parallel/buckets.py), packed
-    into one tiny f32 vector that rides the bucket path's result
-    all_gather — summing the gathered rows across devices yields the
-    global stats with ZERO extra collectives. Every entry is an
-    integer-valued f32 count or an exact partial sum, so the cross-device
-    sum is exact for counts. Layout (in order, entries present only when
-    their series is emitted — telemetry_keys is the single source):
-
-        [flip_neg]            robustLR on: real coords with lr < 0
-        [counts x N_MARGIN_BUCKETS, margin_sum]   full level only
-
-    `real_mask` excludes the layout's explicit padding coordinates
-    (margin 0 there would otherwise pollute bucket 0 and the flip count).
-    Returns None when nothing is needed (telemetry off, or basic with
-    RLR disabled)."""
-    stats = []
-    if lr_shard is not None:
-        stats.append(jnp.sum(jnp.where(real_mask & (lr_shard < 0),
-                                       1.0, 0.0))[None])
-    if cfg.telemetry == "full":
-        counts, margin_sum = _bucketize_margins(
-            jnp.abs(sign_shard), m,
-            weights=real_mask.astype(jnp.float32))
-        stats += [counts, margin_sum[None]]
-    if not stats:
-        return None
-    return jnp.concatenate(stats)
-
-
-def compute_sharded_bucket(cfg, updates_local, info, axis_name,
-                           mask_local=None, mask_full=None,
-                           corrupt_full=None):
-    """Telemetry dict for the bucketed aggregation path. `info` is
-    parallel/rounds._BucketInfo: the globally-summed `shard_vote_stats`
-    vector, the real coordinate count, and (full level) the replicated
-    post-noise aggregate tree reassembled from the SAME all_gather that
-    carried the LR-scaled result. Collective cost: the norm all_gather
-    (basic and up) plus the two cosine-accumulator all_gathers (full) —
-    exactly the leaf path's budget; the flip fraction and vote-margin
-    series that cost the leaf path its per-leaf sign psums (shared with
-    the RLR vote) ride the scattered layout for free."""
-    with jax.named_scope("telemetry"):
-        m = cfg.agents_per_round
-        if mask_local is not None:
-            from defending_against_backdoors_with_robust_learning_rate_tpu.faults import (
-                masking)
-            updates_local = masking.zero_masked(updates_local, mask_local)
-        norms = jax.lax.all_gather(per_agent_norms(updates_local),
-                                   axis_name, axis=0, tiled=True)
-        out = _norm_percentiles(norms)
-        total = info.total_coords
-        i = 0
-        if cfg.robustLR_threshold > 0:
-            out["tel_flip_frac"] = info.stats[0] / total
-            i = 1
-        if cfg.telemetry != "full":
-            return out
-        counts = info.stats[i:i + N_MARGIN_BUCKETS]
-        margin_sum = info.stats[i + N_MARGIN_BUCKETS]
-        out.update(_finish_margins(counts, margin_sum, total, m))
-        mb = jax.tree_util.tree_leaves(updates_local)[0].shape[0]
-        dots_l, usq_l = _cosine_accumulators(
-            jax.tree_util.tree_leaves(updates_local),
-            jax.tree_util.tree_leaves(info.agg), mb)
-        dots = jax.lax.all_gather(dots_l, axis_name, axis=0, tiled=True)
-        usq = jax.lax.all_gather(usq_l, axis_name, axis=0, tiled=True)
-        corrupt = (jnp.zeros((m,), bool) if corrupt_full is None
-                   else corrupt_full)
-        valid = jnp.ones((m,), bool) if mask_full is None else mask_full
-        out.update(_finish_cosine(dots, usq, _agg_sqnorm(info.agg),
                                   corrupt, valid))
         return out
 

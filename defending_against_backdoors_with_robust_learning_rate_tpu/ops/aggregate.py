@@ -41,10 +41,9 @@ def rlr_from_sign_sum(sign_sum, threshold, server_lr):
     """The RLR vote decision from a (raw or absolute) sign-sum array:
     +server_lr per coordinate where |sum_k sign(u_k)| >= threshold, else
     -server_lr (src/aggregation.py:48-54). THE single source of the vote
-    arithmetic — shared by the vmap tree path (`robust_lr`), the sharded
-    per-leaf psum paths (parallel/rounds.py) and the bucketed
-    reduce-scatter path, where `sign_sum` is the SCATTERED shard
-    (parallel/buckets.py) — so every layout thresholds identically.
+    arithmetic — shared by the vmap tree path (`robust_lr`) and the
+    sharded per-leaf psum paths (parallel/rounds.py) — so both threshold
+    identically.
     `threshold` may be a traced scalar (the mask-aware scaled value)."""
     return jnp.where(jnp.abs(sign_sum) >= threshold, server_lr,
                      -server_lr).astype(jnp.float32)

@@ -16,13 +16,9 @@ independent processes, src/runner.sh:12-18). Here multi-host is first-class:
   for params/datasets — every host loads the identical seeded data — and
   agents-sharded for per-agent stacks);
 - the aggregation collective PLAN matters most here: per-leaf psums
-  (`--agg_layout leaf`, 2L+2 on the flagship) are latency-bound over DCN,
-  while the bucketed plan (`--agg_layout bucket`, parallel/buckets.py)
-  runs one reduce-scatter + one all-gather per round at bandwidth — the
-  multi-process driver adopts whichever the config selects (the sharded
-  round builders read `cfg.agg_layout`), and `agg_plan_note` prints which
-  plan a mesh is about to run so pod bring-up logs show the collective
-  shape next to the topology.
+  (2L+2 on the flagship) are latency-bound over DCN, and `agg_plan_note`
+  prints the plan a mesh is about to run so pod bring-up logs show the
+  collective shape next to the topology.
 
 Single-process runs degrade transparently: every helper is a no-op or the
 trivial local construction, so the same driver code serves a laptop CPU, a
@@ -104,35 +100,16 @@ def require_pod_divisible(m: int, what: str) -> int:
     return n
 
 
-def agg_plan_note(cfg, params, mesh: Mesh) -> str:
+def agg_plan_note(cfg, params) -> str:
     """One bring-up log line for the aggregation collective plan this
-    mesh will run each round — the leaf/bucket decision is where a pod
-    run's interconnect time is won or lost, so it belongs next to the
-    `[mesh]` topology line in the driver log."""
-    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-        _pallas_applicable)
+    mesh will run each round: it belongs next to the `[mesh]` topology
+    line in the driver log."""
     n_leaves = len(jax.tree_util.tree_leaves(params))
-    d = int(mesh.devices.size)
-    if _pallas_applicable(cfg):
-        # pallas wins the plan precedence in the shard body — the note
-        # must describe the program that actually runs
-        return ("fused pallas server step: per-device partial sums + "
-                "per-leaf psums (--agg_layout is not consulted)")
-    if cfg.agg_layout == "bucket" and cfg.aggr in ("avg", "sign"):
-        from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-            buckets)
-        layout = buckets.layout_for_leaves(params, d)
-        n = layout.n_buckets + 1 + (2 if cfg.aggr == "avg" else 1)
-        return (f"bucketed aggregation: {layout.n_buckets} bucket(s) x "
-                f"{layout.bucket:,} coords ({layout.total:,} real), "
-                f"{n} collectives/round (reduce-scatter"
-                f" x{layout.n_buckets} + all-gather + scalar psums)")
     if cfg.aggr in ("avg", "sign"):
         per_leaf = 2 if (cfg.aggr == "avg"
                          and cfg.robustLR_threshold > 0) else 1
         return (f"leaf aggregation: {per_leaf} psum(s) x {n_leaves} "
-                f"leaves + scalars per round (--agg_layout bucket for "
-                f"the pod shape)")
+                f"leaves + scalars per round")
     if cfg.aggr == "rfa":
         return ("leaf aggregation: rfa's replicated Weiszfeld iterate "
                 "(two psums per iteration, no transpose)")

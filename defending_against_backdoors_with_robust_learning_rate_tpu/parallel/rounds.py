@@ -47,8 +47,6 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.ops import tree
 from defending_against_backdoors_with_robust_learning_rate_tpu.ops.aggregate import (
     RFA_EPS, RFA_ITERS, agent_sq_dists, apply_aggregate, gaussian_noise_like,
     rlr_from_sign_sum, sq_dist_accum, trmean_k)
-from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-    buckets)
 from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
     AGENTS_AXIS)
 
@@ -292,306 +290,6 @@ def _sharded_robust_lr(updates, cfg, mask_local=None, mask_full=None,
             jax.tree_util.tree_unflatten(treedef, s_leaves))
 
 
-def _bucket_applicable(cfg) -> bool:
-    """The bucketed reduce-scatter layout covers the psum-shaped rules
-    (weighted FedAvg and signSGD, RLR on or off — the paper's headline
-    configurations). The transpose rules (comed/trmean/krum) already run
-    few large collectives (all_to_all + all_gather) and keep their plan;
-    rfa's replicated Weiszfeld iterate keeps its per-iteration psums.
-    Diagnostics need the full lr tree materialized, which the scattered
-    vote never builds — `_build_sharded_body` refuses that combination
-    loudly rather than silently mixing layouts across snap rounds."""
-    return cfg.agg_layout == "bucket" and cfg.aggr in ("avg", "sign")
-
-
-class _BucketInfo:
-    """What the bucketed apply hands to telemetry: the post-noise/guard
-    aggregate tree (full level only — reassembled from the same
-    all_gather that carried the LR-scaled result), the globally-summed
-    vote/flip stats vector that rode that gather (obs/telemetry.py
-    shard_vote_stats; None when telemetry is off), the real (unpadded)
-    coordinate count, and — when the reputation lane is on — this
-    device's [m/d] rep_agree block (obs/reputation.py, computed against
-    the full sign vote whose shard rode the same gather) plus its [m/d]
-    rep_norm block (local: the flat block holds full coordinate rows)."""
-
-    def __init__(self, agg=None, stats=None, total_coords=0,
-                 rep_agree=None, rep_norm=None):
-        self.agg = agg
-        self.stats = stats
-        self.total_coords = total_coords
-        self.rep_agree = rep_agree
-        self.rep_norm = rep_norm
-
-
-def _bucketed_apply(params, updates, sizes, cfg, noise_key, d,
-                    mask_local=None, mask_full=None, knobs=None):
-    """avg/sign [+ RLR] aggregation on the bucketed flat layout
-    (parallel/buckets.py): ONE reduce-scatter per bucket of the stacked
-    partial sums (weighted sum and/or sign sum ride the SAME collective),
-    the masked weighted-average AND the RLR sign-vote computed on the
-    scattered shard, then ONE all_gather of the already-LR-scaled result.
-    Collectives on the flagship (1 bucket): reduce-scatter + all-gather
-    (+ the scalar weight-total psum for avg) — vs 2L+2 = 18 per-leaf
-    psums on the leaf layout.
-
-    Per-coordinate arithmetic is IDENTICAL to the leaf path (the flatten
-    is a relayout, the local partial sums run over the same mb rows in
-    the same order, noise is generated per leaf with the same key split,
-    the empty-electorate guard multiplies the same replicated flag), so
-    bucket-vs-leaf parity is pinned bitwise in fp32
-    (tests/test_bucket_parity.py). Padding coordinates are explicit
-    zeros: they vote margin 0 (=> lr -slr), aggregate 0, and are masked
-    out of every statistic via `shard_coord_index`.
-
-    Returns (new_params, _BucketInfo)."""
-    ax = AGENTS_AXIS
-    masked = mask_local is not None
-    rlr = cfg.robustLR_threshold > 0
-    thr = (float(cfg.robustLR_threshold) if knobs is None
-           else knobs.rlr_threshold)
-    if masked:
-        from defending_against_backdoors_with_robust_learning_rate_tpu.faults import (
-            masking)
-        updates = masking.zero_masked(updates, mask_local)
-        if rlr:
-            thr = masking.rlr_threshold(
-                cfg, mask_full,
-                base=None if knobs is None else knobs.rlr_threshold)
-    slr = cfg.effective_server_lr if knobs is None else knobs.server_lr
-    layout = buckets.layout_for_stacked(updates, d)
-    flat = buckets.flatten_stacked(layout, updates)       # [mb, padded]
-
-    # the full level reads vote margins even without RLR (the leaf path
-    # budgets its own per-leaf psums for that; here the sign sums ride
-    # the one reduce-scatter for free)
-    want_sign = rlr or cfg.aggr == "sign" or cfg.telemetry == "full"
-    rows = []
-    total = None
-    if cfg.aggr == "avg":
-        w = sizes.astype(jnp.float32)
-        if masked:
-            w = jnp.where(mask_local, w, 0.0)
-        total = jax.lax.psum(jnp.sum(w), ax)              # scalar psum
-        rows.append(jnp.sum(flat * w[:, None], axis=0))
-    if want_sign:
-        rows.append(jnp.sum(jnp.sign(flat), axis=0))
-    stacked = jnp.stack(rows)                             # [r, padded]
-    # one reduce-scatter per bucket; both quantities share each collective
-    scat = jnp.concatenate([
-        jax.lax.psum_scatter(
-            stacked[:, b * layout.bucket:(b + 1) * layout.bucket],
-            ax, scatter_dimension=1, tiled=True)
-        for b in range(layout.n_buckets)], axis=1)        # [r, device_len]
-
-    sign_s = scat[-1] if want_sign else None
-    if cfg.aggr == "avg":
-        agg_s = scat[0] / total
-    else:
-        agg_s = jnp.sign(sign_s)
-    if cfg.noise > 0:
-        # generated per leaf from the identical key split as the leaf
-        # path (gaussian_noise_like over the same tree structure), then
-        # relayed out through the flat space — bitwise the same noise
-        noise = gaussian_noise_like(params, noise_key,
-                                    cfg.noise * cfg.clip)
-        pos = jax.lax.axis_index(ax)
-        agg_s = agg_s + buckets.device_shard(
-            layout, buckets.flatten_tree(layout, noise), pos)
-    if masked:
-        agg_s = masking.guard_empty(agg_s, mask_full)
-    if rlr:
-        lr_s = rlr_from_sign_sum(sign_s, thr, slr)
-    else:
-        lr_s = None
-    delta_s = (lr_s if lr_s is not None else slr) * agg_s
-
-    # ONE all_gather carries the LR-scaled result — plus, under
-    # telemetry, the unscaled aggregate (full: the cosine split needs
-    # the replicated agg tree) and the tiny vote/flip stats vector
-    # (basic/full: summed across devices after the gather), so telemetry
-    # adds ZERO collectives here
-    from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
-        reputation as rep_mod)
-    rep_on = rep_mod.reputation_on(cfg)
-    payload = [delta_s]
-    stats_len = 0
-    if cfg.telemetry == "full":
-        payload.append(agg_s)
-    if rep_on:
-        # the reputation lane needs the FULL signed vote replicated to
-        # compare each local client block against — the sign-sum shard
-        # rides the SAME result all_gather (a widened payload, never a
-        # new collective; the *_rep CheckSpecs pin the unchanged plan)
-        payload.append(sign_s)
-    if cfg.telemetry != "off":
-        from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
-            telemetry)
-        pos = jax.lax.axis_index(ax)
-        real = buckets.shard_coord_index(layout, pos) < layout.total
-        stats = telemetry.shard_vote_stats(cfg, sign_s, real, lr_s,
-                                           cfg.agents_per_round)
-        if stats is not None:
-            payload.append(stats)
-            stats_len = stats.shape[0]
-    gathered = jax.lax.all_gather(
-        jnp.concatenate(payload) if len(payload) > 1 else payload[0],
-        ax, axis=0, tiled=True).reshape(d, -1)
-
-    dl = layout.device_len
-    treedef = jax.tree_util.tree_structure(params)
-    delta = buckets.unflatten(
-        layout, buckets.gathered_to_flat(layout, gathered[:, :dl]),
-        treedef)
-    new_params = tree.astype(
-        tree.map(lambda p, dlt: p + dlt, params, delta), jnp.float32)
-    info = _BucketInfo(total_coords=layout.total)
-    if cfg.telemetry == "full":
-        info.agg = buckets.unflatten(
-            layout, buckets.gathered_to_flat(layout, gathered[:, dl:2 * dl]),
-            treedef)
-    if rep_on:
-        off = dl * (2 if cfg.telemetry == "full" else 1)
-        sign_full = buckets.gathered_to_flat(layout,
-                                             gathered[:, off:off + dl])
-        real_full = jnp.arange(sign_full.shape[0]) < layout.total
-        info.rep_agree = rep_mod.agree_rows_flat(flat, sign_full,
-                                                 real_full, layout.total)
-        # norm is local: flat's padding coordinates are explicit zeros,
-        # so the row L2 over the padded block equals the real-coord norm
-        info.rep_norm = rep_mod.norm_rows(flat)
-    if stats_len:
-        info.stats = jnp.sum(gathered[:, -stats_len:], axis=0)
-    return new_params, info
-
-
-def _bucket_async_contribs(cfg, params, updates, szs, mask_local, T_loc,
-                           d, ax):
-    """Buffered-async contributions through the bucketed collective shape
-    (`--agg_mode buffered --agg_layout bucket`): the tick's per-level
-    partial sums flatten into level-stacked rows of the bucket layout,
-    ride ONE `psum_scatter` per bucket, and ONE `all_gather` reconstructs
-    the globally-summed rows, which unflatten back into the contribution
-    trees the shared replicated fold consumes (fl/buffered.fold_commit).
-
-    Collective count: n_buckets reduce-scatters + 1 all_gather (+ the
-    caller's packed scalar psum) — within the sync bucket plan's pinned
-    budget (reduce-scatter 1, all_gather 1, psum 2 on the flagship). The
-    gather carries `levels x quantities` rows instead of sync's one
-    LR-scaled row; a real pod deployment would fold pending state on the
-    scattered shard to keep wire bytes flat — simulation-side this keeps
-    the buffer state layout-uniform with the leaf path (one checkpoint /
-    carry shape per config), which the crash-exact drill depends on."""
-    from defending_against_backdoors_with_robust_learning_rate_tpu.faults import (
-        masking)
-    avg = cfg.aggr == "avg"
-    sgn = buffered.wants_sign(cfg)
-    layout = buckets.layout_for_stacked(updates, d)
-    if mask_local is not None:
-        updates = masking.zero_masked(updates, mask_local)
-    flat = buckets.flatten_stacked(layout, updates)      # [mb, padded]
-    w = szs.astype(jnp.float32)
-    sw = buffered._level_weights(cfg, T_loc)
-    if sw is not None:
-        w = w * sw
-    sflat = jnp.sign(flat) if sgn else None
-    avg_rows, sign_rows, cnt, wsum = [], [], [], []
-    if T_loc is None:
-        valid = (mask_local if mask_local is not None
-                 else jnp.ones(w.shape, bool))
-        wv = jnp.where(valid, w, 0.0)
-        cnt.append(masking.count_f32(valid))
-        if avg:
-            wsum.append(jnp.sum(wv))
-            avg_rows.append(jnp.sum(flat * wv[:, None], axis=0))
-        if sgn:
-            sign_rows.append(jnp.sum(sflat, axis=0))
-    else:
-        S = buffered.max_staleness(cfg)
-        valid = (mask_local if mask_local is not None
-                 else jnp.ones(T_loc.shape, bool))
-        for s in range(S + 1):
-            lvl = valid & (T_loc == s)
-            wl = jnp.where(lvl, w, 0.0)
-            cnt.append(masking.count_f32(lvl))
-            if avg:
-                wsum.append(jnp.sum(wl))
-                avg_rows.append(jnp.sum(flat * wl[:, None], axis=0))
-            if sgn:
-                sign_rows.append(
-                    jnp.sum(jnp.where(lvl[:, None], sflat, 0.0), axis=0))
-    rows = jnp.stack(avg_rows + sign_rows)               # [R, padded]
-    scat = jnp.concatenate([
-        jax.lax.psum_scatter(
-            rows[:, b * layout.bucket:(b + 1) * layout.bucket],
-            ax, scatter_dimension=1, tiled=True)
-        for b in range(layout.n_buckets)], axis=1)       # [R, device_len]
-    gathered = jax.lax.all_gather(scat, ax, axis=0)      # [d, R, dl]
-    treedef = jax.tree_util.tree_structure(params)
-
-    def row_tree(r):
-        return buckets.unflatten(
-            layout, buckets.gathered_to_flat(layout, gathered[:, r, :]),
-            treedef)
-
-    n_lvl = len(avg_rows) if avg else len(sign_rows)
-    trees = {}
-    stack = jax.tree_util.tree_map
-    if T_loc is None:
-        if avg:
-            trees["buf"] = row_tree(0)
-        if sgn:
-            trees["sign"] = row_tree(len(avg_rows))
-        return (trees, cnt[0], wsum[0] if avg else None)
-    if avg:
-        trees["buf"] = stack(lambda *xs: jnp.stack(xs),
-                             *[row_tree(s) for s in range(n_lvl)])
-    if sgn:
-        off = len(avg_rows)
-        trees["sign"] = stack(lambda *xs: jnp.stack(xs),
-                              *[row_tree(off + s) for s in range(n_lvl)])
-    return (trees, jnp.stack(cnt), jnp.stack(wsum) if avg else None)
-
-
-def _sharded_pallas_apply(params, updates, sizes, cfg):
-    """Fused server step over the mesh: ONE Pallas pass per device over each
-    local [m/d, leaf] update block (partial sign-sum + partial weighted sum,
-    the leaf consumed in place — no ravel/concat staging, VERDICT r2 weak
-    #4), psum of the partial trees, then an elementwise lr/apply that XLA
-    fuses. HBM reads U exactly once per device — the single-device kernel's
-    property (ops/pallas_rlr.py), composed with ICI collectives (XLA's
-    collective-combiner batches the per-leaf psums)."""
-    from defending_against_backdoors_with_robust_learning_rate_tpu.ops.pallas_rlr import (
-        partial_vote_avg_flat)
-
-    interp = jax.default_backend() != "tpu"
-    w = sizes.astype(jnp.float32)
-    total = jax.lax.psum(jnp.sum(w), AGENTS_AXIS)
-    wn = w / total
-    slr = cfg.effective_server_lr
-    thr = float(cfg.robustLR_threshold)
-
-    p_leaves, treedef = jax.tree_util.tree_flatten(params)
-    u_leaves = jax.tree_util.tree_leaves(updates)
-    new_leaves = []
-    for p, u in zip(p_leaves, u_leaves, strict=True):
-        mb = u.shape[0]
-        ssum, wsum = partial_vote_avg_flat(u.reshape(mb, -1), wn,
-                                           interpret=interp)
-        ssum = jax.lax.psum(ssum, AGENTS_AXIS)
-        if cfg.aggr == "sign":
-            agg = jnp.sign(ssum)
-        else:
-            agg = jax.lax.psum(wsum, AGENTS_AXIS)
-        if thr > 0:
-            lr = jnp.where(jnp.abs(ssum) >= thr, slr, -slr)
-        else:
-            lr = slr
-        new_leaves.append(
-            (p.reshape(-1).astype(jnp.float32) + lr * agg).reshape(p.shape))
-    return jax.tree_util.tree_unflatten(treedef, new_leaves)
-
-
 def _loss_and_health(cfg, losses, updates_local, new_params, mask_local, d):
     """The shard body's loss reduction, with the health-sentinel lanes
     packed into the SAME collective when the lane is on
@@ -635,10 +333,10 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
     An in-jit attack strategy (attack/registry.py) scales this device's
     corrupt rows right after local training — the flags arrive replicated
     and the transform is elementwise, so the collective plan is untouched
-    on the leaf AND bucketed layouts (pinned by the *_atk_* contract
-    specs). A *scheduled* attack adds one more trailing replicated input:
-    the scalar schedule gate, computed OUTSIDE shard_map from the round
-    index (like the churn mask — the body never needs the index itself).
+    (pinned by the *_atk_* contract specs). A *scheduled* attack adds one
+    more trailing replicated input: the scalar schedule gate, computed
+    OUTSIDE shard_map from the round index (like the churn mask — the
+    body never needs the index itself).
 
     ``mt`` (ISSUE 13, fl/tenancy.py) builds the tenant-pack variant: the
     body is `jax.vmap`ped over a leading [E] tenant axis INSIDE the
@@ -646,13 +344,13 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
     per-tenant scalar knobs, and the in-jit attack gate input is forced
     on whenever the strategy is in-jit (every tenant carries its own
     schedule window). Collectives under vmap batch over the tenant axis
-    — one psum of an [E, ...] payload, not E psums — so the leaf AND
-    bucket collective plans are unchanged by construction (pinned by the
-    *_mt CheckSpecs at 1/8/16-way)."""
+    — one psum of an [E, ...] payload, not E psums — so the collective
+    plan is unchanged by construction (pinned by the *_mt CheckSpecs at
+    1/8/16-way)."""
     from defending_against_backdoors_with_robust_learning_rate_tpu.attack import (
         registry as attack_registry)
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-        _pallas_applicable, host_takes_flags)
+        host_takes_flags)
     faults_on = cfg.faults_enabled
     # a quarantine set (health/monitor.py) rides the same replicated
     # availability-mask input as churn — the caller composes both masks
@@ -672,27 +370,11 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
     if churn_on:
         from defending_against_backdoors_with_robust_learning_rate_tpu.service import (
             churn as churn_mod)
-    # layout-dispatched client-block trainer (ISSUE 10): under
-    # --train_layout megabatch each device folds ITS m/d-client block
-    # into one [mb*bs, ...] megabatch — the fold happens inside the
-    # shard, so the collective plan is untouched by construction
     train_block = make_block_trainer(model, cfg, normalize)
     m = cfg.agents_per_round
     d = mesh.devices.size
     assert m % d == 0, f"agents_per_round={m} not divisible by mesh size {d}"
     mb = m // d
-    if cfg.agg_layout not in ("leaf", "bucket"):
-        raise ValueError(f"agg_layout must be 'leaf' or 'bucket', got "
-                         f"{cfg.agg_layout!r}")
-    if cfg.agg_layout == "bucket" and cfg.diagnostics:
-        # the scattered vote never materializes the full lr tree the
-        # diagnostics extras (lr_flat) read; mixing layouts between snap
-        # and off-snap rounds would silently compare different programs
-        raise ValueError(
-            "--agg_layout bucket does not support --diagnostics (the "
-            "lr tree is never materialized on the scattered path); "
-            "re-run with --agg_layout leaf — the per-leaf psum plan "
-            "keeps the full lr tree and supports every diagnostic")
 
     is_async = buffered.is_buffered(cfg)
 
@@ -764,30 +446,24 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
             mask_local = local(mask_full)
         if is_async:
             # buffered-async tail: this tick's per-level contributions
-            # ride the sync plan's collectives (per-leaf psums on the
-            # leaf layout, per-bucket reduce-scatter + one all_gather on
-            # the bucket layout; the tiny count/weight/loss lanes pack
-            # into ONE vector psum), then the shared replicated fold
-            # advances the carried buffer (fl/buffered.fold_commit —
-            # zero collectives of its own, pinned by the *_async specs)
+            # ride the sync plan's per-leaf psums (the tiny count/weight/
+            # loss lanes pack into ONE vector psum), then the shared
+            # replicated fold advances the carried buffer
+            # (fl/buffered.fold_commit — zero collectives of its own,
+            # pinned by the *_async specs)
             with jax.named_scope("buffered_fold"):
                 T_full = buffered.latency(
                     cfg, noise_key,
                     draw.straggler if draw is not None else None)
                 T_loc = local(T_full) if T_full is not None else None
                 loss_local = jnp.mean(losses)
-                if _bucket_applicable(cfg):
-                    g_trees, cnt_l, wsum_l = _bucket_async_contribs(
-                        cfg, params, updates, szs, mask_local, T_loc, d,
-                        AGENTS_AXIS)
-                else:
-                    c = buffered.tick_contributions(cfg, updates, szs,
-                                                    mask_local, T_loc)
-                    g_trees = {
-                        k: tree.map(
-                            lambda x: jax.lax.psum(x, AGENTS_AXIS), c[k])
-                        for k in ("buf", "sign") if k in c}
-                    cnt_l, wsum_l = c["cnt"], c.get("wsum")
+                c = buffered.tick_contributions(cfg, updates, szs,
+                                                mask_local, T_loc)
+                g_trees = {
+                    k: tree.map(
+                        lambda x: jax.lax.psum(x, AGENTS_AXIS), c[k])
+                    for k in ("buf", "sign") if k in c}
+                cnt_l, wsum_l = c["cnt"], c.get("wsum")
                 lanes = [jnp.atleast_1d(cnt_l)]
                 if wsum_l is not None:
                     lanes.append(jnp.atleast_1d(wsum_l))
@@ -845,23 +521,9 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
                 extras["rep_norm"] = rep_mod.norm_rows(updates,
                                                        mask=mask_local)
             return (new_params, new_astate), loss, extras
-        if _pallas_applicable(cfg):
-            new_params = _sharded_pallas_apply(params, updates, szs, cfg)
-            loss, hextras = _loss_and_health(cfg, losses, updates,
-                                             new_params, None, d)
-            return new_params, loss, hextras
         sign_sums = None
-        bucket_info = None
         with jax.named_scope("aggregate_rlr"):
-            if _bucket_applicable(cfg):
-                # pod-shape plan: per-bucket reduce-scatter + one
-                # all_gather of the LR-scaled result, vote + average on
-                # the scattered shard (parallel/buckets.py)
-                lr = agg = None
-                new_params, bucket_info = _bucketed_apply(
-                    params, updates, szs, cfg, noise_key, d,
-                    mask_local, mask_full, knobs=knobs)
-            elif cfg.robustLR_threshold > 0 and cfg.aggr == "sign":
+            if cfg.robustLR_threshold > 0 and cfg.aggr == "sign":
                 # vote + aggregate share one sign-sum psum per leaf (the
                 # CSE XLA was measured not to do — see _sharded_sign_shared)
                 lr, agg, sign_sums = _sharded_sign_shared(
@@ -901,46 +563,24 @@ def _build_sharded_body(cfg, model, normalize, mesh, take_flags=None,
         if cfg.telemetry != "off":
             from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
                 telemetry)
-            if bucket_info is not None:
-                # the vote/flip stats and (full) the aggregate tree rode
-                # the bucketed result all_gather — zero extra psums, the
-                # leaf path's sign_sums sharing discipline on the new
-                # layout
-                extras.update(telemetry.compute_sharded_bucket(
-                    cfg, updates, bucket_info, AGENTS_AXIS,
-                    mask_local=mask_local, mask_full=mask_full,
-                    corrupt_full=corrupt_full))
-            else:
-                # sign_sums: the vote's per-leaf psum results, so full
-                # telemetry's margin histogram re-reads the existing
-                # collective instead of duplicating it per leaf
-                extras.update(telemetry.compute_sharded(
-                    cfg, updates,
-                    lr if cfg.robustLR_threshold > 0 else None, agg,
-                    AGENTS_AXIS, mask_local=mask_local, mask_full=mask_full,
-                    corrupt_full=corrupt_full, sign_sums=sign_sums))
+            # sign_sums: the vote's per-leaf psum results, so full
+            # telemetry's margin histogram re-reads the existing
+            # collective instead of duplicating it per leaf
+            extras.update(telemetry.compute_sharded(
+                cfg, updates,
+                lr if cfg.robustLR_threshold > 0 else None, agg,
+                AGENTS_AXIS, mask_local=mask_local, mask_full=mask_full,
+                corrupt_full=corrupt_full, sign_sums=sign_sums))
         from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
             reputation as rep_mod)
         if rep_mod.reputation_on(cfg):
-            if bucket_info is not None:
-                # computed inside _bucketed_apply against the full vote
-                # whose shard rode the existing result all_gather
-                rep_local = bucket_info.rep_agree
-                rep_nrm = bucket_info.rep_norm
-                if mask_local is not None:
-                    rep_local = jnp.where(mask_local, rep_local,
-                                          rep_mod.MASKED)
-                    rep_nrm = jnp.where(mask_local, rep_nrm,
-                                        rep_mod.MASKED)
-            else:
-                # leaf layout: the vote's replicated sign-sum psums,
-                # re-read — local [m/d] block, stitched to [m] by the
-                # P(AGENTS_AXIS) out_spec, zero collectives
-                rep_local = rep_mod.agree_rows(updates, sign_sums,
-                                               mask=mask_local)
-                rep_nrm = rep_mod.norm_rows(updates, mask=mask_local)
-            extras["rep_agree"] = rep_local
-            extras["rep_norm"] = rep_nrm
+            # the vote's replicated sign-sum psums, re-read — local
+            # [m/d] block, stitched to [m] by the P(AGENTS_AXIS)
+            # out_spec, zero collectives
+            extras["rep_agree"] = rep_mod.agree_rows(updates, sign_sums,
+                                                     mask=mask_local)
+            extras["rep_norm"] = rep_mod.norm_rows(updates,
+                                                   mask=mask_local)
         if cfg.diagnostics:
             from defending_against_backdoors_with_robust_learning_rate_tpu.fl.diagnostics import (
                 per_agent_norms)
@@ -1095,9 +735,8 @@ def make_sharded_round_fn_mt(cfg, model, normalize, mesh,
     round(params_E, keys_E, rnd, knobs) -> (params_E, info) with every
     carried array [E]-stacked and the tenant axis folded INSIDE the
     shard (each device trains its m/d-agent block for all E tenants; the
-    per-leaf psums / bucketed reduce-scatters batch over the tenant axis
-    instead of multiplying — the *_mt CheckSpecs pin the unchanged plan
-    at 1/8/16-way). Per-tenant sampling, corrupt flags, churn masks and
+    per-leaf psums batch over the tenant axis instead of multiplying — the
+    *_mt CheckSpecs pin the unchanged plan at 1/8/16-way). Per-tenant sampling, corrupt flags, churn masks and
     schedule gates are computed OUTSIDE shard_map from the per-tenant
     keys/knobs and enter replicated, the solo body's exact discipline.
     Buffered mode carries (params_E, astate_E) — both [E]-stacked,

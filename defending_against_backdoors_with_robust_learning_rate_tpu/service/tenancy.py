@@ -35,10 +35,9 @@ offset = -pack_round, so its key streams and schedule gates replay the
 solo program exactly while the rest of the pack keeps training.
 
 Exactness: per-tenant results are parity-pinned against solo runs
-(tests/test_tenancy.py — ulp-close floats, bitwise sign-rule params
-where the megabatch precedent pins it; dataset content comes from the
-pack's FIRST cell, which only matters for the seed-keyed synthetic
-fallback). Checkpointing/heartbeat/spans are per-run facilities the pack
+(tests/test_tenancy.py — ulp-close floats, bitwise sign-rule params;
+dataset content comes from the pack's FIRST cell, which only matters for
+the seed-keyed synthetic fallback). Checkpointing/heartbeat/spans are per-run facilities the pack
 deliberately skips — queue cells are one-shot; run such cells solo.
 """
 

@@ -225,33 +225,12 @@ class RoundEngine:
                 f"queue (service/queue.py --tenants E, or "
                 f"scripts/sweep_scenarios.py --tenants E); train.run "
                 f"runs a single experiment — drop --tenants here")
-        resolved_layout = compile_cache.resolved_train_layout(cfg)
-        if cfg.train_layout != resolved_layout:
-            # same shape as the bucket+diagnostics refusal, but megabatch
-            # has an exact fallback, so degrade loudly instead of dying:
-            # the per-client loss curves (and the diag-variant program
-            # pairing) want the per-client axis, and mixing layouts
-            # between snap and off-snap rounds would silently compare
-            # different programs. The resolver is the single source of
-            # the degrade rule; the engine only normalizes cfg to it.
-            print(f"[layout] --train_layout {cfg.train_layout} does not "
-                  f"support --diagnostics (per-client loss curves need "
-                  f"the per-client axis); degrading this run to "
-                  f"--train_layout {resolved_layout} — drop "
-                  f"--diagnostics to keep the {cfg.train_layout} layout")
-            cfg = cfg.replace(train_layout=resolved_layout)
         self.cfg = cfg
         self._resume_upto = resume_upto
         print_exp_details(cfg)
         self.device = device_record()
         print("[device] platform={platform} kind={kind} n={count}"
               .format(**self.device))
-        if compile_cache.resolved_train_layout(cfg) == "megabatch":
-            print("[layout] megabatch local training: the client axis "
-                  "folds into the batch — one [m*bs, ...] gather + "
-                  "normalize pass per minibatch step with "
-                  "client-segmented loss/mask reductions (fl/client.py; "
-                  "--train_layout vmap restores the per-client layout)")
         obs_telemetry.check_level(cfg.telemetry)
         # health-lane + policy validation (health/monitor.py), loudly
         # and before any build
@@ -268,8 +247,8 @@ class RoundEngine:
         if atk_banner:
             print(atk_banner)
         # buffered-async validation (fl/buffered.py: order-statistic
-        # aggregators, diagnostics, pallas, host-sampled — each refusal
-        # names its remediation)
+        # aggregators, diagnostics, host-sampled — each refusal names
+        # its remediation)
         buffered_mod.check(cfg)
         self.async_mode = async_mode = buffered_mod.is_buffered(cfg)
         async_banner = buffered_mod.banner(cfg)
@@ -289,8 +268,7 @@ class RoundEngine:
             print(f"[telemetry] in-jit defense telemetry: {cfg.telemetry} "
                   f"(Defense/* scalars ride the metrics stream)")
         # reputation-plane validation (obs/reputation.py), loudly and
-        # before any build; the lane itself resolves after the pallas
-        # decision (`auto` rides the jnp paths only)
+        # before any build
         obs_reputation.check(cfg)
         # persistent XLA cache + AOT executable bank — must be configured
         # before the first compile so every program family persists
@@ -473,7 +451,7 @@ class RoundEngine:
             print(f"[mesh] {n_mesh} devices on the `agents` axis "
                   f"({cfg.agents_per_round // n_mesh} agents/device), "
                   f"{jax.process_count()} process(es)")
-            print(f"[agg] {multihost.agg_plan_note(cfg, params, mesh)}")
+            print(f"[agg] {multihost.agg_plan_note(cfg, params)}")
             with tracer.span("setup/build_programs"):
                 round_fn = make_sharded_round_fn(plain_cfg, model, norm,
                                                  mesh, *arrays)
@@ -533,7 +511,7 @@ class RoundEngine:
                               f"cohort-sampled")
                         from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
                             multihost as mh)
-                        print(f"[agg] {mh.agg_plan_note(cfg, params, mesh)}")
+                        print(f"[agg] {mh.agg_plan_note(cfg, params)}")
                         agents_sharding = NamedSharding(mesh, P(AGENTS_AXIS))
                         block_sharding = NamedSharding(mesh,
                                                        P(None, AGENTS_AXIS))
@@ -884,23 +862,6 @@ class RoundEngine:
                 chained_fn = guard_round_fn(chained_fn)
             if host_chained_fn is not None:
                 host_chained_fn = guard_round_fn(host_chained_fn)
-
-        if cfg.use_pallas:
-            from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-                _pallas_applicable)
-            if n_mesh > 1 and _pallas_applicable(plain_cfg):
-                print("[pallas] sharded fused server step: one Pallas pass "
-                      "per device + psum of the sign/avg partials")
-            elif _pallas_applicable(plain_cfg):
-                msg = "[pallas] fused RLR+FedAvg+apply server kernel enabled"
-                if cfg.diagnostics:
-                    msg += (" (snap rounds use the jnp path: diagnostics "
-                            "need the explicit lr vector)")
-                print(msg)
-            else:
-                print(f"[pallas] fused kernel covers aggr=avg/sign with "
-                      f"noise=0; aggr={cfg.aggr!r} noise={cfg.noise} falls "
-                      f"back to the jnp path")
 
         with tracer.span("setup/build_programs"):
             # the image task's builder stays this module's own name: the

@@ -37,22 +37,8 @@ Program families (the manifest vocabulary; see `plan_programs`):
     round_sharded_cohort    bank (data/bank.py + data/cohort.py)
     round_sharded /         shard_map variants (parallel/rounds.py) —
     chained_sharded         adopted at runtime, banked best-effort;
-                            `--agg_layout bucket` (ISSUE 8) swaps their
-                            aggregation plan to the bucketed
-                            reduce-scatter program — same family names,
-                            distinct fingerprints (agg_layout is a
-                            program field), and the analysis passes plan
-                            them per topology through
-                            `plan_sharded_programs`
-    *_mb                    `--train_layout megabatch` (ISSUE 10) twins
-                            of every round/chained family above
-                            (`family_suffix`): the local-training
-                            compute layout folds the client axis into
-                            the batch (fl/client.py), a DIFFERENT traced
-                            program with its own name so the AOT
-                            manifest, the analysis passes and the driver
-                            log all say which layout ran. Eval families
-                            never suffix (no client axis).
+                            the analysis passes plan them per topology
+                            through `plan_sharded_programs`
     eval_val / eval_poison  the two eval-set program instances
 
 Every entry is a pair of files in `<root>/aot/`: `<family>-<fp>.jex`
@@ -150,12 +136,6 @@ EXCLUDED_FIELDS = frozenset({
     # fingerprint, the `telemetry` rule)
     "rep_population_cap", "rep_topk", "rep_streak",
     "defense_flip_frac_hi", "defense_low_margin_hi",
-    # NOT here: `agg_layout` (ISSUE 8). It selects the sharded
-    # aggregation program (per-leaf psums vs bucketed reduce-scatter,
-    # parallel/rounds.py reads it at trace time), so it must stay in the
-    # fingerprint even though the sharded families are never banked —
-    # the same rule as `telemetry`: a traced read makes it program
-    # provenance, and the audit fails closed on excluding it.
 })
 
 # families built from cfg.replace(diagnostics=False) in the driver; their
@@ -254,23 +234,6 @@ def abstractify(tree):
 def _arg_shapes(example_args) -> List[Tuple[str, str]]:
     return [(str(tuple(l.shape)), str(l.dtype))
             for l in jax.tree_util.tree_leaves(abstractify(example_args))]
-
-
-def resolved_train_layout(cfg) -> str:
-    """Single source of the local-training compute layout (ISSUE 10):
-    `--train_layout megabatch` degrades to vmap under `--diagnostics`
-    (per-client loss curves want the per-client axis; mixing layouts
-    between snap and off-snap rounds would silently compare different
-    programs — the engine prints the loud hint). The AOT fingerprint
-    keys THIS resolved value, so a degraded megabatch config shares the
-    vmap run's cache entries instead of splitting them."""
-    layout = getattr(cfg, "train_layout", "vmap")
-    if layout not in ("vmap", "megabatch"):
-        raise ValueError(
-            f"train_layout must be 'vmap' or 'megabatch', got {layout!r}")
-    if layout == "megabatch" and cfg.diagnostics:
-        return "vmap"
-    return layout
 
 
 # `--remat_policy auto`: the tagged convolution outputs of every example in
@@ -487,9 +450,6 @@ def unsupported(cfg, folded: bool) -> List[str]:
     rules = [
         (tokens and not token_model, who,
          f"trains a token model: pass --arch={'|'.join(models.TOKEN_ARCHS)}."),
-        (tokens and resolved_train_layout(cfg) == "megabatch", who,
-         "has no megabatch trainer (fl/client.make_local_train_megabatch "
-         "folds image batches): use --train_layout vmap."),
         (cfg.mesh != 1, who,
          "does not run under --mesh: the sharded body (parallel/rounds.py) "
          "takes the update stack and image shards."),
@@ -503,9 +463,6 @@ def unsupported(cfg, folded: bool) -> List[str]:
          "has no tenant-packed family: drop --tenants."),
         (buffered.is_buffered(cfg), who,
          "does not buffer: use --agg_mode sync."),
-        (cfg.use_pallas, who,
-         "has no Pallas server step (the kernel reads the whole stack): "
-         "drop --use_pallas."),
         (cfg.diagnostics, who,
          "writes no --diagnostics (they need the learning-rate vector and "
          "every client's norms against it)."),
@@ -528,17 +485,14 @@ def unsupported(cfg, folded: bool) -> List[str]:
 
 
 def family_suffix(cfg) -> str:
-    """Program-family name suffix for the aggregation mode + resolved
-    training layout + tenancy: buffered-async families (`round_async`,
-    ..., fl/buffered.py), megabatch families (`round_mb`, ...) and
+    """Program-family name suffix for the aggregation mode + tenancy:
+    buffered-async families (`round_async`, ..., fl/buffered.py) and
     tenant-pack families (`round_mt`, ..., fl/tenancy.py) are DISTINCT
-    programs with distinct names — and they compose (`round_mb_mt`) —
-    so manifests, contracts and driver logs never conflate them."""
+    programs with distinct names — and they compose (`round_async_mt`)
+    — so manifests, contracts and driver logs never conflate them."""
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
         buffered)
     sfx = "_async" if buffered.is_buffered(cfg) else ""
-    if resolved_train_layout(cfg) == "megabatch":
-        sfx += "_mb"
     if getattr(cfg, "tenants", 0) > 0:
         sfx += "_mt"
     return sfx
@@ -568,11 +522,8 @@ def fingerprint(cfg, family: str, example_args) -> str:
         fields.pop(name, None)
     if family not in _DIAG_FAMILIES:
         fields["diagnostics"] = False
-    # the RESOLVED layout keys the cache (a diagnostics-degraded
-    # megabatch config runs the vmap programs — same key, no split)
-    fields["train_layout"] = resolved_train_layout(cfg)
-    # likewise the RESOLVED remat policy: `auto` shares the key of what
-    # it resolves to, and is never a key of its own
+    # the RESOLVED remat policy: `auto` shares the key of what it
+    # resolves to, and is never a key of its own
     fields["remat_policy"] = resolved_remat(cfg).policy
     # and the RESOLVED aggregation path, from the parameters the program
     # takes (its lead argument; in buffered mode the carry's first half):
@@ -644,7 +595,6 @@ def tenant_pack_key(cfg) -> str:
     for name in TENANT_KNOB_FIELDS:
         fields.pop(name, None)
     fields.pop("tenants", None)
-    fields["train_layout"] = resolved_train_layout(cfg)
     fields["_schedule"] = (cfg.rounds, cfg.snap, cfg.chain)
     meta = {"cfg": {k: repr(v) for k, v in sorted(fields.items())},
             "jax": jax.__version__,
@@ -964,10 +914,6 @@ def plan_programs(cfg, model, norm, fed,
     from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
         init_params)
 
-    # normalize the layout ONCE so the plain/diag variants derived below
-    # agree with the engine's diagnostics degrade (train.py prints the
-    # hint; here the degrade must simply hold)
-    cfg = cfg.replace(train_layout=resolved_train_layout(cfg))
     sfx = family_suffix(cfg)
     cohort_mode = is_cohort_mode(cfg, fed)
     if host_mode is None:
@@ -1149,9 +1095,6 @@ def plan_sharded_programs(cfg, model, norm, fed, mesh,
     from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
         init_params)
 
-    # same layout normalization as plan_programs (the plain/diag variants
-    # below must agree with the engine's diagnostics degrade)
-    cfg = cfg.replace(train_layout=resolved_train_layout(cfg))
     sfx = family_suffix(cfg)
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
         task)
@@ -1173,8 +1116,8 @@ def plan_sharded_programs(cfg, model, norm, fed, mesh,
     if getattr(cfg, "tenants", 0) > 0:
         # sharded tenant pack (ISSUE 13): the tenant axis folds INSIDE
         # the shard (parallel/rounds.make_sharded_round_fn_mt) so the
-        # leaf/bucket collective plans are unchanged — the *_mt
-        # CheckSpecs pin that at 1/8/16-way
+        # collective plan is unchanged — the *_mt CheckSpecs pin that
+        # at 1/8/16-way
         from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
             tenancy)
         from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (

@@ -122,20 +122,12 @@ def run_name(cfg) -> str:
         # (run-name-blind rule; the empty default stays cell-free so
         # every historical dir is preserved)
         qrt = f"-qrt:{str(cfg.quarantine).replace(',', '.')}"
-    layout = ""
-    if compile_cache.resolved_train_layout(cfg) == "megabatch":
-        # training-layout cell (ISSUE 10): megabatch results are only
-        # ulp-equal to vmap's, so the two layouts must not share a run
-        # dir (their metrics streams would interleave). The RESOLVED
-        # layout names the dir — a diagnostics-degraded megabatch run
-        # lands in (and is comparable to) the vmap dir it actually ran.
-        layout = "-tl:mb"
     return (f"clip_val:{cfg.clip}"
             f"-noise_std:{cfg.noise}-aggr:{cfg.aggr}"
             f"-s_lr:{cfg.effective_server_lr}-num_cor:{cfg.num_corrupt}"
             f"-thrs_robustLR:{cfg.robustLR_threshold}"
             f"-pttrn:{cfg.pattern_type}-seed:{cfg.seed}"
-            f"{faults}{churn}{traffic}{cohort}{atk}{agm}{qrt}{layout}")
+            f"{faults}{churn}{traffic}{cohort}{atk}{agm}{qrt}")
 
 
 class NullWriter:

@@ -14,8 +14,8 @@ Runs, in order, with a non-zero exit on any finding:
    contracts.TOPOLOGIES (1/8/16-way `agents` meshes, faked CPU devices —
    the tests/conftest.py trick at pod width), including the compiled-HLO
    collective ceilings when --compiled (the CI default) is given — so
-   the gate judges the leaf AND bucketed aggregation plans at pod
-   shapes, not just the 8-way CI mesh;
+   the gate judges the aggregation plan at pod shapes, not just the
+   8-way CI mesh;
 5. program-family coverage fixpoint (coverage — the reachable family
    lattice derived from compile_cache.family_suffix's own field algebra
    crossed with every planner surface, checked against CheckSpecs,

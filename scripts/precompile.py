@@ -36,9 +36,6 @@ def main():
                     help="chained-block length to bank (bench.py default); "
                          "the per-round + eval families are banked "
                          "regardless")
-    ap.add_argument("--train_layouts", default="vmap,megabatch",
-                    help="comma list of local-training layouts to bank "
-                         "(ISSUE 10): both by default")
     ap.add_argument("--rng_impl", choices=("auto", "threefry", "rbg"),
                     default="auto",
                     help="PRNG bit generator — must match the later run "
@@ -79,13 +76,9 @@ def main():
     bank = compile_cache.AotBank(root)
     print(f"[precompile] cache root: {root}", file=sys.stderr)
 
-    import itertools
-    layouts = [t for t in args.train_layouts.split(",") if t]
     summary = []
-    for name, layout in itertools.product(
-            [c for c in args.configs.split(",") if c], layouts):
-        cfg = bench_config(name, compile_cache_dir=args.cache_dir,
-                           train_layout=layout)
+    for name in (c for c in args.configs.split(",") if c):
+        cfg = bench_config(name, compile_cache_dir=args.cache_dir)
         # chain/snap only select WHICH families the planner emits (both are
         # excluded from fingerprints; the round_ids length pins the shape)
         cfg = cfg.replace(chain=args.chain, snap=max(1, args.chain))

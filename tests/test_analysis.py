@@ -475,49 +475,6 @@ def test_telemetry_full_shares_the_vote_psums():
     assert f == [] and rec["collectives"] == {}
 
 
-def test_bucket_budgets_per_topology():
-    """ISSUE-8 acceptance: the bucketed flagship plan is 4 collectives
-    (1 reduce-scatter + 1 all_gather + 2 scalar psums) and HOLDS at
-    every traceable topology — the same counts at a 1-way and the 8-way
-    mesh here, and the pod-shape (@16w) records are pinned in
-    analysis_baseline.json by scripts/check_static.py (16 faked devices
-    exceed this suite's conftest mesh)."""
-    specs = contracts.check_specs()
-    plan = {"all_gather": 1, "psum": 2, "reduce_scatter": 1}
-    for d in (1, 8):
-        findings, rec = jaxpr_lint.check_family(
-            specs["sharded_rlr_avg_bucket"], mesh_size=d)
-        assert findings == [], (d, findings)
-        assert rec["collectives"] == plan, d
-
-    path = jaxpr_lint.baseline_path(REPO)
-    with open(path) as f:
-        pinned = json.load(f)["families"]
-    for key in ("sharded_rlr_avg_bucket", "sharded_rlr_avg_bucket@1w",
-                "sharded_rlr_avg_bucket@16w", "sharded_rlr_sign_bucket",
-                "sharded_rlr_sign_bucket@16w",
-                "sharded_rlr_avg@16w"):
-        assert key in pinned, f"{key} missing from analysis_baseline.json"
-    # topology-free by design: the pod-shape counts equal the 8-way ones
-    assert pinned["sharded_rlr_avg_bucket@16w"]["collectives"] == plan
-    assert pinned["sharded_rlr_avg_bucket"]["collectives"] == plan
-
-
-def test_bucket_telemetry_rides_the_result_gather():
-    """Full telemetry on the bucketed layout costs ZERO extra psums and
-    the SAME 3 tiny all_gathers as the leaf plan (norms + two cosine
-    accumulators) — the flip/margin stats ride the result all_gather."""
-    specs = contracts.check_specs()
-    _, plain = jaxpr_lint.check_family(specs["sharded_rlr_avg_bucket"])
-    findings, tel = jaxpr_lint.check_family(
-        specs["sharded_rlr_avg_bucket_tel_full"])
-    assert findings == []
-    assert tel["collectives"]["psum"] == plain["collectives"]["psum"]
-    assert tel["collectives"]["reduce_scatter"] == 1
-    assert tel["collectives"]["all_gather"] == \
-        plain["collectives"]["all_gather"] + 3
-
-
 def test_faults_adds_exactly_one_all_gather():
     _, plain = jaxpr_lint.check_family(
         contracts.check_specs()["sharded_rlr_avg"])
@@ -579,8 +536,7 @@ def test_async_budgets_and_baseline_pins():
     """ISSUE-12 acceptance: the buffered-async families keep each mode's
     pinned plan — avg+RLR within the 2L+2 psum budget (measured 2L+1:
     the packed count/weight/loss lane replaces the weight psum + loss
-    pmean), the bucket plan at reduce-scatter 1 / all_gather 1 / psum 1,
-    faults + the staleness-stacked pending shape still exactly one
+    pmean), faults + the staleness-stacked pending shape still exactly one
     [m]-bit validation all_gather — and the counts are topology-free
     (the @16w pod-shape records land via scripts/check_static.py)."""
     specs = contracts.check_specs()
@@ -591,12 +547,10 @@ def test_async_budgets_and_baseline_pins():
     path = jaxpr_lint.baseline_path(REPO)
     with open(path) as f:
         pinned = json.load(f)["families"]
-    for key in ("vmap_rlr_avg_async", "vmap_rlr_avg_async_mb",
+    for key in ("vmap_rlr_avg_async",
                 "sharded_rlr_avg_async", "sharded_rlr_avg_async@16w",
                 "sharded_rlr_sign_async", "sharded_rlr_avg_async_stale",
                 "sharded_rlr_avg_async_faults",
-                "sharded_rlr_avg_bucket_async",
-                "sharded_rlr_avg_bucket_async@16w",
                 "sharded_chained_rlr_avg_async",
                 "sharded_rlr_avg_cohort_async"):
         assert key in pinned, f"{key} missing from analysis_baseline.json"
@@ -605,8 +559,6 @@ def test_async_budgets_and_baseline_pins():
     assert pinned["sharded_rlr_avg_async@16w"]["collectives"] == \
         pinned["sharded_rlr_avg_async"]["collectives"] == {"psum": 17}
     assert pinned["sharded_rlr_sign_async"]["collectives"] == {"psum": 9}
-    assert pinned["sharded_rlr_avg_bucket_async"]["collectives"] == {
-        "all_gather": 1, "psum": 1, "reduce_scatter": 1}
     # stale (pending-ladder shapes) + faults: exactly one all_gather each
     for key in ("sharded_rlr_avg_async_stale",
                 "sharded_rlr_avg_async_faults"):
@@ -864,17 +816,16 @@ def test_coverage_new_suffix_branch_fails_loudly(tmp_path):
 
 def test_suffix_tokens_match_driver_table():
     tokens = coverage.suffix_tokens(REPO)
-    assert tokens == ["_async", "_mb", "_mt"]
+    assert tokens == ["_async", "_mt"]
     assert set(tokens) == set(contracts.SUFFIX_DRIVERS)
 
 
 def test_run_name_walk_sees_getattr_and_new_fields():
-    """run_name reads agg_mode/train_layout through getattr helpers
-    (is_buffered, resolved_train_layout) — the walker must see through
-    both; the four fields the coverage pass surfaced as collision bugs
-    must now mark the run dir."""
+    """run_name reads agg_mode through a getattr helper (is_buffered) —
+    the walker must see through it; the four fields the coverage pass
+    surfaced as collision bugs must now mark the run dir."""
     fields = coverage.run_name_fields(REPO)
-    for f in ("agg_mode", "train_layout", "corrupt_mode",
+    for f in ("agg_mode", "corrupt_mode",
               "straggler_epochs", "traffic_latency_sigma", "quarantine"):
         assert f in fields, f
 

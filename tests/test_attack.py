@@ -263,20 +263,6 @@ def test_chain_budget_host_attack_disables_chaining():
                                       host_mode=True) == 4
 
 
-def test_pallas_falls_back_under_in_jit_attack():
-    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
-        _pallas_applicable)
-    assert _pallas_applicable(tiny_cfg(use_pallas=True,
-                                       robustLR_threshold=4))
-    assert not _pallas_applicable(tiny_cfg(use_pallas=True,
-                                           robustLR_threshold=4,
-                                           attack="signflip"))
-    # data-side strategies keep the fused kernel (nothing in-jit changes)
-    assert _pallas_applicable(tiny_cfg(use_pallas=True,
-                                       robustLR_threshold=4,
-                                       attack="dba"))
-
-
 def test_step_takes_round_single_source():
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
         step_takes_round)

@@ -4,8 +4,8 @@ The degenerate-case parity pins are the acceptance backbone: with K=m,
 staleness 0 (no stragglers) and ``async_staleness_exp=0`` the buffered
 tick's fold degenerates to the sync round's exact op sequence —
 bit-identical for sign (integer sign-sums reduce exactly in any order),
-ulp-close for avg — on the vmap path AND the 8-way shard_map mesh (leaf
-and bucket layouts). On top of that: commit cadence (K=2m commits every
+ulp-close for avg — on the vmap path AND the 8-way shard_map mesh. On
+top of that: commit cadence (K=2m commits every
 other tick), the pending-arrival ladder (latencies land T ticks later
 with staleness T, cross-checked against the host mirror draw), chained ==
 per-round, the per-staleness Defense split, loud refusals, and the
@@ -97,21 +97,18 @@ def test_vmap_parity_sign_bitwise():
 def test_vmap_parity_avg_ulp():
     """Same pin for weighted FedAvg + RLR: the fold arithmetic mirrors
     the sync op sequence (measured bitwise on XLA:CPU; pinned at 1e-6
-    for cross-toolchain headroom, the bucket-parity tier rule)."""
+    for cross-toolchain headroom)."""
     cfg = base_check_config()
     ps, pa, _, _ = _run_pair(cfg)
     for a, b in zip(_leaves(ps), _leaves(pa), strict=True):
         np.testing.assert_allclose(a, b, rtol=1e-6, atol=1e-7)
 
 
-@pytest.mark.parametrize("agg_layout", ["leaf", "bucket"])
-def test_sharded_parity_sign_bitwise(agg_layout):
-    """The 8-way shard_map pin, sign+RLR bitwise — on the per-leaf psum
-    plan AND the bucketed reduce-scatter plan (the contribution sums ride
-    each plan's own collectives; fl/buffered.fold_commit is shared)."""
+def test_sharded_parity_sign_bitwise():
+    """The 8-way shard_map pin, sign+RLR bitwise (the contribution sums
+    ride the per-leaf psum plan; fl/buffered.fold_commit is shared)."""
     mesh = make_mesh(8)
-    cfg = base_check_config().replace(aggr="sign", server_lr=1.0,
-                                      agg_layout=agg_layout)
+    cfg = base_check_config().replace(aggr="sign", server_lr=1.0)
     ps, pa, _, info_a = _run_pair(cfg, mesh=mesh)
     for a, b in zip(_leaves(ps), _leaves(pa), strict=True):
         np.testing.assert_array_equal(a, b)
@@ -119,7 +116,7 @@ def test_sharded_parity_sign_bitwise(agg_layout):
 
 
 def test_sharded_parity_avg_ulp():
-    """8-way avg+RLR parity at the bucket-parity ulp tier."""
+    """8-way avg+RLR parity at the ulp tier of a cross-device sum."""
     mesh = make_mesh(8)
     cfg = base_check_config()
     ps, pa, _, _ = _run_pair(cfg, mesh=mesh)
@@ -254,8 +251,6 @@ def test_refusals_are_loud():
         ck(buf.replace(aggr="comed"))
     with pytest.raises(ValueError, match="diagnostics"):
         ck(buf.replace(diagnostics=True))
-    with pytest.raises(ValueError, match="pallas"):
-        ck(buf.replace(use_pallas=True))
     with pytest.raises(ValueError, match="async_buffer_k"):
         ck(buf.replace(async_buffer_k=-1))
     with pytest.raises(ValueError, match="async_max_staleness"):
@@ -273,7 +268,7 @@ def test_family_suffix_and_fingerprint_split():
     cfg = Config(agg_mode="buffered")
     assert compile_cache.family_suffix(cfg) == "_async"
     assert compile_cache.family_suffix(
-        cfg.replace(train_layout="megabatch")) == "_async_mb"
+        cfg.replace(tenants=2)) == "_async_mt"
     assert compile_cache.family_suffix(Config()) == ""
     ex = (jnp.zeros(3),)
     assert compile_cache.fingerprint(cfg, "round_async", ex) != \

@@ -5,7 +5,6 @@ with per-round dispatch: round r's key is fold_in(base_key, r) in both paths
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.config import Config
 from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
@@ -41,12 +40,6 @@ def _assert_trees_close(a, b, **kw):
         np.testing.assert_allclose(np.asarray(x), np.asarray(y), **kw)
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 11): the single-device
-# builder-level chain parity is redundantly covered by its cheap twins —
-# test_run_with_chain_matches_unchained (driver-level, same fold_in
-# derivation end-to-end) and test_sharded_chained_matches_sharded_per_round
-# (the same make_chained scaffold through the sharded body); this variant
-# costs ~26s of duplicate compile
 def test_chained_matches_per_round_dispatch():
     cfg, model, params, norm, arrays = _setup()
     base_key = jax.random.PRNGKey(7)
@@ -68,9 +61,6 @@ def test_chained_matches_per_round_dispatch():
     assert stacked["sampled"].shape == (n, cfg.agents_per_round)
 
 
-@pytest.mark.slow  # knob variant of test_chained_matches_per_round_
-# dispatch (clip+noise only change the round body, not the chain
-# machinery); ~40s of CPU compile
 def test_chained_matches_per_round_with_clip_and_noise():
     """The r4 clip+noise sweep row runs chained: per-batch PGD projection
     and the server's Gaussian noise (k_noise split from the round key) must
@@ -109,11 +99,6 @@ def test_sharded_chained_matches_sharded_per_round():
     assert stacked["train_loss"].shape == (n,)
 
 
-@pytest.mark.slow  # tier-1 re-budget (ISSUE 10): the single-device host
-# chain is redundant coverage — test_sharded_host_chained_matches_per_round
-# runs the SAME make_chained_host scan composed with shard_map (the
-# superset program) and test_chained_matches_per_round_dispatch keeps the
-# vmap chain parity, both in tier-1
 def test_host_chained_matches_per_round_host():
     """Host-sampled chained blocks (fl/rounds.make_chained_round_fn_host)
     must match per-round host dispatch on the same shard payloads + keys."""
@@ -217,9 +202,6 @@ def test_dispatch_schedule_covers_rounds_in_order():
             start, total, snap, chain_n, diag, False))
 
 
-@pytest.mark.slow  # three driver runs (~30s); the host-chain fn-level
-# parity stays in tier-1 (test_host_chained_matches_per_round_host) and
-# the schedule logic is unit-tested (test_dispatch_schedule_*)
 def test_run_host_chain_matches_unchained(tmp_path):
     """Driver-level: host-sampled mode with --chain must produce the same
     curve as unchained host-sampled mode (same sampling sequence, same keys),

@@ -124,13 +124,7 @@ def _restored_params(cfg):
 import pytest  # noqa: E402
 
 
-# slow tier: 4 driver runs per variant (~165s on the 2-core CI box).
-# Mid-chain resume SCHEDULING is pinned cheaply by the dispatch_schedule
-# unit tests and save/restore roundtrips above; these two keep the full
-# end-to-end exactness check for capable hardware (-m slow)
-@pytest.mark.parametrize("host_sampled", [
-    pytest.param("auto", marks=pytest.mark.slow),
-    pytest.param("on", marks=pytest.mark.slow)])
+@pytest.mark.parametrize("host_sampled", ["auto", "on"])
 def test_resume_mid_chain_continues_exact_sequence(tmp_path, host_sampled):
     """--resume restoring at a round where rnd % chain != 0 (round 5 with
     chain=3) must continue the exact sampling/key sequence through the next

@@ -160,7 +160,7 @@ def test_flag_root_persistent_cache_smoke(tmp_path, own_cache_root):
     assert any(n.endswith("-cache") for n in os.listdir(xla_dir))
 
 
-def _plan_families(cfg, host_mode=None):
+def _plan(cfg, host_mode=None):
     from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
         get_federated_data)
     from defending_against_backdoors_with_robust_learning_rate_tpu.fl.common import (
@@ -171,8 +171,11 @@ def _plan_families(cfg, host_mode=None):
     fed = get_federated_data(cfg)
     model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
     norm = make_normalizer(fed.mean, fed.std, fed.raw_is_normalized)
-    return [s.family for s in cc.plan_programs(cfg, model, norm, fed,
-                                               host_mode=host_mode)]
+    return cc.plan_programs(cfg, model, norm, fed, host_mode=host_mode)
+
+
+def _plan_families(cfg, host_mode=None):
+    return [s.family for s in _plan(cfg, host_mode)]
 
 
 def test_plan_programs_families():
@@ -192,6 +195,35 @@ def test_plan_programs_families():
     assert _plan_families(TINY.replace(chain=2, dropout_rate=0.3),
                           host_mode=True) == [
         "round_host", "eval_val", "eval_poison"]
+
+
+def test_chained_families_donate_params():
+    """Donation-audit pin (contracts.DONATED_FAMILIES): every chained
+    family must donate its params argument — the lowered StableHLO
+    carries the input-output alias on arg 0, so no parameter copy rides a
+    dispatched block. The per-round families deliberately keep params
+    alive (diagnostics prev_params, parity callers, supervised retry) —
+    pinned un-aliased here so the asymmetry is a contract, not an
+    accident."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.analysis.contracts import (
+        DONATED_FAMILIES)
+    cfg = TINY.replace(bs=16, chain=2, robustLR_threshold=3)
+    seen = set()
+    for lcfg, host_mode in ((cfg, None), (cfg, True),
+                            (cfg.replace(agg_mode="buffered"), None)):
+        for spec in _plan(lcfg, host_mode):
+            if not spec.family.startswith(("round", "chained")):
+                continue
+            text = cc.lower_program(spec.jit_obj,
+                                    spec.example_args).as_text()
+            donated = "tf.aliasing_output" in text
+            if spec.family in DONATED_FAMILIES:
+                assert donated, f"{spec.family} must donate params"
+                seen.add(spec.family)
+            else:
+                assert not donated, \
+                    f"{spec.family} must NOT donate (prev_params/retry)"
+    assert seen == {"chained", "chained_host", "chained_async"}
 
 
 def test_precompile_then_train_loads(tmp_path, capsys, own_cache_root):

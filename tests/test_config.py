@@ -1,5 +1,7 @@
 """Config flag-surface parity with the reference CLI (src/options.py:4-74)."""
 
+import pytest
+
 from defending_against_backdoors_with_robust_learning_rate_tpu.config import (
     Config, args_parser)
 
@@ -50,3 +52,14 @@ def test_cli_parses_reference_command_line():
 def test_agents_per_round_floor():
     # floor(K * C), src/federated.py:68
     assert Config(num_agents=3383, agent_frac=0.01).agents_per_round == 33
+
+
+@pytest.mark.parametrize("flag", ["--use_pallas", "--agg_layout=bucket",
+                                  "--train_layout=megabatch"])
+def test_removed_flags_are_refused(flag, capsys):
+    """PR 29 took these three forks out with their flags: the parser
+    refuses them as it refuses any flag it does not know."""
+    with pytest.raises(SystemExit) as e:
+        args_parser([flag])
+    assert e.value.code == 2
+    assert "unrecognized arguments" in capsys.readouterr().err

@@ -113,12 +113,6 @@ def test_round_prefetcher_error_while_queue_full():
     pf.close()
 
 
-@pytest.mark.slow  # tier-1 budget (ISSUE 11): chunk-vs-full parity is
-# redundantly covered by the cheap twins
-# test_megabatch.py::test_trainer_parity_f32_with_pgd_and_chunk (both
-# layouts through the same _run_chunked scaffold at trainer level) and
-# the loud non-divisor refusal unit test; this driver-level run costs
-# ~18s of duplicate compile (its sharded variant was already gated)
 def test_driver_agent_chunk_parity():
     """--agent_chunk trades round latency for peak activation HBM; agents
     train independently, so chunked results must match the full vmap."""
@@ -131,8 +125,6 @@ def test_driver_agent_chunk_parity():
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow  # chunk semantics covered unsharded above; the
-# chunk+mesh combination costs ~30s of CPU compile
 def test_driver_agent_chunk_parity_sharded():
     """Chunking applies per-device on the mesh path (2 agents/device on the
     8-device mesh, chunk=1 -> 2 sequential chunks per device)."""
@@ -145,8 +137,6 @@ def test_driver_agent_chunk_parity_sharded():
                                atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.slow  # scale smoke; krum-on-mesh math is covered by
-# test_parallel + test_faults harnesses
 def test_driver_256_agent_krum_on_mesh():
     """BASELINE configs[4] shape scaled to CI: 256 agents (32/device on the
     faked 8-device mesh), 10% corrupt, krum aggregation via the
@@ -205,8 +195,6 @@ def test_driver_rng_impl_rbg():
         jax.config.update("jax_default_prng_impl", "threefry2x32")
 
 
-@pytest.mark.slow  # diag-rounds-stay-unchained is pinned by the
-# dispatch_schedule unit test; this drives it e2e (~20s)
 def test_driver_host_chain_with_diagnostics(monkeypatch, capsys):
     """diagnostics + host-sampled + --chain: the dispatch schedule must keep
     every snap round unchained (it needs prev_params + the diag-compiled

@@ -4,7 +4,6 @@ training learns, the backdoor succeeds without defense, and RLR collapses it."""
 import jax
 import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.config import Config
 from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
@@ -51,7 +50,6 @@ def test_clean_training_learns():
     assert val_acc > 0.6, f"val_acc={val_acc}"
 
 
-@pytest.mark.slow  # 2x 20-round trainings (~50s on the 2-core CI box)
 def test_backdoor_succeeds_without_defense_and_rlr_collapses_it():
     """2 of 8 corrupt, full poison: backdoor ~1.0 undefended; RLR at
     threshold 6 drives it to ~0 at a small clean-acc cost — the README's
@@ -69,8 +67,6 @@ def test_backdoor_succeeds_without_defense_and_rlr_collapses_it():
         f"RLR did not collapse backdoor: {poison_d} vs undefended {poison_a}")
 
 
-@pytest.mark.slow  # host-sampled e2e also covered by test_driver host
-# tests and test_faults.test_chaos_run_host_sampled_mode
 def test_host_sampled_mode_trains():
     """The host-sampled path (fedemnist: shard stacks too big for HBM; the
     driver gathers each round's sampled shards host-side) runs rounds with

@@ -70,8 +70,7 @@ def _leaves_equal(a, b):
 # `where`-selected stack; whether XLA:CPU emits the bit-same fused sum as
 # for the dense expression is its choice, and under jaxlib 0.9.0 it does
 # not: avg differs by 1 ulp of the leaf's largest magnitude (3e-8 abs).
-# Pinned at 2, the bound tests/test_bucket_parity.py already gives avg's
-# reduction order. The sign rule reduces integer-valued f32 partials,
+# Pinned at 2. The sign rule reduces integer-valued f32 partials,
 # which sum exactly in any order, and stays pinned bitwise.
 AVG_ULPS = 2
 

@@ -15,9 +15,9 @@ Acceptance drilled here:
   heartbeat values; console renders a 3-run fixture fleet; trajectory
   gate rc 0/1/2 on pass/regress/malformed.
 
-The true-SIGKILL ``kill_recover`` twin drill is ``-m slow`` (subprocess
-pair; the in-process rollback re-entry drills the identical machinery —
-the cheap-twin convention) and runs fully in CI ``obs-fleet-smoke``.
+The true-SIGKILL ``kill_recover`` twin drill is a subprocess pair (the
+in-process rollback re-entry drills the identical machinery) and also
+runs in CI ``obs-fleet-smoke``.
 """
 
 import json
@@ -507,9 +507,6 @@ def test_console_on_real_fleet(fleet):
     assert "done" in text
 
 
-@pytest.mark.slow  # true-SIGKILL subprocess pair (~60s warm); cheap twin
-# in tier-1: test_ladder_stream_typed_and_deterministic drills the
-# identical in-process rollback re-entry + ledger determinism
 def test_kill_recover_ledger_byte_identical_to_unkilled_twin(tmp_path):
     """THE ledger acceptance: a kill_recover@N drill's events.jsonl is
     byte-identical (modulo wall clocks) to its unkilled twin's — the

@@ -180,9 +180,7 @@ TOKENS = dict(data="tokens", arch="lfm2_moe", agent_chunk=1)
     (dict(TOKENS, cohort_sampled="on"), "cohort"),
     (dict(TOKENS, tenants=2), "--tenants"),
     (dict(TOKENS, agg_mode="buffered"), "buffer"),
-    (dict(TOKENS, use_pallas=True), "Pallas"),
     (dict(TOKENS, diagnostics=True), "--diagnostics"),
-    (dict(TOKENS, train_layout="megabatch"), "megabatch"),
     (dict(TOKENS, arch="resnet9"), "--arch=lfm2_moe"),
     (dict(data="cifar10", arch="lfm2_moe"), "--data=tokens"),
     (dict(agg_path="fold", aggr="comed"), "--aggr=comed"),

@@ -15,9 +15,8 @@ Three layers, mirroring the module split:
   fault escalates to QUARANTINE then HALT loudly; `record` keeps the
   metrics flowing through a NaN; a resume from mid-rollback on-disk
   state picks the LADDER up, not the failure (the cheap twin of the
-  slow-gated true-SIGKILL kill_recover drill — the PR-8/10/11 budget
-  pattern); the 8-way shard_map acceptance drill rides the slow gate
-  (the vmap twin pins the identical machinery in tier-1).
+  slow-gated true-SIGKILL kill_mid_rollback drill); the 8-way shard_map
+  acceptance drill runs the vmap drill's machinery over the mesh.
 
 Data-plane integrity (bank sha256 sidecars + the bank_corrupt chaos
 drill) closes the file.
@@ -364,7 +363,7 @@ def test_serve_refuses_recover_with_rlr_adapt(tmp_path):
 
 
 def test_serve_nan_recovers_via_rollback_byte_identical(tmp_path):
-    """THE ladder drill (vmap twin of the slow 8-way one): a seeded NaN
+    """THE ladder drill (vmap twin of the 8-way one): a seeded NaN
     burst DISCARDs, escalates to ROLLBACK (the restored prev_params were
     poisoned too), replays clean — rc 0, journaled phases, and a final
     stream byte-identical to the uninjected twin."""
@@ -500,8 +499,6 @@ def test_serve_rearms_journaled_quarantine_set(tmp_path):
     assert summary["service"]["health"]["quarantined"] == [5]
 
 
-@pytest.mark.slow  # sharded-family compile; the vmap twin above pins the
-# identical ladder machinery in tier-1 (ISSUE-14 acceptance drill)
 def test_serve_nan_recovers_on_8way_shard_map(tmp_path):
     base = dict(service_rounds=6, mesh=8)
     cfg_a = _cfg(tmp_path, "a", **base)

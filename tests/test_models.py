@@ -12,7 +12,6 @@ Reference CNN_CIFAR (src/models.py:33-58):
 
 import jax
 import jax.numpy as jnp
-import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
     get_model, init_params, param_count)
@@ -86,7 +85,6 @@ def test_resnet9_is_the_north_star_default_for_cifar():
     assert type(get_model("fmnist", "auto")).__name__ == "CNN_MNIST"
 
 
-@pytest.mark.slow  # ResNet-9 fwd+bwd compiled twice (~25s on CI CPU)
 def test_resnet9_remat_matches_unremated():
     """Blockwise rematerialization (HBM lever for the 40-agent cifar
     configs) is exact: same param tree, same loss, same grads."""
@@ -110,11 +108,6 @@ def test_resnet9_remat_matches_unremated():
         assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.slow  # tier-1 re-budget (ISSUE 10): same ResNet-9
-# fwd+bwd-compiled-twice shape as test_resnet9_remat_matches_unremated
-# (slow-gated since PR 5) — remat exactness is jax-level behavior both
-# variants pin identically; tier-1 keeps the ResNet-9 construction +
-# registry coverage
 def test_resnet9_selective_remat_matches_block():
     """The selective policy (save conv/MXU outputs, recompute only the
     elementwise tail — VERDICT r4 next #4) is exact like blockwise remat:
@@ -139,3 +132,27 @@ def test_resnet9_selective_remat_matches_block():
     for a, b in zip(jax.tree_util.tree_leaves(g1),
                     jax.tree_util.tree_leaves(g2), strict=True):
         assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def test_flops_per_example_analytic():
+    """The registry's analytic FLOP model (bench.py's compile-free MFU
+    source): positive, monotone in image size, and within 2x of XLA's
+    own cost analysis of the compiled fwd+bwd step (the 3x-forward
+    convention vs the compiler's exact count)."""
+    from bench import bench_config, train_step_flops
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.common import (
+        make_normalizer)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
+        flops_per_example)
+    f28 = flops_per_example("fmnist", "cnn", (28, 28, 1))
+    f8 = flops_per_example("synthetic", "cnn", (8, 8, 1))
+    assert f28 and f8 and f28 > f8 > 0
+    assert flops_per_example("cifar10", "cnn", (32, 32, 3)) > f28
+    assert flops_per_example("cifar10", "resnet9", (32, 32, 3)) is None
+    cfg = bench_config("fmnist").replace(bs=16)
+    model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
+    params = init_params(model, (28, 28, 1), jax.random.PRNGKey(0))
+    norm = make_normalizer(0.5, 0.5, False)
+    xla_step = train_step_flops(model, params, norm, cfg, (28, 28, 1))
+    analytic_step = 3.0 * f28 * cfg.bs
+    assert 0.5 < analytic_step / xla_step < 2.0, (analytic_step, xla_step)

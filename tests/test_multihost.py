@@ -80,33 +80,6 @@ print("SUMMARY" + str(pid) + "=" + json.dumps(
 """
 
 
-BUCKET_DRIVER = r"""
-import json, sys
-import jax
-jax.config.update("jax_platforms", "cpu")
-coordinator, n_proc, pid = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
-from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-    multihost)
-multihost.maybe_initialize(coordinator, n_proc, pid)
-from defending_against_backdoors_with_robust_learning_rate_tpu import train
-from defending_against_backdoors_with_robust_learning_rate_tpu.config import Config
-from defending_against_backdoors_with_robust_learning_rate_tpu.utils.metrics import (
-    NullWriter)
-# ISSUE 8: the two-process global mesh adopts the BUCKETED aggregation
-# program — per-bucket reduce-scatter + one all-gather of the LR-scaled
-# result over the 8-device (2-process) mesh, the pod collective shape
-cfg = Config(data="synthetic", num_agents=8, bs=16, local_ep=1,
-             synth_train_size=256, synth_val_size=64, eval_bs=64,
-             rounds=2, snap=2, seed=5, mesh=0, chain=2,
-             num_corrupt=1, poison_frac=1.0, robustLR_threshold=3,
-             agg_layout="bucket", tensorboard=False)
-summary = train.run(cfg, writer=NullWriter())
-print("SUMMARY" + str(pid) + "=" + json.dumps(
-    {k: v for k, v in summary.items() if isinstance(v, (int, float))}),
-    flush=True)
-"""
-
-
 def _free_port():
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -164,50 +137,6 @@ def test_two_process_host_sampled_trains():
     np.testing.assert_allclose(summaries[0]["val_loss"],
                                summaries[1]["val_loss"], atol=1e-5)
     assert 0.0 <= summaries[0]["val_acc"] <= 1.0
-
-
-@pytest.mark.slow  # same CPU-backend gate as above
-def test_two_process_bucketed_aggregation_trains():
-    """ISSUE-8 multihost adoption drill: the two-process global `agents`
-    mesh runs the BUCKETED reduce-scatter aggregation program — the
-    collective shape a real pod would use — and both processes compute
-    the identical replicated result. (The single-process bucket path is
-    parity-pinned in tier-1 by tests/test_bucket_parity.py; this drill
-    needs cross-process collectives, which XLA:CPU cannot run.)"""
-    port = _free_port()
-    coord = f"127.0.0.1:{port}"
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
-    env.pop("JAX_PLATFORMS", None)
-    procs = [subprocess.Popen(
-        [sys.executable, "-c", BUCKET_DRIVER, coord, "2", str(pid)],
-        stdout=subprocess.PIPE, stderr=subprocess.PIPE, text=True, env=env,
-        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-        for pid in (0, 1)]
-    outs = []
-    try:
-        for p in procs:
-            out, err = p.communicate(timeout=420)
-            outs.append((p.returncode, out, err))
-    except subprocess.TimeoutExpired:
-        for p in procs:
-            p.kill()
-        pytest.fail("two-process bucketed run timed out")
-
-    summaries = {}
-    for pid, (rc, out, err) in enumerate(outs):
-        assert rc == 0, f"rc={rc}\nstdout:\n{out}\nstderr:\n{err[-3000:]}"
-        # the driver announced the bucketed plan next to the topology
-        assert "[agg] bucketed aggregation" in out, out
-        for line in out.splitlines():
-            if line.startswith(f"SUMMARY{pid}="):
-                summaries[pid] = json.loads(line.split("=", 1)[1])
-    assert set(summaries) == {0, 1}, summaries
-    assert summaries[0]["round"] == summaries[1]["round"] == 2
-    np.testing.assert_allclose(summaries[0]["val_acc"],
-                               summaries[1]["val_acc"], atol=1e-6)
-    np.testing.assert_allclose(summaries[0]["val_loss"],
-                               summaries[1]["val_loss"], atol=1e-5)
 
 
 @pytest.mark.slow  # same CPU-backend gate as above

@@ -618,11 +618,6 @@ def test_driver_telemetry_sync_async_defense_parity(tmp_path):
     assert ra == rs and len(ra) >= 2 * 4  # >=4 Defense rows per boundary
 
 
-@pytest.mark.slow  # two full driver runs (~56s): the heaviest tier-1
-# test, slow-gated (ISSUE 8 budget). Cheap twins in tier-1:
-# test_driver_smoke_full_observability exercises the driver+obs e2e and
-# tests/test_attribution.py unit-covers the capture-window parsing + the
-# XLA:CPU no-device-track degradation.
 def test_driver_profile_rounds_window_report_and_off_bit_identity(
         tmp_path, monkeypatch):
     """ISSUE-5 acceptance, driver side: --profile_rounds 2 samples a

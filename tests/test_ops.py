@@ -210,6 +210,36 @@ def test_apply_aggregate_with_lr_tree():
     np.testing.assert_allclose(out2["w0"], [2.0, 4.0, 6.0])
 
 
+@pytest.mark.parametrize("aggr,m,n,thr", [
+    ("avg", 4, 300, 3.0), ("avg", 10, 5000, 4.0), ("avg", 7, 1111, 0.0),
+    ("sign", 6, 2222, 0.0), ("sign", 6, 2222, 3.0)])
+def test_server_step_matches_numpy_reference(aggr, m, n, thr):
+    """The three calls the round makes under `aggregate_rlr` (the vote,
+    the rule, the apply) on a random [m, n] stack, against a NumPy
+    transcription of compute_robustLR + FedAvg / signSGD majority
+    (src/aggregation.py:48-75). Threshold 0 is the undefended step: a
+    scalar server lr, as fl/rounds.py passes it."""
+    rng = np.random.default_rng(0 if aggr == "avg" else 2)
+    u = rng.normal(size=(m, n)).astype(np.float32)
+    w = rng.uniform(1, 5, size=(m,)).astype(np.float32)
+    p = rng.normal(size=(n,)).astype(np.float32)
+    slr = 1.0 if aggr == "avg" else 0.05
+
+    updates = {"w": jnp.asarray(u)}
+    lr = robust_lr(updates, thr, slr) if thr > 0 else slr
+    agg = (agg_avg(updates, jnp.asarray(w)) if aggr == "avg"
+           else agg_sign(updates))
+    got = np.asarray(apply_aggregate({"w": jnp.asarray(p)}, lr, agg)["w"])
+
+    ssum = np.sign(u).sum(0)
+    want_agg = ((u * (w / w.sum())[:, None]).sum(0) if aggr == "avg"
+                else np.sign(ssum))
+    want_lr = np.where(np.abs(ssum) >= thr, slr, -slr) if thr > 0 else slr
+    tol = 1e-5 if aggr == "avg" else 1e-6
+    np.testing.assert_allclose(got, p + want_lr * want_agg, atol=tol,
+                               rtol=tol)
+
+
 def test_noise_added_when_enabled():
     cfg = Config(aggr="avg", noise=1.0, clip=0.5)
     u = {"w": jnp.zeros((4, 100))}

@@ -28,12 +28,12 @@ def test_pick_agent_mesh_size():
     assert pick_agent_mesh_size(1, 7, n_devices=8) == 1
 
 
-def _setup(aggr, num_corrupt=1):
+def _setup(aggr, num_corrupt=1, **kw):
     cfg = Config(data="synthetic", num_agents=8, bs=16, local_ep=1,
                  synth_train_size=256, synth_val_size=64, aggr=aggr,
                  num_corrupt=num_corrupt, poison_frac=1.0,
                  robustLR_threshold=3 if aggr in ("avg", "sign") else 0,
-                 seed=11)
+                 seed=11, **kw)
     fed = get_federated_data(cfg)
     model = get_model(cfg.data, cfg.model_arch, cfg.dtype)
     params = init_params(model, cfg.image_shape, jax.random.PRNGKey(0))
@@ -43,19 +43,11 @@ def _setup(aggr, num_corrupt=1):
     return cfg, model, params, norm, arrays
 
 
-# slow-tier split (tier-1 budget, ISSUE 1 + ISSUE 8): each collective
-# PATTERN keeps one tier-1 representative, its structural twins ride the
-# slow tier — sign (psum of sign-sums = avg's RLR vote psum pattern),
-# trmean (same all_to_all transpose + local sort as comed), and rfa
-# (per-iteration weighted psums = avg's pattern iterated). Value-level
-# semantics of every rule stay tier-1-covered in tests/test_ops.py.
-@pytest.mark.parametrize("aggr", [
-    "avg", "comed", pytest.param("sign", marks=pytest.mark.slow),
-    pytest.param("trmean", marks=pytest.mark.slow),
-    "krum", pytest.param("rfa", marks=pytest.mark.slow)])
-def test_sharded_round_matches_vmap_round(aggr):
+def _sharded_and_vmap_round(cfg, model, params, norm, arrays):
+    """One round of the vmap program and of the 8-way shard_map program
+    from the same parameters and key: ((params, info), (params, info)),
+    after the comparisons every case shares."""
     assert len(jax.devices()) == 8, "conftest must fake 8 CPU devices"
-    cfg, model, params, norm, arrays = _setup(aggr)
     key = jax.random.PRNGKey(42)
 
     single = make_round_fn(cfg, model, norm, *arrays)
@@ -73,6 +65,53 @@ def test_sharded_round_matches_vmap_round(aggr):
                                    atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(float(info1["train_loss"]),
                                float(info2["train_loss"]), rtol=1e-4)
+    return info1, info2
+
+
+# one case a collective pattern: psums of weighted sums and of sign sums
+# (avg, sign), the all_to_all transpose with a local sort or distance
+# matrix (comed, trmean, krum), per-iteration weighted psums (rfa).
+# Value-level semantics of every rule are in tests/test_ops.py.
+@pytest.mark.parametrize("aggr", ["avg", "comed", "sign", "trmean", "krum",
+                                  "rfa"])
+def test_sharded_round_matches_vmap_round(aggr):
+    _sharded_and_vmap_round(*_setup(aggr))
+
+
+LEAF_VARIANTS = {
+    "avg_rlr": dict(aggr="avg"),
+    "sign_rlr": dict(aggr="sign", server_lr=0.5),
+    "avg_rlr_tel_full": dict(aggr="avg", telemetry="full"),
+    "avg_rlr_faults": dict(aggr="avg", dropout_rate=0.3,
+                           payload_norm_cap=100.0,
+                           faults_spare_corrupt=True),
+}
+
+# series that are integer counts over coordinates or clients: the sum
+# over devices is exact, so the per-leaf psum plan reads what vmap reads
+_EXACT_SERIES = ("tel_flip_frac", "tel_margin_hist")
+
+
+@pytest.mark.parametrize("name", sorted(LEAF_VARIANTS))
+def test_leaf_round_matches_vmap_round(name):
+    """The per-leaf psum plan against the single-device vmap round with
+    the RLR vote on, two corrupt clients, and the lanes that ride the
+    plan (full telemetry, the faults mask): parameters and loss as
+    above, and every `tel_*` / `fault_*` series."""
+    info1, info2 = _sharded_and_vmap_round(
+        *_setup(num_corrupt=2, **LEAF_VARIANTS[name]))
+    series = sorted(k for k in info1
+                    if k.startswith(("tel_", "fault_")))
+    assert series == sorted(k for k in info2
+                            if k.startswith(("tel_", "fault_")))
+    assert series or name in ("avg_rlr", "sign_rlr")
+    for k in series:
+        a, b = np.asarray(info1[k]), np.asarray(info2[k])
+        if k in _EXACT_SERIES or k.startswith("fault_"):
+            np.testing.assert_array_equal(a, b, err_msg=k)
+        else:
+            np.testing.assert_allclose(a, b, atol=1e-6, rtol=1e-5,
+                                       err_msg=k)
 
 
 def test_param_shard_transpose_roundtrip():
@@ -125,10 +164,6 @@ def test_multihost_helpers_single_process_degrade():
     assert np.isfinite(float(info["train_loss"]))
 
 
-@pytest.mark.slow  # ~30s; slow-gated (ISSUE 8 budget). Cheap twins in
-# tier-1: the single-round sharded parity above plus
-# test_chain.test_sharded_chained_matches_sharded_per_round (multi-round
-# sharded execution inside one scan).
 def test_sharded_multiround_trains():
     cfg, model, params, norm, arrays = _setup("avg", num_corrupt=0)
     mesh = make_mesh(4)
@@ -179,8 +214,6 @@ def test_sharded_host_round_matches_single_device_host():
                                float(info2["train_loss"]), rtol=1e-4)
 
 
-@pytest.mark.slow  # duplicate of test_guards.test_guard_composes_with
-# _sharded_round (same checkify-over-collectives property)
 def test_guarded_sharded_round_runs():
     """--debug_nan over the shard_mapped path (ADVICE r1): checkify must
     accept the psum/all_to_all/all_gather collectives at trace time and the

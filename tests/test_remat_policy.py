@@ -328,7 +328,7 @@ def test_precompile_manifest_keys_the_resolved_policy(limit, tmp_path,
             lambda name, **kw: base(name, remat_policy=policy, **kw))
         monkeypatch.setattr(sys, "argv", [
             "precompile.py", "--print_manifest", "--configs", "resnet9",
-            "--train_layouts", "vmap", "--synth_train_size", "512",
+            "--synth_train_size", "512",
             "--cache_dir", str(tmp_path)])
         assert precompile.main() == 0
         monkeypatch.setattr(bench, "bench_config", base)

@@ -3,12 +3,10 @@
 Three layers, mirroring the module split:
 
 - lane math: the in-jit rep_agree/rep_norm reductions against numpy
-  host oracles (sign ties, MASKED sentinel slots, the bucketed flat
-  variant against the tree variant on an odd-size padded layout), and
-  full round-program parity vmap vs sharded-leaf vs bucket on the faked
-  8-device mesh — the agreement lane is integer-count arithmetic so
-  parity is bitwise, the norm lane crosses a summation-order change so
-  it gets the layout tolerance.
+  host oracles (sign ties, MASKED sentinel slots), and full
+  round-program parity vmap vs sharded on the faked 8-device mesh — the
+  agreement lane is integer-count arithmetic so parity is bitwise, the
+  norm lane gets the cross-path tolerance.
 - tracker: the two-signal suspicion fold against hand-computed
   EMA/streak oracles (a boosted client scores on the norm term with
   PERFECT agreement, a sign-flipper on the agreement term), the
@@ -45,8 +43,6 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry i
     get_model, init_params)
 from defending_against_backdoors_with_robust_learning_rate_tpu.obs import (
     events as obs_events, reputation as rep)
-from defending_against_backdoors_with_robust_learning_rate_tpu.parallel import (
-    buckets)
 from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.mesh import (
     make_mesh)
 from defending_against_backdoors_with_robust_learning_rate_tpu.parallel.rounds import (
@@ -147,31 +143,11 @@ def test_lane_rows_match_host_oracle():
     assert float(got_am[2]) == float(got_nm[2]) == rep.MASKED
 
 
-def test_flat_variant_matches_tree():
-    """The bucketed layout's agree_rows_flat / norm_rows-on-flat equal
-    the tree variants: padding coordinates are explicit zeros, excluded
-    from agreement by the real mask and free in the norm."""
-    upd = _stacked()
-    sums = rep.sign_sums_from(upd)
-    layout = buckets.layout_for_leaves(
-        {k: v[0] for k, v in upd.items()}, d=8, bucket_bytes=64)
-    assert layout.padded > layout.total   # padding actually in play
-    flat = buckets.flatten_stacked(layout, upd)
-    flat_sign = buckets.flatten_tree(layout, sums)
-    real = jnp.arange(layout.padded) < layout.total
-    got = rep.agree_rows_flat(flat, flat_sign, real, layout.total)
-    want = rep.agree_rows(upd, sums)
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    np.testing.assert_allclose(np.asarray(rep.norm_rows(flat)),
-                               np.asarray(rep.norm_rows(upd)), rtol=1e-6)
-
-
-def test_round_program_lane_parity_vmap_leaf_bucket():
-    """One full round on the faked 8-device mesh: the vmap, sharded-leaf
-    and bucketed programs emit the SAME [m] rep rows. Agreement counts
-    integer-valued f32 partials (bitwise across layouts); the norm
-    crosses a per-leaf vs flat summation-order change (layout
-    tolerance)."""
+def test_round_program_lane_parity_vmap_leaf():
+    """One full round on the faked 8-device mesh: the vmap and the
+    sharded programs emit the SAME [m] rep rows. Agreement counts
+    integer-valued f32 partials (bitwise across the two); the norm gets
+    the cross-path tolerance."""
     assert len(jax.devices()) == 8, "conftest must fake 8 CPU devices"
     cfg = Config(data="synthetic", num_agents=8, bs=16, local_ep=1,
                  synth_train_size=256, synth_val_size=64,
@@ -191,19 +167,14 @@ def test_round_program_lane_parity_vmap_leaf_bucket():
     _, i0 = make_round_fn(cfg, model, norm, *arrays)(params, key)
     _, i1 = make_sharded_round_fn(cfg, model, norm, mesh, *arrays)(
         params, key)
-    _, i2 = make_sharded_round_fn(cfg.replace(agg_layout="bucket"),
-                                  model, norm, mesh, *arrays)(params, key)
-    for info in (i0, i1, i2):
+    for info in (i0, i1):
         assert np.asarray(info["rep_agree"]).shape == (8,)
         assert np.asarray(info["rep_norm"]).shape == (8,)
     np.testing.assert_array_equal(np.asarray(i0["rep_agree"]),
                                   np.asarray(i1["rep_agree"]))
-    np.testing.assert_array_equal(np.asarray(i1["rep_agree"]),
-                                  np.asarray(i2["rep_agree"]))
-    for a, b in ((i0, i1), (i1, i2)):
-        np.testing.assert_allclose(np.asarray(a["rep_norm"]),
-                                   np.asarray(b["rep_norm"]),
-                                   atol=1e-5, rtol=1e-5)
+    np.testing.assert_allclose(np.asarray(i0["rep_norm"]),
+                               np.asarray(i1["rep_norm"]),
+                               atol=1e-5, rtol=1e-5)
     # every agreement is a real fraction, nothing masked in a full draw
     agrees = np.asarray(i0["rep_agree"])
     assert ((agrees >= 0.0) & (agrees <= 1.0)).all()
@@ -400,11 +371,6 @@ def _lines(cfg):
                        for p in NON_TIMING_PREFIXES)]
 
 
-@pytest.mark.slow  # ~65s of serve() fixtures (tier-1 budget gating);
-# the fast tier keeps serve+reputation coverage via test_service.py's
-# crash-exact drill (robustLR_threshold=3 -> lanes on, rows byte-compared)
-# and the lane/tracker drills above; CI's defense-obs-smoke job pins the
-# AUC / off-twin / event surfaces at the CLI level on every push.
 @pytest.mark.parametrize("attack", ["boost", "signflip"])
 def test_serve_suspicion_auc(attack_runs, attack):
     """THE acceptance drill: the ranking — which never reads a corrupt
@@ -419,7 +385,6 @@ def test_serve_suspicion_auc(attack_runs, attack):
     assert susp["suspect_count"] >= 1            # streaks actually fired
 
 
-@pytest.mark.slow  # shares the serve() fixtures above
 def test_serve_reputation_rows_and_events(attack_runs):
     cfg, _ = attack_runs["boost"]
     tags = {json.loads(l)["tag"] for l in _lines(cfg)}
@@ -440,7 +405,6 @@ def test_serve_reputation_rows_and_events(attack_runs):
     assert entries and all("reputation" in e for e in entries)
 
 
-@pytest.mark.slow  # shares the serve() fixtures above
 def test_serve_reputation_off_twin(attack_runs):
     """--reputation off: the SAME stream minus the Reputation/* rows
     (bit-identical training), no suspicion summary, no journal key."""

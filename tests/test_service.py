@@ -7,7 +7,7 @@ metrics.jsonl byte-identical (modulo wall-clock rows) to an uninterrupted
 run's, on both the vmap and the 8-device shard_map paths. Tier-1 drives
 the interruption in-process (abandon mid-round after un-journaled rows —
 exactly the on-disk state a kill -9 leaves); the true SIGKILL drill runs
-as a slow subprocess test and in the CI service-mode smoke job.
+as a subprocess test and in the CI service-mode smoke job.
 """
 
 import itertools
@@ -583,10 +583,6 @@ def test_resume_reenters_aot_bank(tmp_path):
     assert aot and all(e == "aot/hit" for e in aot), aot
 
 
-@pytest.mark.slow  # ~30s; slow-gated (ISSUE 8 budget). Cheap twin in
-# tier-1: test_serve_crash_exact_resume_vmap drills the identical
-# recovery protocol; the sharded round body itself is parity-pinned by
-# test_parallel + test_bucket_parity.
 def test_serve_crash_exact_resume_sharded(tmp_path):
     """The same drill over the 8-device shard_map path (faked CPU mesh):
     churn + masked collectives + crash recovery compose."""
@@ -715,8 +711,6 @@ def test_prepare_resume_preserves_prior_runs_rows(tmp_path):
 # --- the true kill -9 drill (subprocess; CI runs it in the service job) --
 
 
-@pytest.mark.slow  # two cold subprocess interpreters; the in-process
-# drills above pin the same truncate+replay machinery in tier-1
 def test_service_kill9_subprocess_drill(tmp_path):
     pkg = "defending_against_backdoors_with_robust_learning_rate_tpu"
     args = [sys.executable, "-m", f"{pkg}.service.driver",
@@ -773,8 +767,8 @@ def test_serve_buffered_midbuffer_recovery(tmp_path):
     commits land on even ticks, checkpoints on odd) resumes to
     byte-identical non-timing rows — the buffer + staleness counters
     round-trip through the digest-verified checkpoint exactly like
-    params (the true-SIGKILL twin rides the slow-gated subprocess drill
-    via --chaos kill_midbuf)."""
+    params (the true-SIGKILL twin is the subprocess drill via --chaos
+    kill_midbuf)."""
     base = dict(agg_mode="buffered", async_buffer_k=16,
                 straggler_rate=0.4, snap=3, service_rounds=9,
                 churn_available=1.0)
@@ -798,9 +792,6 @@ def test_serve_buffered_midbuffer_recovery(tmp_path):
     assert rows[("Async/Buffer_Fill", 3)] > 0   # the kill WAS mid-buffer
 
 
-@pytest.mark.slow  # two cold subprocess interpreters; the in-process
-# twin (test_serve_buffered_midbuffer_recovery) drills the identical
-# recovery protocol in tier-1
 def test_service_kill_midbuf_subprocess_drill(tmp_path):
     """True SIGKILL mid-buffer (--chaos kill_midbuf@4 on a buffered
     service): the killed life dies with uncommitted arrivals in the

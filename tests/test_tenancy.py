@@ -2,8 +2,7 @@
 service/tenancy.py): E experiments folded into one resident *_mt
 program must be a pure EXECUTION-layout change.
 
-Parity tiers, by what the arithmetic guarantees (the megabatch
-precedent):
+Parity tiers, by what the arithmetic guarantees:
 
 - the tenant programs run the SAME ops with the same keys as the solo
   paths, so per-tenant metrics are ulp-close to solo runs (measured
@@ -141,7 +140,7 @@ def test_e1_bit_identity_with_untenanted_path(tmp_path):
 def test_sign_rule_bitwise_and_slot_isolation():
     """Program-level pin: the sign+RLR tenant program's slot-0 params
     equal the solo round's params BITWISE (integer sign-vote arithmetic
-    reduces exactly in any order — the megabatch precedent), and a
+    reduces exactly in any order), and a
     different server_lr in slot 1 leaves slot 0 untouched (knob
     isolation across the tenant axis)."""
     solo_cfg = _cfg(aggr="sign", server_lr=0.5, robustLR_threshold=3,
@@ -248,10 +247,10 @@ def test_fingerprint_splits_on_tenant_count_not_knobs():
     # built at all) legitimately splits the program
     assert compile_cache.fingerprint(
         base.replace(robustLR_threshold=0), "round_mt", ex) != fp2
-    # family naming: tenancy suffixes compose after megabatch
+    # family naming: the tenancy suffix composes after the buffered one
     assert compile_cache.family_suffix(base) == "_mt"
     assert compile_cache.family_suffix(
-        base.replace(train_layout="megabatch")) == "_mb_mt"
+        base.replace(agg_mode="buffered")) == "_async_mt"
     assert compile_cache.family_suffix(base.replace(tenants=0)) == ""
 
 
@@ -290,7 +289,6 @@ def test_refusals():
     assert ftenancy.ineligible_reason(_cfg()) == ""
     assert "diagnostics" in ftenancy.ineligible_reason(
         _cfg(diagnostics=True))
-    assert "pallas" in ftenancy.ineligible_reason(_cfg(use_pallas=True))
     # buffered and cohort packs became ELIGIBLE in ISSUE 16 (the stacked
     # (params, state) carry / the shared bank gather)
     assert ftenancy.ineligible_reason(_cfg(agg_mode="buffered")) == ""
