@@ -87,9 +87,7 @@ def bench_config(name: str, remat_policy: str = "block",
         return Config(data="cifar10", num_agents=40, local_ep=2, bs=256,
                       num_corrupt=4, poison_frac=0.5, pattern_type="plus",
                       robustLR_threshold=8, arch="resnet9",
-                      remat=(remat_policy != "none"),
-                      remat_policy=("block" if remat_policy == "none"
-                                    else remat_policy),
+                      remat=True, remat_policy=remat_policy,
                       agent_chunk=(10 if agent_chunk < 0 else agent_chunk),
                       synth_train_size=50000,
                       synth_val_size=10000, seed=0, **extra)
@@ -240,8 +238,9 @@ def main():
                     default="block",
                     help="resnet9 config only: block = full blockwise "
                          "remat (r4 baseline, +33%% fwd recompute), conv = "
-                         "selective save-conv-outputs remat, none = no "
-                         "remat at all (viable at bf16 with agent_chunk)")
+                         "selective save-conv-outputs remat, none = "
+                         "nothing recomputed (the program --remat left "
+                         "out builds)")
     ap.add_argument("--agent_chunk", type=int, default=-1,
                     help="resnet9 config only: override the agent chunk "
                          "size (-1 keeps the config default of 10; 0 = "

@@ -92,14 +92,17 @@ class Config:
                                     # agent count (else full vmap)
     remat: bool = False             # rematerialization of the model's
                                     # forward (ResNet-9): backward
-                                    # recomputes activations instead of
+                                    # recomputes the activations that do
+                                    # not fit the device instead of
                                     # stashing them (exact, saves HBM)
     remat_policy: str = "auto"      # what backward recomputes under remat.
                                     # block: everything in a block; conv:
                                     # only the elementwise tail, the conv
-                                    # (MXU) outputs are kept; auto: conv
-                                    # when their bytes fit the device, else
-                                    # block (compile_cache.resolved_remat)
+                                    # (MXU) outputs are kept; none:
+                                    # nothing; auto: the least of the
+                                    # three that fits the device, block
+                                    # where it reports no limit
+                                    # (compile_cache.resolved_remat)
     # --- fault injection & elastic participation (faults/) ---
     dropout_rate: float = 0.0       # per-round Bernoulli client dropout
     straggler_rate: float = 0.0     # per-round straggler probability
@@ -590,8 +593,8 @@ FIELD_PROVENANCE = {
     "remat": "program",
     "remat_policy": "program",    # the fingerprint keys the RESOLVED
                                   # policy (compile_cache.resolved_remat:
-                                  # `auto` is block or conv by the
-                                  # device's memory limit)
+                                  # `auto` is block, conv or none by
+                                  # the device's memory limit)
     "dropout_rate": "program",    # faults path is traced
     "straggler_rate": "program",
     "straggler_epochs": "program",
@@ -823,17 +826,22 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "per-device agent count)")
     p.add_argument("--remat", action="store_true",
                    help="rematerialization of the model forward "
-                        "(ResNet-9): recompute activations in backward "
-                        "instead of stashing them — exact, saves HBM")
+                        "(ResNet-9): backward recomputes the activations "
+                        "that do not fit the device instead of stashing "
+                        "them — exact, saves HBM; how much is recomputed "
+                        "is --remat_policy's, and nothing is where all of "
+                        "it fits")
     p.add_argument("--remat_policy", type=str, default=d.remat_policy,
-                   choices=("auto", "block", "conv"),
+                   choices=("auto", "block", "conv", "none"),
                    help="what backward recomputes under --remat: block = "
                         "everything in a block; conv = only the "
                         "elementwise tail (GroupNorm, relu, pool), the "
-                        "conv (MXU) outputs are kept; auto = conv when "
-                        "the kept bytes (examples in flight on a device x "
-                        "the model's conv outputs) fit a third of what "
-                        "the device has free, else block; block on a "
+                        "conv (MXU) outputs are kept; none = nothing "
+                        "(the program --remat left out builds); auto = "
+                        "by the conv outputs' bytes (examples in flight "
+                        "on a device x the model's conv outputs) against "
+                        "what the device has free: none where 3.8x fit, "
+                        "conv where 3x fit, else block; block on a "
                         "backend that reports no memory limit (the CPU)")
     p.add_argument("--dropout_rate", type=float, default=d.dropout_rate,
                    help="per-round Bernoulli client dropout probability "

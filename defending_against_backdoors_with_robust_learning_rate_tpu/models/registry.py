@@ -15,7 +15,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.utils.jaxprs impo
     iter_eqns)
 
 _DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16}
-REMAT_POLICIES = ("block", "conv")
+REMAT_POLICIES = ("block", "conv", "none")
 
 
 def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
@@ -25,7 +25,8 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     arch='resnet9' selects the BASELINE north-star ResNet-9 extension.
     `remat` enables rematerialization (ResNet-9 only; the small CNNs'
     activations never pressure HBM); `remat_policy` picks full blockwise
-    ("block") or selective save-conv-outputs ("conv") recompute. It takes
+    ("block") or selective save-conv-outputs ("conv") recompute, or none
+    of either ("none": the model `remat=False` builds). It takes
     the RESOLVED policy: `--remat_policy auto` is a rule over the device's
     memory (utils/compile_cache.resolved_remat), not a model property.
     An arch in `TOKEN_ARCHS` is a token task's model: it reads its widths

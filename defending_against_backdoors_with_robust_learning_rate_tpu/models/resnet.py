@@ -59,10 +59,12 @@ class ResNet9(nn.Module):
     n_classes: int = 10
     dtype: Any = jnp.float32
     # rematerialization (jax.checkpoint via nn.remat): backward recomputes
-    # each block's activations instead of stashing them — the standard TPU
-    # trade of FLOPs for HBM. Exact (bitwise-equal grads); needed when many
-    # agents' ResNet batches are vmapped on one chip (40 agents x bs 256
-    # stashes ~19 GB un-remated, > v5e's 16 GB HBM).
+    # what does not fit the device instead of stashing it — the standard
+    # TPU trade of FLOPs for HBM. Exact (bitwise-equal grads); needed when
+    # many agents' ResNet batches are vmapped on one chip: all 40 agents x
+    # bs 256 at once stash ~19 GB un-remated, > v5e's 16 GB HBM. The
+    # benchmark's shape, ten agents at once, stashes a quarter of that
+    # and fits with nothing recomputed (PERF.md section 6, PR 30).
     remat: bool = False
     # remat_policy (active only when remat=True):
     #   "block" — save block inputs only, recompute EVERYTHING in backward:
@@ -72,21 +74,23 @@ class ResNet9(nn.Module):
     #             recompute only the cheap elementwise tail (GN, relu,
     #             pool): none of the conv recompute, for `conv_out` bytes
     #             per example in flight
-    # The user's `--remat_policy auto` is resolved to one of the two from
-    # the device's memory before a model is built
+    #   "none"  — recompute nothing: the plain modules, the program
+    #             remat=False builds
+    # The user's `--remat_policy auto` is resolved to one of the three
+    # from the device's memory before a model is built
     # (utils/compile_cache.resolved_remat); this module takes the result.
     remat_policy: str = "block"
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
-        if self.remat and self.remat_policy == "conv":
+        if not self.remat or self.remat_policy == "none":
+            Conv, Res = ConvGN, Residual
+        elif self.remat_policy == "conv":
             pol = jax.checkpoint_policies.save_only_these_names("conv_out")
             Conv = nn.remat(ConvGN, policy=pol)
             Res = nn.remat(Residual, policy=pol)
-        elif self.remat:
-            Conv, Res = nn.remat(ConvGN), nn.remat(Residual)
         else:
-            Conv, Res = ConvGN, Residual
+            Conv, Res = nn.remat(ConvGN), nn.remat(Residual)
         # explicit names: nn.remat prefixes auto-generated module names
         # ("CheckpointConvGN_0"), which would fork the param tree between
         # remat on/off — same tree means checkpoints interchange freely

@@ -248,13 +248,47 @@ def _arg_shapes(example_args) -> List[Tuple[str, str]]:
 # `block` (13.42 GB, 0.2365 rounds/s) where `conv` forced by hand still
 # fit, 1.5 GB under the limit, and ran 0.2566.
 REMAT_CONV_SHARE_DIVISOR = 3
+# The rung above it (ISSUE 30): nothing is recomputed where this multiple
+# of the same bytes fits what is free. On the chip (PERF.md section 6,
+# PR 30; the benchmark's shape, f32, ten agents of 256 at once, 3.86 GB of
+# tagged outputs) the round with nothing recomputed reserved 9.51 GB where
+# `conv` reserved 8.34, which with 0.40 GB in use beside the program is
+# PR 25's reading by hand, a peak of 9.91 GB against 8.75: 2.25x the
+# tagged bytes of what was free where `conv` took 1.95x. Per chip on four
+# it reserved 7.83 GB against 6.67. At 3.8 it needs 0.59 of what is free at
+# the threshold, under `conv`'s 0.65 at its own; the cells ask 14.66 GB of
+# 15.67 free (16.46 a chip on four), so a GB more resident does not flip
+# them. XLA's memory analysis for a described v5e (arguments + outputs +
+# temporaries, GB of 16.91; it read 1.6 GB over the chip at the cell's
+# shape) at the shapes no cell visits, none / conv / block:
+#   f32  chunk 10  11.41 / 10.14 /  9.13   resolves none
+#   f32  chunk 20  17.23 / 17.34 / 15.32   block (3 x 7.72 GB kept)
+#   f32  chunk 40  refused at 18.59G / 28.61G / 16.10G of 15.75G: block
+#   bf16 chunk 10   7.73 /  7.48           none
+#   bf16 chunk 20  12.62 / 11.86           none (3.86 GB kept)
+#   bf16 chunk 40  16.86 / 18.07 / 15.75   block
+#   f32  tenants 2 19.15 / 19.17 / 16.46   block
+#   bf16 tenants 2 18.76 / 13.22           conv: 3.8 x 3.86 = 14.66 GB of
+#                                          14.62 free
+# The last row sets the constant. A packed program keeps about 1.15 MB an
+# example more than an unpacked one when nothing is recomputed (f32, two
+# tenants of five agents: 14.32 against 11.41), 4.3x its tagged bytes in
+# bf16: at 3.5, where the unpacked f32 round would already sit under
+# `conv`'s share (2.64x by the analysis, 0.75 of what is free), the packed
+# bf16 row resolved `none` and did not fit. 3.8 is the least that turns it
+# down and the most that leaves the cells a GB. It does not cover every
+# pack: at 4.3x, a packed bf16 shape just inside the threshold would ask
+# for more than is free (PERF.md section 7; no cell packs).
+REMAT_NONE_SHARE_DIVISOR = 3.8
 
 
 @dataclasses.dataclass(frozen=True)
 class RematChoice:
     """What `resolved_remat` settled, and on what."""
-    policy: str                  # "block" | "conv": what get_model receives
-    saved_bytes: int             # conv outputs `conv` keeps on one device
+    policy: str                  # "block" | "conv" | "none": what
+    #                              get_model receives
+    saved_bytes: int             # conv outputs `conv` keeps on one device:
+    #                              the rule's input under every policy
     limit_bytes: Optional[int]   # the device's limit less what is resident
     #                              there; None: the backend reports none
     chosen: bool = False         # by the rule, not by the user
@@ -263,12 +297,17 @@ class RematChoice:
         held = ("no memory limit reported by this backend"
                 if self.limit_bytes is None else
                 f"{self.limit_bytes / 1e9:.2f} GB free of the device's "
-                f"limit, a 1/{REMAT_CONV_SHARE_DIVISOR} share of it "
-                f"allowed")
+                f"limit (none where {REMAT_NONE_SHARE_DIVISOR:g}x fit "
+                f"it, conv where {REMAT_CONV_SHARE_DIVISOR:g}x, else "
+                f"block)")
+        does = {"block": "every block is recomputed in backward",
+                "conv": "the convolution outputs are kept, the "
+                        "elementwise tail is recomputed",
+                "none": "nothing is recomputed"}[self.policy]
         return (f"remat policy {self.policy} "
-                f"({'auto' if self.chosen else 'as asked'}): keeping the "
-                f"convolution outputs takes {self.saved_bytes / 1e9:.2f} "
-                f"GB; {held}")
+                f"({'auto' if self.chosen else 'as asked'}): {does}; the "
+                f"convolution outputs of the examples in flight take "
+                f"{self.saved_bytes / 1e9:.2f} GB; {held}")
 
 
 def device_memory_limit() -> Optional[int]:
@@ -280,16 +319,22 @@ def device_memory_limit() -> Optional[int]:
 
 def remat_policy_for(bytes_per_example: int, examples_in_flight: int,
                      free_bytes: Optional[int]) -> str:
-    """The rule of `--remat_policy auto`, on numbers alone: `conv` when the
-    convolution outputs of the examples in flight fit their share of
-    `free_bytes`, the device's limit less what is resident, else `block`.
-    A backend that reports no limit (None) gets `block`: nothing there
-    says they fit."""
+    """The rule of `--remat_policy auto`, on numbers alone: a ladder over
+    the convolution outputs of the examples in flight, held against
+    `free_bytes`, the device's limit less what is resident. `none` where
+    the whole backward's activations fit beside them
+    (`REMAT_NONE_SHARE_DIVISOR` x their bytes), else `conv` where they
+    fit themselves (`REMAT_CONV_SHARE_DIVISOR` x), else `block`. A backend
+    that reports no limit (None) gets `block`: nothing there says they
+    fit."""
     if free_bytes is None:
         return "block"
     saved = bytes_per_example * examples_in_flight
-    return ("conv" if REMAT_CONV_SHARE_DIVISOR * saved <= free_bytes
-            else "block")
+    if REMAT_NONE_SHARE_DIVISOR * saved <= free_bytes:
+        return "none"
+    if REMAT_CONV_SHARE_DIVISOR * saved <= free_bytes:
+        return "conv"
+    return "block"
 
 
 @functools.lru_cache(maxsize=32)
@@ -307,8 +352,10 @@ def _remat_shapes(data: str, arch: str, dtype: str,
 def resolved_remat(cfg, fed=None,
                    threshold: Optional[int] = None) -> RematChoice:
     """Single source of what the backward pass recomputes under `--remat`
-    (ISSUE 25). `--remat_policy block|conv` is honoured; `auto` keeps the
-    convolution outputs (`conv`) when their bytes fit the device and
+    (ISSUE 25, ISSUE 30). `--remat_policy block|conv|none` is honoured;
+    `auto` recomputes nothing (`none`) when the whole backward's
+    activations fit the device, keeps the convolution outputs and
+    recomputes the elementwise tail (`conv`) when only those fit, and
     recomputes whole blocks (`block`) when they do not, from what the
     program can observe without compiling anything:
 
@@ -327,7 +374,7 @@ def resolved_remat(cfg, fed=None,
     Every builder of a model (the engine, the pack engine, precompile,
     bench, the jaxpr lint) resolves through here with its `fed` and writes
     the policy back into its cfg, so that `get_model` and the bank's
-    fingerprint see `block` or `conv`, never `auto`; `fingerprint`
+    fingerprint see `block`, `conv` or `none`, never `auto`; `fingerprint`
     resolves a cfg still carrying `auto` through here as well, so that
     `auto` is never a key of its own. Without `--remat` the policy selects
     nothing and reads `block`."""

@@ -12,6 +12,7 @@ Reference CNN_CIFAR (src/models.py:33-58):
 
 import jax
 import jax.numpy as jnp
+import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
     get_model, init_params, param_count)
@@ -132,6 +133,59 @@ def test_resnet9_selective_remat_matches_block():
     for a, b in zip(jax.tree_util.tree_leaves(g1),
                     jax.tree_util.tree_leaves(g2), strict=True):
         assert jnp.allclose(a, b, rtol=1e-5, atol=1e-6)
+
+
+def _grad_fn(model, x):
+    return jax.grad(lambda p: jnp.sum(jax.nn.log_softmax(
+        model.apply({"params": p}, x, train=False)) ** 2))
+
+
+def test_resnet9_remat_policy_none_is_the_unremated_model():
+    """`remat=True, remat_policy="none"` (what `--remat_policy auto`
+    settles where the whole backward's activations fit, ISSUE 30) builds
+    the plain modules: the parameter tree of every other policy, gradients
+    bit-equal to `remat=False`, and nothing to recompute in the jaxpr of
+    its gradient, where the other two policies have a checkpoint."""
+    from defending_against_backdoors_with_robust_learning_rate_tpu.utils.jaxprs import (
+        iter_eqns)
+    plain = get_model("cifar10", "resnet9")
+    none = get_model("cifar10", "resnet9", remat=True, remat_policy="none")
+    params = init_params(plain, (32, 32, 3), jax.random.PRNGKey(0))
+    for policy in ("block", "conv", "none"):
+        other = init_params(
+            get_model("cifar10", "resnet9", remat=True, remat_policy=policy),
+            (32, 32, 3), jax.random.PRNGKey(0))
+        assert (jax.tree_util.tree_structure(params)
+                == jax.tree_util.tree_structure(other))
+        for a, b in zip(jax.tree_util.tree_leaves(params),
+                        jax.tree_util.tree_leaves(other), strict=True):
+            assert a.shape == b.shape and bool(jnp.all(a == b))
+    x = jax.random.normal(jax.random.PRNGKey(1), (4, 32, 32, 3))
+    for a, b in zip(jax.tree_util.tree_leaves(_grad_fn(plain, x)(params)),
+                    jax.tree_util.tree_leaves(_grad_fn(none, x)(params)),
+                    strict=True):
+        assert bool(jnp.all(a == b))
+
+    def traced(model):
+        jaxpr = jax.make_jaxpr(_grad_fn(model, x))(params)
+        return str(jaxpr), {eqn.primitive.name for eqn in iter_eqns(jaxpr)
+                            } & {"checkpoint", "remat", "remat2"}
+
+    text, recomputed = traced(none)
+    assert not recomputed and text == traced(plain)[0]
+    for policy in ("block", "conv"):
+        assert traced(get_model("cifar10", "resnet9", remat=True,
+                                remat_policy=policy))[1]
+
+
+def test_get_model_takes_resolved_policies_only():
+    for asked in ("auto", "some"):
+        with pytest.raises(ValueError, match="resolve 'auto'"):
+            get_model("cifar10", "resnet9", remat=True, remat_policy=asked)
+    # without remat the policy selects nothing, whatever it says
+    for policy in ("block", "conv", "none"):
+        model = get_model("cifar10", "resnet9", remat_policy=policy)
+        assert (model.remat, model.remat_policy) == (False, policy)
 
 
 def test_flops_per_example_analytic():
