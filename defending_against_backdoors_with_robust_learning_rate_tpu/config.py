@@ -61,7 +61,9 @@ class Config:
     coordinator: str = ""           # host:port of process 0
     num_processes: int = 0          # total processes in the job
     process_id: int = -1            # this process's id; -1 = auto
-    arch: str = "auto"              # auto | cnn | resnet9 | lfm2_moe
+    arch: str = "auto"              # auto | cnn | resnet9, or a token model
+                                    # of models/registry.TOKEN_ARCHS:
+                                    # lfm2_moe | mla_moe
     dtype: str = "f32"              # f32 | bf16 (compute dtype on the MXU)
     rng_impl: str = "auto"          # auto: hardware RNG (rbg) on TPU,
                                     # threefry elsewhere; threefry | rbg
@@ -449,10 +451,12 @@ class Config:
     # synthetic-data knobs (used when `data` is missing on disk or 'synthetic')
     synth_train_size: int = 2048
     synth_val_size: int = 512
-    # --- the token task (--data=tokens --arch=lfm2_moe; fl/task.py) ---
+    # --- the token task (--data=tokens --arch=lfm2_moe|mla_moe; fl/task.py) ---
     seq_len: int = 2048             # tokens a packed sequence feeds the model
-    lm_config: str = "lfm2-8b-a1b"  # the published widths: a name in
-                                    # models/lfm2_moe.PUBLISHED, or a file
+    lm_config: str = "lfm2-8b-a1b"  # the published widths: a name in the
+                                    # arch's PUBLISHED (lfm2_moe:
+                                    # lfm2-8b-a1b; mla_moe:
+                                    # joyai-llm-flash), or a file
     lm_layers: str = ""             # the cut in depth: source layer indices
                                     # held, comma-separated ("" = all)
     lm_experts_held: int = 0        # experts of a sparse layer that live on
@@ -789,7 +793,8 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                    help="jax platform override (cpu|tpu); empty = default")
     p.add_argument("--seed", type=int, default=d.seed)
     p.add_argument("--arch", type=str, default=d.arch,
-                   help="auto|cnn|resnet9 (BASELINE.json configs[3-4])")
+                   help="auto|cnn|resnet9 (BASELINE.json configs[3-4]); "
+                        "with --data=tokens a token model: lfm2_moe|mla_moe")
     p.add_argument("--dtype", type=str, default=d.dtype, help="f32|bf16")
     p.add_argument("--rng_impl", choices=("auto", "threefry", "rbg"),
                    default=d.rng_impl,
@@ -1179,8 +1184,9 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--seq_len", type=int, default=d.seq_len,
                    help="--data=tokens: tokens of a packed sequence")
     p.add_argument("--lm_config", type=str, default=d.lm_config,
-                   help="--arch=lfm2_moe: the published widths, a name "
-                        "(lfm2-8b-a1b) or a JSON file")
+                   help="a token model's published widths, a name "
+                        "(--arch=lfm2_moe: lfm2-8b-a1b; --arch=mla_moe: "
+                        "joyai-llm-flash) or a JSON file")
     p.add_argument("--lm_layers", type=str, default=d.lm_layers,
                    help="the cut in depth: source layer indices held, "
                         "comma-separated (empty = all)")

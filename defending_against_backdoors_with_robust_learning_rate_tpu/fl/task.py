@@ -7,9 +7,12 @@ tokens, `attack/` for images).
   labels [bs])`, cross-entropy over the real samples, eval with the
   reference's 10-class confusion matrix (`fl/evaluate.py`).
 - token task (`--data=tokens`): batch `[bs, T + 1]` token ids; the loss is
-  the next-token cross-entropy per token over the real sequences; eval is
-  per token over the positions its mask counts, and also returns the
-  (token, expert) pairs the validation tokens were routed to.
+  the next-token cross-entropy per token over the real sequences, plus,
+  where the model's training forward gives logits for tokens further on
+  (multi-token prediction), `model.ahead_weight` times the mean of their
+  cross-entropies; eval runs the main model only, per token over the
+  positions its mask counts, and also returns the (token, expert) pairs the
+  validation tokens were routed to.
 
 What a combination of task and round does not support is refused in
 `utils/compile_cache.unsupported`, with one sentence each."""
@@ -33,6 +36,10 @@ MOE_PAIRS = "moe_pairs"
 # and how many of its sparse-layer forwards held more pairs than the sorted
 # buffer's first pass takes (`model.dispatch_rows`): the second pass ran
 MOE_OVERFLOW = "moe_overflow"
+# and, of a model that predicts further on, its auxiliary term (before the
+# weight) summed over the steps that held a real sequence, and those steps
+MTP_LOSS = "mtp_loss"
+MTP_STEPS = "mtp_steps"
 # the aggregation rules a folded round can run: sums over clients
 FOLD_RULES = ("avg", "sign")
 
@@ -52,17 +59,39 @@ def make_batch_loss(model, cfg, normalize, deterministic: bool = False):
     batch, per-step values to sum). `w` [bs] marks the real rows."""
     if is_tokens(cfg):
         def token_loss(params, x, _y, w, _rng):
-            logits, pairs = model.apply({"params": params}, x[:, :-1],
-                                        train=True)
-            ce = optax.softmax_cross_entropy_with_integer_labels(
-                logits, x[:, 1:])
+            logits, pairs, *ahead = model.apply({"params": params}, x[:, :-1],
+                                                train=True)
+
+            def token_ce(lg, k=0):
+                """[bs, T]: the logits at position i against the token
+                1 + k on; 0 at the last k positions, which have none."""
+                if not k:
+                    return optax.softmax_cross_entropy_with_integer_labels(
+                        lg, x[:, 1:])
+                ce = optax.softmax_cross_entropy_with_integer_labels(
+                    lg, jnp.pad(x[:, 1 + k:], ((0, 0), (0, k))))
+                return jnp.where(jnp.arange(lg.shape[1]) < lg.shape[1] - k,
+                                 ce, 0.0)
+
+            ce = token_ce(logits)
             wf = w.astype(jnp.float32)
             n = jnp.maximum(jnp.sum(wf) * ce.shape[1], 1.0)
             over = (jnp.sum(pairs[:, :-1], axis=1)
                     > model.dispatch_rows(ce.size))
-            return (jnp.sum(ce * wf[:, None]) / n,
-                    {MOE_PAIRS: pairs.astype(jnp.float32),
-                     MOE_OVERFLOW: jnp.sum(over, dtype=jnp.float32)})
+            loss = jnp.sum(ce * wf[:, None]) / n
+            sums = {MOE_PAIRS: pairs.astype(jnp.float32),
+                    MOE_OVERFLOW: jnp.sum(over, dtype=jnp.float32)}
+            if ahead and ahead[0]:
+                # multi-token prediction: module k's logits against the
+                # token k + 1 on, the modules' mean weighted into the loss
+                aux = sum(
+                    jnp.sum(token_ce(lg, k) * wf[:, None])
+                    / jnp.maximum(jnp.sum(wf) * (ce.shape[1] - k), 1.0)
+                    for k, lg in enumerate(ahead[0], start=1)) / len(ahead[0])
+                real = (jnp.sum(wf) > 0).astype(jnp.float32)
+                loss = loss + model.ahead_weight * aux
+                sums.update({MTP_LOSS: aux * real, MTP_STEPS: real})
+            return loss, sums
         return token_loss
 
     def image_loss(params, x, y, w, rng):
@@ -93,13 +122,18 @@ def split_per_client(per):
 def round_counters(sums: Dict) -> Dict:
     """The round's drained counters from the per-client sums: pairs
     computed here, pairs routed to absent experts, the largest and the
-    mean load of a held expert (over layers, clients and steps), and the
-    sparse-layer forwards that took the second pass."""
+    mean load of a held expert (over layers, clients and steps), the
+    sparse-layer forwards that took the second pass and, of a model with
+    an auxiliary term, its mean over the clients' steps (`mtp_loss`)."""
     if MOE_PAIRS not in sums:
         return {}
     pairs = jnp.sum(sums[MOE_PAIRS], axis=0)          # [layers, held + 1]
     held = pairs[:, :-1]
-    return {"moe_pairs_held": jnp.sum(held),
+    ahead = ({"mtp_loss": jnp.sum(sums[MTP_LOSS])
+              / jnp.maximum(jnp.sum(sums[MTP_STEPS]), 1.0)}
+             if MTP_LOSS in sums else {})
+    return {**ahead,
+            "moe_pairs_held": jnp.sum(held),
             "moe_pairs_absent": jnp.sum(pairs[:, -1]),
             "moe_load_max": jnp.max(held) if held.size else jnp.float32(0),
             "moe_load_mean": jnp.mean(held) if held.size else jnp.float32(0),

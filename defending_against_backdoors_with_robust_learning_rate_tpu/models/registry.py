@@ -29,9 +29,11 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     of either ("none": the model `remat=False` builds). It takes
     the RESOLVED policy: `--remat_policy auto` is a rule over the device's
     memory (utils/compile_cache.resolved_remat), not a model property.
-    An arch in `TOKEN_ARCHS` is a token task's model: it reads its widths
-    and its cut from `cfg`, which it needs, and under `remat` recomputes
-    block by block. This module is the one place that knows a model by its
+    An arch in `TOKEN_ARCHS` is a token task's model (`lfm2_moe`: short
+    convolutions, grouped-query attention, a 32-expert mixture; `mla_moe`:
+    latent attention, a 256-expert mixture with a shared expert, a
+    multi-token-prediction module): it reads its widths and its cut from
+    `cfg`, which it needs, and under `remat` recomputes block by block. This module is the one place that knows a model by its
     name: the engine, the planner and the data layer ask the model (or
     `arch_takes_tokens`, `token_vocab`) what it is."""
     if remat_policy not in REMAT_POLICIES:
@@ -58,8 +60,11 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
 # archs whose batch is `[bs, T + 1]` token ids, and the module of each: it
 # has `from_cfg(cfg, dtype, remat)` and `vocab_from_cfg(cfg)`, and its model
 # carries `takes_tokens = True`, `pairs_shape`, `dispatch_rows(n_tokens)` and
-# `build_counters(n_tokens)`
-TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe"}
+# `build_counters(n_tokens)`. Its forward returns `(logits, pairs)`; one that
+# predicts further on than the next token returns, in training, a third
+# value, the logits per token ahead, and carries `ahead_weight`
+# (fl/task.make_batch_loss)
+TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe", "mla_moe": "mla_moe"}
 
 
 def _token_module(arch: str):

@@ -1401,7 +1401,7 @@ class RoundEngine:
             # to, and the round's router counters ride the same fetch
             vals["moe_eval_pairs"] = per_class_d
             vals.update({k: info[k] for k in task_mod.MOE_ROUND_KEYS
-                         if k in info})
+                         + (task_mod.MTP_LOSS,) if k in info})
         else:
             vals["base_acc"] = per_class_d[cfg.base_class]
         if "fault_voters" in info:
@@ -1490,6 +1490,10 @@ class RoundEngine:
         writer.scalar("Poison/Cumulative_Poison_Accuracy_Mean",
                       cum_poison_acc / ernd, ernd)
         writer.scalar("Train/Loss", float(vals["train_loss"]), ernd)
+        if task_mod.MTP_LOSS in vals:
+            # the auxiliary term alone, before its weight (fl/task.py)
+            writer.scalar("Train/MTP_Loss", float(vals[task_mod.MTP_LOSS]),
+                          ernd)
         if "moe_eval_pairs" in vals:
             # router load (fl/task.py): the validation tokens' pairs by
             # sparse layer and held expert (last column: experts not held

@@ -20,7 +20,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.fl import task
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.client import (
     make_local_train)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe as lm)
+    lfm2_moe as lm, token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
     abstract_params, get_model, init_params, param_count)
 
@@ -199,7 +199,7 @@ def test_expert_bias_is_a_buffer_that_changes_selections(built):
                    if isinstance(layer, dict) for k in layer)
     b = lm.expert_bias(spec, 3)
     np.testing.assert_array_equal(b, ref.expert_bias(dims, 3))
-    assert np.all(b != 0) and np.abs(b).max() <= lm.EXPERT_BIAS_SCALE
+    assert np.all(b != 0) and np.abs(b).max() <= token_ops.EXPERT_BIAS_SCALE
     x = jax.random.normal(jax.random.PRNGKey(8), (64, spec.hidden))
     gate = params["layer_2"]["gate"]
     with_b, _ = ref.route(x, gate, dims, 3)
@@ -312,7 +312,9 @@ CUT_TOKENS = 64        # 128 pairs; a quarter of the experts held: 64 rows
 def small_tiles(monkeypatch):
     """At toy size the rule's 512-row tile covers every pair: whole tiles
     of 8 rows make the cut real. The rule itself is the module's."""
-    monkeypatch.setattr(lm, "MOE_ROWS_TILE", 8)
+    monkeypatch.setattr(token_ops, "MOE_ROWS_TILE", 8)
+    # (and a row a token as the floor: at top-2 two rows are every pair)
+    monkeypatch.setattr(token_ops, "MOE_ROWS_PER_TOKEN", 1)
 
 
 def layer_of(spec, seed):
@@ -386,10 +388,10 @@ def test_cut_buffer_equals_the_worst_case_formulation(small_tiles, held,
 
 
 @pytest.mark.parametrize("tokens,held,want", [
-    (8192, 8, 16384), (8192, 32, 32768), (8192, 1, 2048), (8192, 16, 32768),
+    (8192, 8, 16384), (8192, 32, 32768), (8192, 1, 16384), (8192, 16, 32768),
     (24, 4, 96), (1000, 8, 2048)])
 def test_dispatch_rows_follow_the_share_of_experts_held(tokens, held, want):
     spec = lm.spec_from("lfm2-8b-a1b", "2", held, 0, 16384)
     assert lm.dispatch_rows(spec, tokens) == want
     assert want <= tokens * spec.top_k and (
-        want == tokens * spec.top_k or want % lm.MOE_ROWS_TILE == 0)
+        want == tokens * spec.top_k or want % token_ops.MOE_ROWS_TILE == 0)

@@ -22,7 +22,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.fl.evaluate impor
 from defending_against_backdoors_with_robust_learning_rate_tpu.fl.rounds import (
     make_round_fn)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe as lm)
+    lfm2_moe as lm, token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
     get_model, init_params)
 
@@ -166,6 +166,45 @@ def test_folded_round_matches_stacked_round_on_the_token_model(fed):
     assert float(f_info["moe_load_max"]) >= float(f_info["moe_load_mean"]) > 0
 
 
+def test_folded_round_matches_stacked_round_on_the_mla_model():
+    """The latent-attention model through both aggregation paths at a tiny
+    size: the MTP module's leaves are trained, folded and voted like any
+    other, and the auxiliary term's row rides both."""
+    tiny = os.path.join(os.path.dirname(__file__), "data", "mla_tiny.json")
+    cfg = cfg_of(arch="mla_moe", lm_config=tiny, lm_layers="0,1,2")
+    fed = get_federated_data(cfg)
+    model = get_model(cfg.data, cfg.model_arch, "f32", remat=True, cfg=cfg)
+    params = init_params(model, (T,), jax.random.PRNGKey(0))
+    arrays = tuple(map(jnp.asarray, (fed.train.images, fed.train.labels,
+                                     fed.train.sizes)))
+    out = {}
+    for path in ("stack", "fold"):
+        fn = make_round_fn(cfg.replace(agg_path=path), model, None, *arrays)
+        out[path] = fn(params, jax.random.PRNGKey(5))
+    moved = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.abs(a - b).max()), out["fold"][0], params)
+    # (a held expert no token picked has no gradient: at toy widths the
+    # fixed bias decides most selections)
+    assert all(v > 0 for k, v in moved["mtp_0"].items() if k != "block")
+    assert all(moved["mtp_0"]["block"][k] > 0
+               for k in ("gate", "q_a_proj", "kv_a_norm", "shared_w2"))
+    assert max(jax.tree_util.tree_leaves(moved["mtp_0"])) > 0
+    for a, b in zip(jax.tree_util.tree_leaves(out["stack"][0]),
+                    jax.tree_util.tree_leaves(out["fold"][0]), strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=2e-6)
+    s_info, f_info = out["stack"][1], out["fold"][1]
+    for k in ("train_loss", "mtp_loss") + task.MOE_ROUND_KEYS:
+        np.testing.assert_allclose(float(s_info[k]), float(f_info[k]),
+                                   rtol=1e-6)
+    assert float(f_info["mtp_loss"]) > 0
+    assert float(f_info["train_loss"]) > float(f_info["mtp_loss"]) * 0.3
+    # 4 clients x 2 steps x 2 sequences x T tokens x 4 experts x (2 sparse
+    # layers + the MTP module's block)
+    assert float(f_info["moe_pairs_held"] + f_info["moe_pairs_absent"]) == \
+        4 * 2 * 2 * T * 4 * 3
+
+
 @pytest.fixture
 def every_pair_held(monkeypatch):
     """Steering for the sorted buffer's second pass, in the test alone: a
@@ -177,7 +216,8 @@ def every_pair_held(monkeypatch):
         return np.where((e >= 0) & (e < spec.experts_held), 8.0,
                         0.0).astype(np.float32)
     monkeypatch.setattr(lm, "expert_bias", bias)
-    monkeypatch.setattr(lm, "MOE_ROWS_TILE", 8)
+    monkeypatch.setattr(token_ops, "MOE_ROWS_TILE", 8)
+    monkeypatch.setattr(token_ops, "MOE_ROWS_PER_TOKEN", 1)
 
 
 def test_round_counts_the_forwards_that_took_the_second_pass(

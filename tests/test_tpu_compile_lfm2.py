@@ -1,9 +1,11 @@
-"""The token model's layers compiled for a described TPU v5e at the
+"""The token models' layers compiled for a described TPU v5e at the
 published widths, with no chip attached: what interpret-free CPU tests
 cannot show (the TPU compiler takes `jax.lax.ragged_dot` as its own grouped
 product, and the step's temporaries fit). Nothing runs, so nothing here is a
-time or a result. All of it lives in this one file: only the worker that is
-given the file loads the TPU's library, inside the fixture."""
+time or a result. All of it, for both token models, lives in this one file:
+only the worker that is given the file loads the TPU's library, inside the
+fixture (a second such file could go to another worker, whose fixture would
+then skip every test in silence)."""
 
 import os
 
@@ -12,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe as lm)
+    lfm2_moe as lm, mla_moe as mm)
 
 TOKENS = 8192          # a client's step: 4 sequences of 2048
 
@@ -105,3 +107,92 @@ def test_layer_compiles_for_the_v5e_at_published_widths(one_chip,
         entry = text[text.index("\nENTRY "):]
         assert f"[{TOKENS * spec.top_k},{f}]" not in entry
         assert temp < 700 * 2 ** 20, temp
+
+
+def mla_spec():
+    return mm.spec_from("joyai-llm-flash", "0,1,2,3,4", 8, 0, 16160)
+
+
+@pytest.mark.parametrize("what", ["mla_attention", "sparse_ffn"])
+def test_mla_layer_compiles_for_the_v5e_at_published_widths(one_chip,
+                                                            quiet_cache, what):
+    spec = mla_spec()
+    d, f, e = spec.hidden, spec.moe_ffn, spec.experts_held
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if what == "sparse_ffn":
+        p = {"gate": (d, spec.n_experts), "experts_w1": (e, d, f),
+             "experts_w3": (e, d, f), "experts_w2": (e, f, d),
+             "shared_w1": (d, spec.shared_ffn),
+             "shared_w3": (d, spec.shared_ffn),
+             "shared_w2": (spec.shared_ffn, d)}
+        x = _aval((TOKENS, d), bf16, one_chip)
+
+        def fn(p, x):
+            return mm.sparse_ffn(p, x, spec, 2, bf16)
+    else:
+        h = spec.heads
+        p = {"q_a_proj": (d, spec.q_rank), "q_a_norm": (spec.q_rank,),
+             "q_b_proj": (spec.q_rank, h * (spec.nope_dim + spec.rope_dim)),
+             "kv_a_proj": (d, spec.kv_rank + spec.rope_dim),
+             "kv_a_norm": (spec.kv_rank,),
+             "kv_b_proj": (spec.kv_rank, h * (spec.nope_dim + spec.v_dim)),
+             "o_proj": (h * spec.v_dim, d)}
+        x = _aval((4, 2048, d), bf16, one_chip)
+
+        def fn(p, x):
+            return mm.mla_attention(p, x, spec, bf16), ()
+    p = {k: _aval(s, f32, one_chip) for k, s in p.items()}
+
+    def loss(p, x):
+        out, _aux = fn(p, x)
+        return jnp.sum(out.astype(f32))
+
+    compiled = jax.jit(jax.grad(loss)).lower(p, x).compile()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < 3 * 2 ** 30, temp
+    if what == "sparse_ffn":
+        # 8 of 256 experts held: 16384 of the step's 65536 sorted rows (two
+        # rows a token), the rest on a conditional that carries no buffer of its
+        # size
+        assert text.count("ragged-dot") >= 3
+        assert mm.dispatch_rows(spec, TOKENS) == 16384
+        assert " conditional(" in text
+        entry = text[text.index("\nENTRY "):]
+        assert f"[{TOKENS * spec.top_k},{f}]" not in entry
+        # what is left is combine's and dispatch's gather of a token's 8
+        # pairs, [8, 8192, 2048] (LFM2's top-4: half of it): 1038 MiB here
+        assert temp < 1200 * 2 ** 20, temp
+
+
+def test_mla_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
+        one_chip, quiet_cache):
+    """A client's whole step at the benchmark's shape: the loss with its
+    MTP term through `fl/task.make_batch_loss`, every block recomputed, and
+    its gradient, 491.7M parameters. The round holds four more trees of
+    this size beside it, so a step has about 5 GiB for its temporaries."""
+    import types
+
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        task)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
+        abstract_params, param_count)
+    model = mm.MlaMoE(spec=mla_spec(), dtype=jnp.bfloat16, remat=True)
+    shapes = abstract_params(model, (2048,))
+    assert param_count(shapes) == 491_696_128
+    p = jax.tree_util.tree_map(
+        lambda a: _aval(a.shape, a.dtype, one_chip), shapes)
+    loss = task.make_batch_loss(model, types.SimpleNamespace(data="tokens"),
+                                None)
+    step = jax.jit(jax.value_and_grad(
+        lambda p, x, w: loss(p, x, None, w, None), has_aux=True))
+    compiled = step.lower(p, _aval((4, 2049), jnp.int32, one_chip),
+                          _aval((4,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    # five sparse blocks (the MTP module's with them), each three grouped
+    # products forward, recomputed, and their transposes
+    assert text.count("ragged-dot") >= 5 * 3 * 3
+    assert text.count(" conditional(") >= 5
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes >= 4 * 491_696_128
+    assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
