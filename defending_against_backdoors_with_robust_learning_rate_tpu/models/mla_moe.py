@@ -53,7 +53,8 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, causal_attention, dense_ffn, dispatch_rows)
+    _mm, _rms, attention_squares, causal_attention, dense_ffn,
+    dispatch_rows)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 PUBLISHED = {"joyai-llm-flash": os.path.join(_HERE, "joyai_llm_flash.json")}
@@ -323,13 +324,15 @@ class MlaMoE(nn.Module):
     def dispatch_rows(self, n_tokens: int) -> int:
         return dispatch_rows(self.spec, n_tokens)
 
-    def build_counters(self, n_tokens: int):
+    def build_counters(self, n_tokens: int, seq_len: int):
         """Counted once when an engine is built (obs/spans.py), for a step
-        of `n_tokens` tokens."""
+        of `n_tokens` tokens in sequences of `seq_len`."""
+        computed, square = attention_squares(seq_len)
         return {"experts_held": self.spec.experts_held,
                 "vocab_held": self.spec.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
                 "moe_rows_worst": n_tokens * self.spec.top_k,
+                "attn_squares_computed": computed, "attn_squares": square,
                 "mtp_depth": self.spec.mtp_depth,
                 "shared_experts": self.spec.shared_ffn // self.spec.moe_ffn}
 

@@ -60,9 +60,9 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
 # archs whose batch is `[bs, T + 1]` token ids, and the module of each: it
 # has `from_cfg(cfg, dtype, remat)` and `vocab_from_cfg(cfg)`, and its model
 # carries `takes_tokens = True`, `pairs_shape`, `dispatch_rows(n_tokens)` and
-# `build_counters(n_tokens)`. Its forward returns `(logits, pairs)`; one that
-# predicts further on than the next token returns, in training, a third
-# value, the logits per token ahead, and carries `ahead_weight`
+# `build_counters(n_tokens, seq_len)`. Its forward returns `(logits, pairs)`;
+# one that predicts further on than the next token returns, in training, a
+# third value, the logits per token ahead, and carries `ahead_weight`
 # (fl/task.make_batch_loss)
 TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe", "mla_moe": "mla_moe"}
 

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-ATTN_QUERY_BLOCK = 512     # attention runs over query blocks of this many
+ATTN_QUERY_BLOCK = 256     # attention runs over query blocks of this many
 # the sorted buffer of a sparse layer holds MOE_ROWS_OVER_EXPECTED times the
 # pairs its held experts draw when every expert draws alike, in whole tiles
 # of MOE_ROWS_TILE rows (`dispatch_rows`)
@@ -90,36 +90,61 @@ def _rms(x, w, eps):
     return y * w
 
 
+def _query_block(seq_len: int, q_block: int) -> int:
+    """Rows of a query block: `q_block` where it divides a longer sequence,
+    else the whole sequence in one block."""
+    return (q_block if seq_len % q_block == 0 and seq_len > q_block
+            else seq_len)
+
+
+def attention_squares(seq_len: int, q_block: int = ATTN_QUERY_BLOCK):
+    """(computed, square): how many (query block, key block) squares of
+    scores `causal_attention` forms for a sequence of `seq_len`, those at
+    or below the diagonal, and how many the square score matrix has."""
+    nb = seq_len // _query_block(seq_len, q_block)
+    return nb * (nb + 1) // 2, nb * nb
+
+
 def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK):
     """Causal softmax attention over query blocks, in plain `jax.numpy`:
     q [B, T, H, d], k [B, T, KV, d] and v [B, T, KV, dv], H a multiple of
     KV; -> [B, T, H * dv]. Scores (scaled by d ** -0.5) and softmax are
-    float32; a block's scores are recomputed in backward, so one block's
-    [B, H, q_block, T] is what is live."""
+    float32. A query block reads the keys and values at or before its last
+    row and no others: the squares above the diagonal are never formed
+    (`attention_squares`), the diagonal square is masked. Every block's
+    scores are recomputed in backward, so what is saved is q, k and v."""
     b, t, h, d = q.shape
     kv = k.shape[2]
     g = h // kv
     scale = d ** -0.5
-    qb = q_block if t % q_block == 0 and t > q_block else t
-    nb = t // qb
-    qs = q.reshape(b, nb, qb, kv, g, d).transpose(1, 0, 2, 3, 4, 5)
-    cols = jnp.arange(t)
+    qb = _query_block(t, q_block)
+    qs = q.reshape(b, t // qb, qb, kv, g, d)
 
-    def block(args):
-        qi, i = args
-        s = jnp.einsum("bqkgd,bskd->bkgqs", qi, k,
+    def block(qi, ki, vi):
+        """`qi`'s rows are the last `qb` of the `ki.shape[1]` positions."""
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qi, ki,
                        preferred_element_type=jnp.float32) * scale
-        rows = i * qb + jnp.arange(qb)
+        cols = jnp.arange(ki.shape[1])
+        rows = ki.shape[1] - qb + jnp.arange(qb)
         s = jnp.where(rows[:, None] >= cols[None, :], s,
                       jnp.finfo(jnp.float32).min)
-        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-        return jnp.einsum("bkgqs,bskd->bqkgd", p, v)
+        p = jax.nn.softmax(s, axis=-1).astype(vi.dtype)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p, vi)
 
-    if nb == 1:
-        out = block((qs[0], 0))[None]
-    else:
-        out = jax.lax.map(jax.checkpoint(block), (qs, jnp.arange(nb)))
-    return out.transpose(1, 0, 2, 3, 4, 5).reshape(b, t, h * v.shape[-1])
+    if t == qb:
+        return block(qs[:, 0], k, v).reshape(b, t, h * v.shape[-1])
+    # the blocks have each its own width, so they are traced in turn, and
+    # each waits for the one before it (backward, for the one after it):
+    # left to the scheduler, eight blocks' scores and key gradients at once
+    # put the MLA layer's temporaries 9% over what a rolled loop took
+    # (XLA's memory analysis for a described v5e; PERF.md section 6, PR 32)
+    outs = []
+    for i, end in enumerate(range(qb, t + 1, qb)):
+        qi = qs[:, i]
+        if outs:
+            qi, outs[-1] = jax.lax.optimization_barrier((qi, outs[-1]))
+        outs.append(jax.checkpoint(block)(qi, k[:, :end], v[:, :end]))
+    return jnp.concatenate(outs, axis=1).reshape(b, t, h * v.shape[-1])
 
 
 def dispatch_rows(sp, n_tokens: int) -> int:

@@ -321,9 +321,11 @@ class RoundEngine:
                               remat_policy=cfg.remat_policy, cfg=cfg)
             example_shape = task_mod.input_shape(cfg, fed)
             # what a model wants counted once at build (a token model: the
-            # experts and vocabulary rows it holds, and the rows a sparse
-            # layer's first pass takes of a step's sorted pairs)
-            built = (model.build_counters(cfg.bs * example_shape[0])
+            # experts and vocabulary rows it holds, the rows a sparse
+            # layer's first pass takes of a step's sorted pairs, and the
+            # squares of a sequence's scores its attention forms)
+            built = (model.build_counters(cfg.bs * example_shape[0],
+                                          example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
             for name, value in built.items():
                 tracer.count(name, value)
@@ -347,6 +349,11 @@ class RoundEngine:
                       f"{built['moe_rows_worst']}: the first pass over a "
                       f"step's sorted pairs, {built['experts_held']} "
                       f"experts held; what it cannot hold takes a second")
+                print(f"[model] attention squares "
+                      f"{built['attn_squares_computed']} of "
+                      f"{built['attn_squares']}: the blocks of a sequence's "
+                      f"scores at or below the diagonal, the only ones "
+                      f"formed")
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

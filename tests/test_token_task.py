@@ -282,7 +282,11 @@ def test_engine_counts_the_buffers_rows_and_its_overflow(
     assert counted["experts_held"] == held
     # 2 clients x 2 steps x 1 sparse layer
     assert counted["moe_overflow_steps"] == (4 if overflow else 0)
-    assert f"[model] moe rows {rows} of {pairs}" in capsys.readouterr().out
+    # sequences of 16: one block, its one square
+    assert counted["attn_squares_computed"] == counted["attn_squares"] == 1
+    out = capsys.readouterr().out
+    assert f"[model] moe rows {rows} of {pairs}" in out
+    assert "[model] attention squares 1 of 1" in out
     run_dirs = [d for d in tmp_path.iterdir() if d.is_dir()]
     with open(run_dirs[0] / "metrics.jsonl") as fh:
         written = [json.loads(line) for line in fh]

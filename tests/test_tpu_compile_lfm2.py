@@ -17,6 +17,17 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     lfm2_moe as lm, mla_moe as mm)
 
 TOKENS = 8192          # a client's step: 4 sequences of 2048
+# temporaries (bytes) of the cases that run `token_ops.causal_attention`,
+# read from these same tests at the parent of PR 32 (commit 47e0b38, where
+# every query block still multiplied all 2048 keys under a rolled
+# `jax.lax.map`), here, under the suite's settings. The blocks are traced
+# in turn since, each ordered behind the one before it so that XLA's
+# scheduler does not hold several blocks' scores at once: each case may read
+# at most ATTN_TEMP_ROOM times its number
+ATTN_TEMP_AT_PARENT = {"attention": 1_195_053_568,
+                       "mla_attention": 1_639_820_800,
+                       "mla_step": 3_393_176_576}
+ATTN_TEMP_ROOM = 1.05
 
 
 @pytest.fixture(scope="module")
@@ -97,6 +108,8 @@ def test_layer_compiles_for_the_v5e_at_published_widths(one_chip,
     # one layer's backward, without the round's accumulators: well under
     # the 4.7 GB the cut leaves for a step's activations
     assert 0 < temp < 3 * 2 ** 30, temp
+    if what in ATTN_TEMP_AT_PARENT:
+        assert temp <= ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT[what], temp
     if what == "sparse_ffn":
         # 8 of 32 experts held: the first pass sorts into 16384 rows of the
         # 32768 pairs, and the rest hangs on a conditional that carries no
@@ -151,6 +164,8 @@ def test_mla_layer_compiles_for_the_v5e_at_published_widths(one_chip,
     text = compiled.as_text()
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert 0 < temp < 3 * 2 ** 30, temp
+    if what in ATTN_TEMP_AT_PARENT:
+        assert temp <= ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT[what], temp
     if what == "sparse_ffn":
         # 8 of 256 experts held: 16384 of the step's 65536 sorted rows (two
         # rows a token), the rest on a conditional that carries no buffer of its
@@ -196,3 +211,5 @@ def test_mla_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     ma = compiled.memory_analysis()
     assert ma.output_size_in_bytes >= 4 * 491_696_128
     assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes <= \
+        ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT["mla_step"], ma.temp_size_in_bytes
