@@ -45,7 +45,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
     _mm, _rms, attention_squares, causal_attention, dense_ffn,
-    dispatch_rows)
+    dispatch_rows, rope_half)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 PUBLISHED = {"lfm2-8b-a1b": os.path.join(_HERE, "lfm2_8b_a1b.json")}
@@ -144,13 +144,9 @@ def sparse_ffn(p, x, sp: LMSpec, src_layer: int, dtype):
 
 def _rope(x, theta):
     """Rotate-half rotary embedding over the whole head; x [B, T, n, d]."""
-    d, t = x.shape[-1], x.shape[1]
+    d = x.shape[-1]
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
-    x1, x2 = x[..., :d // 2], x[..., d // 2:]
-    rot = jnp.concatenate([-x2, x1], axis=-1)
-    return x * jnp.cos(ang) + rot * jnp.sin(ang)
+    return rope_half(x, inv)
 
 
 def short_conv(p, x, sp: LMSpec, dtype):

@@ -22,7 +22,8 @@ family's public implementation give them (what the file lacks is under its
   use) over all `n_routed_experts` with top-k, the weights normalised over
   the selected (`+ 1e-20`) and scaled by `routed_scaling_factor`, the
   correction bias a buffer outside the trained tree; plus the shared
-  expert, which every chip of the deployment computes alike.
+  expert (`token_ops.shared_expert`), which every chip of the deployment
+  computes alike.
 - MTP module k (`num_nextn_predict_layers` of them, k from 1): `u_i = W_eh
   [rms_e(embed(t_{i+k})) ; rms_h(h_i)]` with `h_i` the module before's
   output (the last main block's, before the final norm, for k = 1), one
@@ -54,7 +55,7 @@ from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
     _mm, _rms, attention_squares, causal_attention, dense_ffn,
-    dispatch_rows)
+    dispatch_rows, shared_expert)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 PUBLISHED = {"joyai-llm-flash": os.path.join(_HERE, "joyai_llm_flash.json")}
@@ -204,12 +205,6 @@ def mla_attention(p, x, sp: MlaSpec, dtype):
             [kv[..., :dn], jnp.broadcast_to(k_pe, (b, t, h, dr))], axis=-1)
         o = causal_attention(q, k, kv[..., dn:])
         return _mm(o, p["o_proj"], dtype)
-
-
-def shared_expert(p, x, dtype):
-    with jax.named_scope("shared_expert"):
-        return _mm(jax.nn.silu(_mm(x, p["shared_w1"], dtype))
-                   * _mm(x, p["shared_w3"], dtype), p["shared_w2"], dtype)
 
 
 def sparse_ffn(p, x, sp: MlaSpec, src_layer: int, dtype):

@@ -32,7 +32,9 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     An arch in `TOKEN_ARCHS` is a token task's model (`lfm2_moe`: short
     convolutions, grouped-query attention, a 32-expert mixture; `mla_moe`:
     latent attention, a 256-expert mixture with a shared expert, a
-    multi-token-prediction module): it reads its widths and its cut from
+    multi-token-prediction module; `swa_moe`: sliding-window and full
+    attention mixed by layer with query heads and rotary embedding by layer
+    kind, a 256-expert mixture with a shared expert): it reads its widths and its cut from
     `cfg`, which it needs, and under `remat` recomputes block by block. This module is the one place that knows a model by its
     name: the engine, the planner and the data layer ask the model (or
     `arch_takes_tokens`, `token_vocab`) what it is."""
@@ -64,7 +66,8 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
 # one that predicts further on than the next token returns, in training, a
 # third value, the logits per token ahead, and carries `ahead_weight`
 # (fl/task.make_batch_loss)
-TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe", "mla_moe": "mla_moe"}
+TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe", "mla_moe": "mla_moe",
+               "swa_moe": "swa_moe"}
 
 
 def _token_module(arch: str):

@@ -1,9 +1,10 @@
-"""What the token models share (`lfm2_moe.py`, `mla_moe.py`): RMSNorm, the
-matrix product in the products' dtype, causal attention over query blocks,
-the gated feed-forward, and ONE implementation of the sparse feed-forward:
-sigmoid routing over every published expert, the sort of the (token,
-expert) pairs by held expert, dispatch, the held experts' grouped products
-and combine.
+"""What the token models share (`lfm2_moe.py`, `mla_moe.py`, `swa_moe.py`):
+RMSNorm, the matrix product in the products' dtype, rotate-half rotary
+embedding, causal attention over query blocks (with a sliding window where
+a layer has one), the gated feed-forward, the shared expert, and ONE
+implementation of the sparse feed-forward: sigmoid routing over every
+published expert, the sort of the (token, expert) pairs by held expert,
+dispatch, the held experts' grouped products and combine.
 
 A sparse layer reads its shape off the model's spec, whatever its class:
 `n_experts` (the router's width), `top_k`, `norm_topk`, `topk_eps` (what the
@@ -21,7 +22,7 @@ expert, those held first, and the held experts' products are three
 twice the share of the pairs that the held experts draw in expectation and
 at least two rows a token; a step that holds more computes the rest in a
 second pass under a `jax.lax.cond`. Scopes: `moe_router`, `moe_experts`,
-`dense_ffn`."""
+`dense_ffn`, `shared_expert`."""
 
 from __future__ import annotations
 
@@ -97,22 +98,56 @@ def _query_block(seq_len: int, q_block: int) -> int:
             else seq_len)
 
 
-def attention_squares(seq_len: int, q_block: int = ATTN_QUERY_BLOCK):
+def _first_key_block(i: int, qb: int, window) -> int:
+    """The key block query block `i` starts reading at: the one that holds
+    column `i * qb - window + 1`, the earliest key its first row reads
+    (block 0 without a window)."""
+    return 0 if window is None else max(0, (i * qb - window + 1) // qb)
+
+
+def attention_squares(seq_len: int, q_block: int = ATTN_QUERY_BLOCK,
+                      window=None):
     """(computed, square): how many (query block, key block) squares of
     scores `causal_attention` forms for a sequence of `seq_len`, those at
-    or below the diagonal, and how many the square score matrix has."""
-    nb = seq_len // _query_block(seq_len, q_block)
-    return nb * (nb + 1) // 2, nb * nb
+    or below the diagonal and, under a `window`, not wholly below the
+    band, and how many the square score matrix has."""
+    qb = _query_block(seq_len, q_block)
+    nb = seq_len // qb
+    return (sum(i + 1 - _first_key_block(i, qb, window) for i in range(nb)),
+            nb * nb)
 
 
-def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK):
+def rope_half(x, inv_freq, scale: float = 1.0):
+    """Rotate-half rotary embedding, `x cos + rot(x) sin` with `rot(x) =
+    [-x2, x1]`, over the first `2 * len(inv_freq)` widths of a head, the
+    rest passed through; x [B, T, n, d], positions from 0. `scale`
+    multiplies cos and sin (YaRN's attention factor)."""
+    d, t, r = x.shape[-1], x.shape[1], 2 * inv_freq.shape[0]
+    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
+    xr = x if r == d else x[..., :r]
+    x1, x2 = xr[..., :r // 2], xr[..., r // 2:]
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+
+    def scaled(fn):
+        return fn(ang) if scale == 1.0 else fn(ang) * scale
+
+    out = xr * scaled(jnp.cos) + rot * scaled(jnp.sin)
+    return out if r == d else jnp.concatenate([out, x[..., r:]], axis=-1)
+
+
+def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK, window=None):
     """Causal softmax attention over query blocks, in plain `jax.numpy`:
     q [B, T, H, d], k [B, T, KV, d] and v [B, T, KV, dv], H a multiple of
     KV; -> [B, T, H * dv]. Scores (scaled by d ** -0.5) and softmax are
     float32. A query block reads the keys and values at or before its last
     row and no others: the squares above the diagonal are never formed
-    (`attention_squares`), the diagonal square is masked. Every block's
-    scores are recomputed in backward, so what is saved is q, k and v."""
+    (`attention_squares`), the diagonal square is masked. With a `window`,
+    query r reads the `window` keys c with `r - window < c <= r`: a block
+    reads from the start of the key block that holds its first row's
+    earliest key, masks the band inside what it reads, and the squares
+    below the band are never formed either. Every block's scores are
+    recomputed in backward, so what is saved is q, k and v."""
     b, t, h, d = q.shape
     kv = k.shape[2]
     g = h // kv
@@ -126,8 +161,10 @@ def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK):
                        preferred_element_type=jnp.float32) * scale
         cols = jnp.arange(ki.shape[1])
         rows = ki.shape[1] - qb + jnp.arange(qb)
-        s = jnp.where(rows[:, None] >= cols[None, :], s,
-                      jnp.finfo(jnp.float32).min)
+        keep = rows[:, None] >= cols[None, :]
+        if window is not None:
+            keep = keep & (rows[:, None] - cols[None, :] < window)
+        s = jnp.where(keep, s, jnp.finfo(jnp.float32).min)
         p = jax.nn.softmax(s, axis=-1).astype(vi.dtype)
         return jnp.einsum("bkgqs,bskd->bqkgd", p, vi)
 
@@ -143,7 +180,9 @@ def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK):
         qi = qs[:, i]
         if outs:
             qi, outs[-1] = jax.lax.optimization_barrier((qi, outs[-1]))
-        outs.append(jax.checkpoint(block)(qi, k[:, :end], v[:, :end]))
+        start = _first_key_block(i, qb, window) * qb
+        outs.append(jax.checkpoint(block)(
+            qi, k[:, start:end], v[:, start:end]))
     return jnp.concatenate(outs, axis=1).reshape(b, t, h * v.shape[-1])
 
 
@@ -237,6 +276,14 @@ def dense_ffn(p, x, dtype):
     with jax.named_scope("dense_ffn"):
         return _mm(jax.nn.silu(_mm(x, p["w1"], dtype))
                    * _mm(x, p["w3"], dtype), p["w2"], dtype)
+
+
+def shared_expert(p, x, dtype):
+    """The gated feed-forward every token passes beside its routed experts
+    (`shared_w1`, `shared_w3`, `shared_w2`), added ungated."""
+    with jax.named_scope("shared_expert"):
+        return _mm(jax.nn.silu(_mm(x, p["shared_w1"], dtype))
+                   * _mm(x, p["shared_w3"], dtype), p["shared_w2"], dtype)
 
 
 def _expert_rows(lo, hi, trained, sort):

@@ -323,7 +323,8 @@ class RoundEngine:
             # what a model wants counted once at build (a token model: the
             # experts and vocabulary rows it holds, the rows a sparse
             # layer's first pass takes of a step's sorted pairs, and the
-            # squares of a sequence's scores its attention forms)
+            # squares of a sequence's scores its attention forms, by layer
+            # kind where some layers have a window)
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
@@ -353,7 +354,13 @@ class RoundEngine:
                       f"{built['attn_squares_computed']} of "
                       f"{built['attn_squares']}: the blocks of a sequence's "
                       f"scores at or below the diagonal, the only ones "
-                      f"formed")
+                      f"formed"
+                      + (f" in {built['attn_full_layers']} full-attention "
+                         f"layer(s); {built['attn_window_squares_computed']}"
+                         f" in {built['attn_window_layers']} layer(s) with "
+                         f"a window of {built['attn_window']} keys, those "
+                         f"below the band not formed either"
+                         if "attn_window" in built else ""))
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

@@ -174,3 +174,215 @@ def test_both_token_models_count_the_squares_at_build(module, model):
         attention_squares(2048) == (36, 64)
     toy = model().build_counters(2 * 16, 16)
     assert (toy["attn_squares_computed"], toy["attn_squares"]) == (1, 1)
+
+
+# ---- a sliding window in the same core (PR 33) ------------------------------
+def banded_matrix_attention(q, k, v, window):
+    """The whole [T, T] score matrix, masked outside the band: query r
+    reads keys c with r - window < c <= r."""
+    b, t, h, d = q.shape
+    g = h // k.shape[2]
+    k, v = jnp.repeat(k, g, axis=2), jnp.repeat(v, g, axis=2)
+    s = jnp.einsum("bqhd,bshd->bhqs", q, k,
+                   precision=jax.lax.Precision.HIGHEST) * d ** -0.5
+    r, c = jnp.arange(t)[:, None], jnp.arange(t)[None, :]
+    s = jnp.where((c <= r) & (r - c < window), s, -jnp.inf)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("bhqs,bshd->bqhd", p, v,
+                   precision=jax.lax.Precision.HIGHEST)
+    return o.reshape(b, t, -1)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("window", [16, 24, 40])
+@pytest.mark.parametrize("t,q_block", [(64, 16), (64, 32), (48, 16)])
+def test_window_blocks_match_a_banded_softmax_in_output_and_gradients(
+        layout, t, q_block, window):
+    """Windows the block divides, one it does not (24 over 16, 40 over 16
+    and 32) and one wider than a block: outputs and q/k/v gradients."""
+    q, k, v = _qkv(layout, t, seed=5)
+    computed, square = attention_squares(t, q_block, window)
+    assert square > 1 and computed <= attention_squares(t, q_block)[0]
+    w = jax.random.normal(jax.random.PRNGKey(7),
+                          (2, t, LAYOUTS[layout][0] * LAYOUTS[layout][3]))
+
+    def scalar(fn):
+        return lambda q, k, v: jnp.sum(fn(q, k, v) * w)
+
+    got = causal_attention(q, k, v, q_block, window)
+    want = banded_matrix_attention(q, k, v, window)
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-6)
+    # the window is felt: the causal result is another
+    assert float(jnp.abs(want - full_matrix_attention(q, k, v)).max()) > 1e-3
+    grads = jax.grad(scalar(lambda *a: causal_attention(*a, q_block, window)),
+                     argnums=(0, 1, 2))(q, k, v)
+    wants = jax.grad(scalar(lambda *a: banded_matrix_attention(*a, window)),
+                     argnums=(0, 1, 2))(q, k, v)
+    for name, g, wnt in zip("qkv", grads, wants):
+        np.testing.assert_allclose(g, wnt, rtol=2e-5, atol=2e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("t,q_block,window", [(40, 16, 24), (16, 16, 4)])
+def test_a_window_inside_one_block_is_masked_there(layout, t, q_block,
+                                                   window):
+    """A sequence that takes one block (the block does not divide it, or it
+    is the block): the band is masked inside it."""
+    q, k, v = _qkv(layout, t, seed=6)
+    assert attention_squares(t, q_block, window) == (1, 1)
+    np.testing.assert_allclose(causal_attention(q, k, v, q_block, window),
+                               banded_matrix_attention(q, k, v, window),
+                               rtol=2e-5, atol=2e-6)
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("window", [16, 24])
+def test_a_key_outside_the_band_does_not_touch_a_row(layout, window):
+    """Perturb the key and value at position 20: rows before it (causal)
+    and rows from 20 + window on (below the band) stay bit-equal, whether
+    the key's square is formed and masked or never formed; rows inside the
+    band move."""
+    t, q_block, at = 64, 16, 20
+    q, k, v = _qkv(layout, t, seed=1)
+    base = causal_attention(q, k, v, q_block, window)
+    moved = causal_attention(q, k.at[:, at].add(3.0), v.at[:, at].add(-2.0),
+                             q_block, window)
+    outside = np.r_[0:at, at + window:t]
+    np.testing.assert_array_equal(np.asarray(base[:, outside]),
+                                  np.asarray(moved[:, outside]))
+    inside = np.asarray(base[:, at:at + window]
+                        != moved[:, at:at + window])
+    assert inside.any(axis=(0, 2)).all()
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_window_blocks_batch_over_clients(layout):
+    t, q_block, window = 48, 16, 24
+    q, k, v = (jnp.stack(x) for x in zip(_qkv(layout, t, seed=3),
+                                         _qkv(layout, t, seed=4)))
+
+    def loss(q, k, v):
+        return jnp.sum(causal_attention(q, k, v, q_block, window) ** 2)
+
+    got = jax.vmap(jax.grad(loss, argnums=(0, 1, 2)))(q, k, v)
+    for i in range(2):
+        want = jax.grad(loss, argnums=(0, 1, 2))(q[i], k[i], v[i])
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(g[i], w, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("seq_len,q_block,window,want", [
+    (4096, 256, 512, (45, 256)), (4096, 256, None, (136, 256)),
+    (2048, 256, 512, (21, 64)), (4096, 256, 256, (31, 256)),
+    (4096, 256, 513, (45, 256)), (4096, 256, 514, (58, 256)),
+    (4096, 256, 1, (16, 256)),
+    (4096, 256, 4096, (136, 256)), (64, 16, 24, (9, 16)),
+    (64, 16, 40, (10, 16)), (64, 32, 40, (3, 4)), (40, 16, 24, (1, 1))])
+def test_attention_squares_under_a_window(seq_len, q_block, window, want):
+    assert attention_squares(seq_len, q_block, window) == want
+
+
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("heads", [64, 48])
+def test_no_window_product_spans_more_than_three_blocks_at_4096(backward,
+                                                               heads):
+    """Traced, not run, at the new cell's shape (T 4096, 64 or 48 query
+    heads over 8 key-value heads of 128, a window of 512, blocks of 256): a
+    block's score products are over at most 768 columns, 256 + 512 + 14 x
+    768 in all where the causal core holds 256 x (1 + ... + 16)."""
+    t, qb, window, kv, d = 4096, token_ops.ATTN_QUERY_BLOCK, 512, 8, 128
+    g = heads // kv
+    q, k, v = (jax.ShapeDtypeStruct((1, t, n, d), jnp.bfloat16)
+               for n in (heads, kv, kv))
+
+    def fn(q, k, v, window=window):
+        return jnp.sum(causal_attention(q, k, v, qb, window
+                                        ).astype(jnp.float32))
+
+    closed = jax.make_jaxpr(jax.grad(fn, argnums=(0, 1, 2))
+                            if backward else fn)(q, k, v)
+    widths = sorted(max(out) for out in _dot_shapes(closed.jaxpr, [])
+                    if sorted(out) == sorted((1, kv, g, qb, max(out))))
+    each = 3 if backward else 1
+    assert widths == sorted(([256, 512] + [768] * 14) * each), widths
+    assert max(widths) == 768
+    computed, square = attention_squares(t, qb, window)
+    assert (computed, square) == (45, 256)
+    assert sum(widths) * qb == each * computed * qb * qb
+    # the causal core at the same shape: 136 squares
+    causal = jax.make_jaxpr(lambda q, k, v: fn(q, k, v, None))(q, k, v)
+    assert sum(max(out) for out in _dot_shapes(causal.jaxpr, [])
+               if sorted(out) == sorted((1, kv, g, qb, max(out)))
+               ) == 136 * qb
+
+
+def parent_causal_attention(q, k, v, q_block):
+    """`token_ops.causal_attention` as PR 32 left it (commit da118ef), kept
+    here word for word: what `window=None` has to trace."""
+    b, t, h, d = q.shape
+    kv = k.shape[2]
+    g = h // kv
+    scale = d ** -0.5
+    qb = token_ops._query_block(t, q_block)
+    qs = q.reshape(b, t // qb, qb, kv, g, d)
+
+    def block(qi, ki, vi):
+        s = jnp.einsum("bqkgd,bskd->bkgqs", qi, ki,
+                       preferred_element_type=jnp.float32) * scale
+        cols = jnp.arange(ki.shape[1])
+        rows = ki.shape[1] - qb + jnp.arange(qb)
+        s = jnp.where(rows[:, None] >= cols[None, :], s,
+                      jnp.finfo(jnp.float32).min)
+        p = jax.nn.softmax(s, axis=-1).astype(vi.dtype)
+        return jnp.einsum("bkgqs,bskd->bqkgd", p, vi)
+
+    if t == qb:
+        return block(qs[:, 0], k, v).reshape(b, t, h * v.shape[-1])
+    outs = []
+    for i, end in enumerate(range(qb, t + 1, qb)):
+        qi = qs[:, i]
+        if outs:
+            qi, outs[-1] = jax.lax.optimization_barrier((qi, outs[-1]))
+        outs.append(jax.checkpoint(block)(qi, k[:, :end], v[:, :end]))
+    return jnp.concatenate(outs, axis=1).reshape(b, t, h * v.shape[-1])
+
+
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+@pytest.mark.parametrize("backward", [False, True])
+@pytest.mark.parametrize("t,q_block", [(2048, 256), (64, 16), (40, 16)])
+def test_without_a_window_the_core_traces_what_the_parent_traced(
+        layout, backward, t, q_block):
+    h, kv, d, dv = LAYOUTS[layout]
+    q, k, v = (jax.ShapeDtypeStruct((2, t, n, w), jnp.bfloat16)
+               for n, w in ((h, d), (kv, d), (kv, dv)))
+
+    def trace(fn):
+        def scalar(q, k, v):
+            return jnp.sum(fn(q, k, v).astype(jnp.float32))
+        return str(jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1, 2))
+                                  if backward else scalar)(q, k, v))
+
+    want = trace(lambda *a: parent_causal_attention(*a, q_block))
+    assert trace(lambda *a: causal_attention(*a, q_block)) == want
+    assert trace(lambda *a: causal_attention(*a, q_block, None)) == want
+    if t > q_block and t % q_block == 0:
+        assert trace(lambda *a: causal_attention(*a, q_block, 32)) != want
+
+
+def test_the_window_model_counts_both_kinds_squares_at_build():
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+        swa_moe)
+    assert swa_moe.causal_attention is causal_attention
+    model = swa_moe.SwaMoE(spec=swa_moe.spec_from(
+        "laguna-xs.2", "0,1,2,3,4", 16, 0, 12544))
+    at_cell = model.build_counters(2 * 4096, 4096)
+    assert (at_cell["attn_window_squares_computed"],
+            at_cell["attn_squares_computed"], at_cell["attn_squares"]) == \
+        (45, 136, 256)
+    assert (at_cell["attn_window"], at_cell["attn_window_layers"],
+            at_cell["attn_full_layers"]) == (512, 3, 2)
+    # at the other token cells' 2048 the window would save 15 of 36
+    at_2048 = model.build_counters(4 * 2048, 2048)
+    assert (at_2048["attn_window_squares_computed"],
+            at_2048["attn_squares_computed"]) == (21, 36)

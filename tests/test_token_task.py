@@ -205,6 +205,41 @@ def test_folded_round_matches_stacked_round_on_the_mla_model():
         4 * 2 * 2 * T * 4 * 3
 
 
+def test_folded_round_matches_stacked_round_on_the_window_model():
+    """The window/full-attention model through both aggregation paths at a
+    tiny size (T 64 over a window of 8 keys): both layer kinds' leaves, the
+    output gates among them, are trained, folded and voted like any other."""
+    tiny = os.path.join(os.path.dirname(__file__), "data", "swa_tiny.json")
+    cfg = cfg_of(arch="swa_moe", lm_config=tiny, lm_layers="0,1,2,3,4")
+    fed = get_federated_data(cfg)
+    model = get_model(cfg.data, cfg.model_arch, "f32", remat=True, cfg=cfg)
+    params = init_params(model, (T,), jax.random.PRNGKey(0))
+    arrays = tuple(map(jnp.asarray, (fed.train.images, fed.train.labels,
+                                     fed.train.sizes)))
+    out = {}
+    for path in ("stack", "fold"):
+        fn = make_round_fn(cfg.replace(agg_path=path), model, None, *arrays)
+        out[path] = fn(params, jax.random.PRNGKey(5))
+    moved = jax.tree_util.tree_map(
+        lambda a, b: float(jnp.abs(a - b).max()), out["fold"][0], params)
+    for layer in ("layer_1", "layer_4"):        # a window and a full layer
+        assert all(moved[layer][k] > 0 for k in (
+            "q_proj", "k_proj", "v_proj", "g_proj", "o_proj", "gate",
+            "shared_w2")), (layer, moved[layer])
+    for a, b in zip(jax.tree_util.tree_leaves(out["stack"][0]),
+                    jax.tree_util.tree_leaves(out["fold"][0]), strict=True):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=0,
+                                   atol=2e-6)
+    s_info, f_info = out["stack"][1], out["fold"][1]
+    assert "mtp_loss" not in f_info
+    for k in ("train_loss",) + task.MOE_ROUND_KEYS:
+        np.testing.assert_allclose(float(s_info[k]), float(f_info[k]),
+                                   rtol=1e-6)
+    # 4 clients x 2 steps x 2 sequences x T tokens x 4 experts x 4 layers
+    assert float(f_info["moe_pairs_held"] + f_info["moe_pairs_absent"]) == \
+        4 * 2 * 2 * T * 4 * 4
+
+
 @pytest.fixture
 def every_pair_held(monkeypatch):
     """Steering for the sorted buffer's second pass, in the test alone: a

@@ -14,7 +14,7 @@ import jax.numpy as jnp
 import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe as lm, mla_moe as mm)
+    lfm2_moe as lm, mla_moe as mm, swa_moe as sm)
 
 TOKENS = 8192          # a client's step: 4 sequences of 2048
 # temporaries (bytes) of the cases that run `token_ops.causal_attention`,
@@ -213,3 +213,109 @@ def test_mla_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
     assert ma.temp_size_in_bytes <= \
         ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT["mla_step"], ma.temp_size_in_bytes
+
+
+def swa_spec():
+    return sm.spec_from("laguna-xs.2", "0,1,2,3,4", 16, 0, 12544)
+
+
+# temporaries (bytes) XLA's analysis read for these cases when PR 33 wrote
+# them, here, under the suite's settings (MiB: window attention 1228.3,
+# global attention 1831.8, the sparse layer 759.7, the whole step 1860.2):
+# recorded, and each held to ATTN_TEMP_ROOM times its number from here on
+SWA_TEMP_AT_PR33 = {"window_attention": 1_287_950_336,
+                    "global_attention": 1_920_769_024,
+                    "sparse_ffn": 796_617_216, "swa_step": 1_950_541_824}
+
+
+@pytest.mark.parametrize("what", ["window_attention", "global_attention",
+                                  "sparse_ffn"])
+def test_swa_layer_compiles_for_the_v5e_at_published_widths(one_chip,
+                                                            quiet_cache, what):
+    """Window attention (64 query heads, three key blocks a query block),
+    global attention (48 heads, partial rotary under YaRN) and the sparse
+    layer at its third shape (16 of 256 experts of width 512, top-8, with
+    the shared expert), each with its gradient, at the cell's 2 x 4096
+    tokens."""
+    spec = swa_spec()
+    d, f, e = spec.hidden, spec.moe_ffn, spec.experts_held
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if what == "sparse_ffn":
+        p = {"gate": (d, spec.n_experts), "experts_w1": (e, d, f),
+             "experts_w3": (e, d, f), "experts_w2": (e, f, d),
+             "shared_w1": (d, spec.shared_ffn),
+             "shared_w3": (d, spec.shared_ffn),
+             "shared_w2": (spec.shared_ffn, d)}
+        x = _aval((TOKENS, d), bf16, one_chip)
+
+        def fn(p, x):
+            return sm.sparse_ffn(p, x, spec, bf16)
+    else:
+        kind, h = ((sm.WINDOW, 64) if what == "window_attention"
+                   else (sm.FULL, 48))
+        hq, hkv = h * spec.head_dim, spec.kv_heads * spec.head_dim
+        p = {"q_proj": (d, hq), "k_proj": (d, hkv), "v_proj": (d, hkv),
+             "g_proj": (d, h), "o_proj": (hq, d)}
+        x = _aval((2, 4096, d), bf16, one_chip)
+
+        def fn(p, x):
+            return sm.attention(p, x, spec, kind, bf16), ()
+    p = {k: _aval(s, f32, one_chip) for k, s in p.items()}
+
+    def loss(p, x):
+        out, _aux = fn(p, x)
+        return jnp.sum(out.astype(f32))
+
+    compiled = jax.jit(jax.grad(loss)).lower(p, x).compile()
+    text = compiled.as_text()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert 0 < temp < 3 * 2 ** 30, temp
+    assert temp <= ATTN_TEMP_ROOM * SWA_TEMP_AT_PR33[what], temp
+    if what == "sparse_ffn":
+        # 16 of 256 held: 16384 of the step's 65536 sorted rows (two rows a
+        # token), the rest on a conditional that carries no buffer of its
+        # size
+        assert text.count("ragged-dot") >= 3
+        assert sm.dispatch_rows(spec, TOKENS) == 16384
+        assert " conditional(" in text
+        entry = text[text.index("\nENTRY "):]
+        assert f"[{TOKENS * spec.top_k},{f}]" not in entry
+    else:
+        # no product of the core spans the sequence: a window block reads
+        # 768 keys, and no score tensor has 4096 of them
+        assert "4096,4096]" not in text
+
+
+def test_swa_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
+        one_chip, quiet_cache):
+    """A client's whole step at the benchmark's shape (2 sequences of 4096):
+    the loss through `fl/task.make_batch_loss`, every block recomputed, and
+    its gradient, 490.3M parameters. The round holds four more trees of
+    this size beside it, so a step has about 5 GiB for its temporaries."""
+    import types
+
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl import (
+        task)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
+        abstract_params, param_count)
+    model = sm.SwaMoE(spec=swa_spec(), dtype=jnp.bfloat16, remat=True)
+    shapes = abstract_params(model, (4096,))
+    assert param_count(shapes) == 490_297_344
+    p = jax.tree_util.tree_map(
+        lambda a: _aval(a.shape, a.dtype, one_chip), shapes)
+    loss = task.make_batch_loss(model, types.SimpleNamespace(data="tokens"),
+                                None)
+    step = jax.jit(jax.value_and_grad(
+        lambda p, x, w: loss(p, x, None, w, None), has_aux=True))
+    compiled = step.lower(p, _aval((2, 4097), jnp.int32, one_chip),
+                          _aval((2,), jnp.bool_, one_chip)).compile()
+    text = compiled.as_text()
+    # four sparse blocks, each three grouped products forward, recomputed,
+    # and their transposes
+    assert text.count("ragged-dot") >= 4 * 3 * 3
+    assert text.count(" conditional(") >= 4
+    ma = compiled.memory_analysis()
+    assert ma.output_size_in_bytes >= 4 * 490_297_344
+    assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
+    assert ma.temp_size_in_bytes <= \
+        ATTN_TEMP_ROOM * SWA_TEMP_AT_PR33["swa_step"], ma.temp_size_in_bytes
