@@ -44,7 +44,7 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, attention_squares, causal_attention, dense_ffn,
+    _mm, _rms, attention_plan, causal_attention, dense_ffn,
     dispatch_rows, rope_half)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -248,12 +248,14 @@ class LFM2MoE(nn.Module):
     def build_counters(self, n_tokens: int, seq_len: int):
         """Counted once when an engine is built (obs/spans.py), for a step
         of `n_tokens` tokens in sequences of `seq_len`."""
-        computed, square = attention_squares(seq_len)
+        path, computed, square = attention_plan(seq_len)
         return {"experts_held": self.spec.experts_held,
                 "vocab_held": self.spec.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
                 "moe_rows_worst": n_tokens * self.spec.top_k,
-                "attn_squares_computed": computed, "attn_squares": square}
+                "attn_squares_computed": computed, "attn_squares": square,
+                "attn_path": {path: sum(kind != "conv" for _i, kind, _s
+                                        in self.spec.layers)}}
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False):

@@ -53,7 +53,7 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, attention_squares, causal_attention, dense_ffn,
+    _mm, _rms, attention_plan, causal_attention, dense_ffn,
     dispatch_rows, rope_half, shared_expert)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -327,9 +327,12 @@ class SwaMoE(nn.Module):
         """Counted once when an engine is built (obs/spans.py), for a step
         of `n_tokens` tokens in sequences of `seq_len`."""
         sp = self.spec
-        computed, square = attention_squares(seq_len)
-        in_window, _ = attention_squares(seq_len, window=sp.window)
+        path, computed, square = attention_plan(seq_len)
+        w_path, in_window, w_square = attention_plan(seq_len, sp.window)
         kinds = [kind for _i, kind, _h, _s in sp.layers]
+        paths = {}
+        for took, kind in ((path, FULL), (w_path, WINDOW)):
+            paths[took] = paths.get(took, 0) + kinds.count(kind)
         return {"experts_held": sp.experts_held,
                 "vocab_held": sp.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
@@ -337,6 +340,7 @@ class SwaMoE(nn.Module):
                 "attn_squares_computed": computed, "attn_squares": square,
                 "attn_window": sp.window,
                 "attn_window_squares_computed": in_window,
+                "attn_window_squares": w_square, "attn_path": paths,
                 "attn_window_layers": kinds.count(WINDOW),
                 "attn_full_layers": kinds.count(FULL),
                 "shared_experts": 1}

@@ -1,8 +1,8 @@
 """What the token models share (`lfm2_moe.py`, `mla_moe.py`, `swa_moe.py`):
 RMSNorm, the matrix product in the products' dtype, rotate-half rotary
-embedding, causal attention over query blocks (with a sliding window where
-a layer has one), the gated feed-forward, the shared expert, and ONE
-implementation of the sparse feed-forward: sigmoid routing over every
+embedding, causal attention (with a sliding window where a layer has one),
+the gated feed-forward, the shared expert, and ONE implementation of the
+sparse feed-forward: sigmoid routing over every
 published expert, the sort of the (token, expert) pairs by held expert,
 dispatch, the held experts' grouped products and combine.
 
@@ -22,7 +22,16 @@ expert, those held first, and the held experts' products are three
 twice the share of the pairs that the held experts draw in expectation and
 at least two rows a token; a step that holds more computes the rest in a
 second pass under a `jax.lax.cond`. Scopes: `moe_router`, `moe_experts`,
-`dense_ffn`, `shared_expert`."""
+`dense_ffn`, `shared_expert`.
+
+The attention core, `causal_attention`, is one function with two lowerings,
+chosen when a program is traced (`kernel_plan`). `plain_causal_attention`,
+in `jax.numpy` over query blocks, is the reference: what every program built
+for the CPU runs, what the TPU runs for a sequence of one block or of a
+length that 128 lanes do not divide, and what the kernel is tested against.
+A program built for the TPU runs every other sequence through the fused
+kernel of `attention_kernel.py`, forward and backward, where the float32
+scores stay in VMEM."""
 
 from __future__ import annotations
 
@@ -31,6 +40,9 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+    attention_kernel)
 
 ATTN_QUERY_BLOCK = 256     # attention runs over query blocks of this many
 # the sorted buffer of a sparse layer holds MOE_ROWS_OVER_EXPECTED times the
@@ -98,23 +110,31 @@ def _query_block(seq_len: int, q_block: int) -> int:
             else seq_len)
 
 
-def _first_key_block(i: int, qb: int, window) -> int:
+def _first_key_block(i: int, qb: int, window, kb=None) -> int:
     """The key block query block `i` starts reading at: the one that holds
     column `i * qb - window + 1`, the earliest key its first row reads
-    (block 0 without a window)."""
-    return 0 if window is None else max(0, (i * qb - window + 1) // qb)
+    (block 0 without a window). Key blocks are `kb` wide, the query
+    block's width unless given."""
+    return 0 if window is None else max(0, (i * qb - window + 1)
+                                        // (kb or qb))
 
 
 def attention_squares(seq_len: int, q_block: int = ATTN_QUERY_BLOCK,
-                      window=None):
+                      window=None, k_block=None):
     """(computed, square): how many (query block, key block) squares of
     scores `causal_attention` forms for a sequence of `seq_len`, those at
     or below the diagonal and, under a `window`, not wholly below the
-    band, and how many the square score matrix has."""
+    band, and how many the square score matrix has. The plain path's key
+    blocks are its query blocks; the kernel's have a width of their own,
+    `k_block` (`attention_kernel.plan`)."""
     qb = _query_block(seq_len, q_block)
-    nb = seq_len // qb
-    return (sum(i + 1 - _first_key_block(i, qb, window) for i in range(nb)),
-            nb * nb)
+    kb = k_block or qb
+    nq, nk = seq_len // qb, seq_len // kb
+    # query block i reads from its first key block to the one that holds
+    # its last row's own column
+    return (sum(((i + 1) * qb - 1) // kb + 1
+                - _first_key_block(i, qb, window, kb) for i in range(nq)),
+            nq * nk)
 
 
 def rope_half(x, inv_freq, scale: float = 1.0):
@@ -136,7 +156,8 @@ def rope_half(x, inv_freq, scale: float = 1.0):
     return out if r == d else jnp.concatenate([out, x[..., r:]], axis=-1)
 
 
-def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK, window=None):
+def plain_causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK,
+                           window=None):
     """Causal softmax attention over query blocks, in plain `jax.numpy`:
     q [B, T, H, d], k [B, T, KV, d] and v [B, T, KV, dv], H a multiple of
     KV; -> [B, T, H * dv]. Scores (scaled by d ** -0.5) and softmax are
@@ -184,6 +205,43 @@ def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK, window=None):
         outs.append(jax.checkpoint(block)(
             qi, k[:, start:end], v[:, start:end]))
     return jnp.concatenate(outs, axis=1).reshape(b, t, h * v.shape[-1])
+
+
+def kernel_plan(seq_len: int, q_block: int = ATTN_QUERY_BLOCK, window=None):
+    """The `attention_kernel.Plan` by which `causal_attention` runs the
+    fused kernel, or None where it takes the plain path: off the TPU, for
+    a sequence the plain path runs as one block (`seq_len <= q_block`), and
+    for a length the kernel's 128-lane blocks do not divide."""
+    if not attention_kernel.on_tpu() or seq_len <= q_block:
+        return None
+    return attention_kernel.plan(seq_len, window)
+
+
+def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK, window=None):
+    """Causal softmax attention, q [B, T, H, d], k [B, T, KV, d], v [B, T,
+    KV, dv] -> [B, T, H * dv], under a `window` of keys where a layer has
+    one: `plain_causal_attention`'s mathematics by one of two lowerings,
+    chosen when the program is traced, from the platform it is built for,
+    the sequence's length and the window (`kernel_plan`). On the TPU a
+    sequence of several whole kernel blocks runs the fused kernel, forward
+    and backward (`attention_kernel.attention`: the float32 scores never
+    leave VMEM); everywhere else, and for every other sequence on the TPU
+    too, the plain path over query blocks of `q_block`, which is also the
+    reference the kernel is tested against."""
+    took = kernel_plan(q.shape[1], q_block, window)
+    if took is None:
+        return plain_causal_attention(q, k, v, q_block, window)
+    return attention_kernel.attention(q, k, v, window, took)
+
+
+def attention_plan(seq_len: int, window=None):
+    """(path, computed, square) of `causal_attention` for a sequence of
+    `seq_len` in a program built in this process: "kernel" or "plain", and
+    that path's `attention_squares` at its own blocks."""
+    took = kernel_plan(seq_len, window=window)
+    if took is None:
+        return ("plain",) + attention_squares(seq_len, window=window)
+    return ("kernel",) + attention_squares(seq_len, took.q, window, took.k)
 
 
 def dispatch_rows(sp, n_tokens: int) -> int:

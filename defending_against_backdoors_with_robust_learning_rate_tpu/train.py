@@ -324,12 +324,16 @@ class RoundEngine:
             # experts and vocabulary rows it holds, the rows a sparse
             # layer's first pass takes of a step's sorted pairs, and the
             # squares of a sequence's scores its attention forms, by layer
-            # kind where some layers have a window)
+            # kind where some layers have a window, and the attention
+            # layers by the path their core takes: attn_path{path=})
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
+            attn_paths = built.pop("attn_path", {})
             for name, value in built.items():
                 tracer.count(name, value)
+            for path, layers in attn_paths.items():
+                tracer.count("attn_path", layers, path=path)
             # stack or fold, settled here from the stack's bytes and what
             # the device has free (compile_cache.resolved_agg), before a
             # parameter exists; from here on cfg carries the resolved
@@ -357,10 +361,14 @@ class RoundEngine:
                       f"formed"
                       + (f" in {built['attn_full_layers']} full-attention "
                          f"layer(s); {built['attn_window_squares_computed']}"
+                         f" of {built['attn_window_squares']}"
                          f" in {built['attn_window_layers']} layer(s) with "
                          f"a window of {built['attn_window']} keys, those "
                          f"below the band not formed either"
-                         if "attn_window" in built else ""))
+                         if "attn_window" in built else "")
+                      + "; path " + ", ".join(
+                          f"{path} in {layers} layer(s)"
+                          for path, layers in sorted(attn_paths.items())))
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

@@ -3,7 +3,18 @@ model test reaches (every toy sequence is shorter than `ATTN_QUERY_BLOCK`):
 a query block reads the keys at or before its last row and no others.
 Outputs and gradients against a full-matrix masked softmax written here, in
 float32, to the tolerance of a reordered sum; and, at the benchmark's
-sequence length, which products the traced function holds."""
+sequence length, which products the traced function holds.
+
+From PR 35 the function has a second lowering, the fused kernel of
+`models/attention_kernel.py`, taken where a program is traced for the TPU
+(`token_ops.kernel_plan`). Here on the CPU `causal_attention` IS the plain
+path, word for word what the parent traced; the kernel runs in Pallas's
+interpret mode against it and against the masked softmax written here, and
+`as_on_the_tpu` shows which sequences would take it. (Compiled for a
+described v5e at the published widths: `test_tpu_compile_lfm2.py`.)"""
+
+import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -11,9 +22,9 @@ import numpy as np
 import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe, mla_moe, token_ops)
+    attention_kernel, lfm2_moe, mla_moe, token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    attention_squares, causal_attention)
+    attention_squares, causal_attention, plain_causal_attention)
 
 # (H, KV, d, dv): MLA's layout (a key a head, values narrower than keys) and
 # LFM2's (four query heads a key-value head)
@@ -110,11 +121,30 @@ def test_a_length_the_block_does_not_divide_takes_one_block(layout):
     assert "checkpoint" not in text and "remat" not in text
 
 
-@pytest.mark.parametrize("seq_len,q_block,want", [
-    (2048, 512, (10, 16)), (2048, 256, (36, 64)), (64, 512, (1, 1)),
-    (512, 512, (1, 1)), (2000, 512, (1, 1)), (4096, 512, (36, 64))])
-def test_attention_squares(seq_len, q_block, want):
-    assert attention_squares(seq_len, q_block) == want
+@pytest.mark.parametrize("seq_len,q_block,k_block,want", [
+    (2048, 512, None, (10, 16)), (2048, 256, None, (36, 64)),
+    (64, 512, None, (1, 1)), (512, 512, None, (1, 1)),
+    (2000, 512, None, (1, 1)), (4096, 512, None, (36, 64)),
+    # the kernel's blocks (`attention_kernel.plan`): a key block of its
+    # own width; 3 of 4 and 10 of 16 are the cells' causal layers
+    (2048, 1024, 1024, (3, 4)), (4096, 1024, 1024, (10, 16)),
+    (2048, 512, 512, (10, 16)), (4096, 512, 512, (36, 64)),
+    (2048, 512, 256, (20, 32)), (2048, 256, 512, (20, 32)),
+    (512, 512, 128, (4, 4)), (256, 128, 128, (3, 4))])
+def test_attention_squares(seq_len, q_block, k_block, want):
+    assert attention_squares(seq_len, q_block, k_block=k_block) == want
+    assert want == _blocks_the_mask_touches(seq_len, q_block, None, k_block)
+
+
+def _blocks_the_mask_touches(seq_len, q_block, window, k_block):
+    """`attention_squares` by counting: the [T, T] mask cut into the
+    path's blocks, those that keep any score."""
+    qb = token_ops._query_block(seq_len, q_block)
+    kb = k_block or qb
+    r, c = np.arange(seq_len)[:, None], np.arange(seq_len)[None, :]
+    keep = (c <= r) if window is None else (c <= r) & (r - c < window)
+    tiles = keep.reshape(seq_len // qb, qb, seq_len // kb, kb).any((1, 3))
+    return int(tiles.sum()), tiles.size
 
 
 def _dot_shapes(jaxpr, found):
@@ -161,19 +191,51 @@ def test_no_product_spans_the_sequence_at_the_benchmarks_length(layout,
     assert sum(widths) * qb == each * computed * qb * qb
 
 
-@pytest.mark.parametrize("module,model", [
+MODELS = [
     (lfm2_moe, lambda: lfm2_moe.LFM2MoE(
-        spec=lfm2_moe.spec_from("lfm2-8b-a1b", "0,2,3,4,5", 8, 0, 16384))),
+        spec=lfm2_moe.spec_from("lfm2-8b-a1b", "0,2,3,4,5", 8, 0, 16384)),
+     1),
     (mla_moe, lambda: mla_moe.MlaMoE(
-        spec=mla_moe.spec_from("joyai-llm-flash", "0,1,2,3,4", 8, 0, 16160))),
-], ids=["lfm2_moe", "mla_moe"])
-def test_both_token_models_count_the_squares_at_build(module, model):
+        spec=mla_moe.spec_from("joyai-llm-flash", "0,1,2,3,4", 8, 0, 16160)),
+     6),
+]
+
+
+@pytest.fixture
+def as_on_the_tpu(monkeypatch):
+    """Programs traced in this test are built as the TPU's are: the
+    platform's name is all `token_ops.kernel_plan` asks of it."""
+    monkeypatch.setattr(attention_kernel, "on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("module,model,layers", MODELS,
+                         ids=["lfm2_moe", "mla_moe"])
+def test_both_token_models_count_the_squares_at_build(module, model, layers):
     assert module.causal_attention is causal_attention
     at_cell = model().build_counters(4 * 2048, 2048)
     assert (at_cell["attn_squares_computed"], at_cell["attn_squares"]) == \
         attention_squares(2048) == (36, 64)
+    # on the CPU every attention layer (LFM2's one of five; MLA's five and
+    # the MTP block's) takes the plain path
+    assert at_cell["attn_path"] == {"plain": layers}
     toy = model().build_counters(2 * 16, 16)
     assert (toy["attn_squares_computed"], toy["attn_squares"]) == (1, 1)
+    assert toy["attn_path"] == {"plain": layers}
+
+
+@pytest.mark.parametrize("module,model,layers", MODELS,
+                         ids=["lfm2_moe", "mla_moe"])
+def test_built_for_the_tpu_the_models_count_the_kernels_blocks(
+        module, model, layers, as_on_the_tpu):
+    """The kernel's path and the squares at the kernel's blocks (3 of 4 at
+    2048); a sequence the kernel does not take stays plain there too."""
+    at_cell = model().build_counters(4 * 2048, 2048)
+    assert (at_cell["attn_squares_computed"], at_cell["attn_squares"]) == \
+        attention_squares(2048, 1024, k_block=1024) == (3, 4)
+    assert at_cell["attn_path"] == {"kernel": layers}
+    toy = model().build_counters(2 * 16, 16)
+    assert (toy["attn_squares_computed"], toy["attn_squares"]) == (1, 1)
+    assert toy["attn_path"] == {"plain": layers}
 
 
 # ---- a sliding window in the same core (PR 33) ------------------------------
@@ -281,6 +343,19 @@ def test_window_blocks_batch_over_clients(layout):
     (64, 16, 40, (10, 16)), (64, 32, 40, (3, 4)), (40, 16, 24, (1, 1))])
 def test_attention_squares_under_a_window(seq_len, q_block, window, want):
     assert attention_squares(seq_len, q_block, window) == want
+    assert want == _blocks_the_mask_touches(seq_len, q_block, window, None)
+
+
+@pytest.mark.parametrize("seq_len,q_block,k_block,window,want", [
+    (4096, 512, 256, 512, (30, 128)), (4096, 256, 256, 512, (45, 256)),
+    (4096, 512, 512, 512, (15, 64)), (4096, 512, 128, 512, (60, 256)),
+    (4096, 128, 128, 512, (150, 1024)), (384, 128, 128, 200, (6, 9)),
+    (512, 128, 128, 1, (4, 16)), (512, 256, 128, 129, (5, 8))])
+def test_attention_squares_under_a_window_at_the_kernels_blocks(
+        seq_len, q_block, k_block, window, want):
+    assert attention_squares(seq_len, q_block, window, k_block) == want
+    assert want == _blocks_the_mask_touches(seq_len, q_block, window,
+                                            k_block)
 
 
 @pytest.mark.parametrize("backward", [False, True])
@@ -382,7 +457,201 @@ def test_the_window_model_counts_both_kinds_squares_at_build():
         (45, 136, 256)
     assert (at_cell["attn_window"], at_cell["attn_window_layers"],
             at_cell["attn_full_layers"]) == (512, 3, 2)
+    assert at_cell["attn_window_squares"] == 256
+    assert at_cell["attn_path"] == {"plain": 5}
     # at the other token cells' 2048 the window would save 15 of 36
     at_2048 = model.build_counters(4 * 2048, 2048)
     assert (at_2048["attn_window_squares_computed"],
             at_2048["attn_squares_computed"]) == (21, 36)
+
+
+def test_built_for_the_tpu_the_window_model_counts_the_kernels_blocks(
+        as_on_the_tpu):
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
+        swa_moe)
+    model = swa_moe.SwaMoE(spec=swa_moe.spec_from(
+        "laguna-xs.2", "0,1,2,3,4", 16, 0, 12544))
+    at_cell = model.build_counters(2 * 4096, 4096)
+    full, band = attention_kernel.plan(4096), attention_kernel.plan(4096, 512)
+    assert (at_cell["attn_squares_computed"], at_cell["attn_squares"]) == \
+        attention_squares(4096, full.q, k_block=full.k) == (10, 16)
+    assert (at_cell["attn_window_squares_computed"],
+            at_cell["attn_window_squares"]) == \
+        attention_squares(4096, band.q, 512, band.k) == (15, 64)
+    assert at_cell["attn_path"] == {"kernel": 5}
+    toy = model.build_counters(2 * 32, 32)
+    assert toy["attn_path"] == {"plain": 5}
+
+
+# ---- the fused kernel, the TPU's lowering of the same core (PR 35) ----------
+# (H, KV, d, dv, T, window, (query block, key block) where they are not the
+# module's own, wrapper): the cells' head layouts at fewer heads, in
+# Pallas's interpret mode (the backward fused under a causal mask, two
+# kernels under a window: `attention_kernel.plan`)
+KERNEL_CASES = {
+    # MLA: a key a head, 192 wide with ONE rotary part broadcast to every
+    # head, values of 128
+    "mla_causal": (2, 2, 192, 128, 256, None, (128, 128), None),
+    "mla_causal_checkpoint": (2, 2, 192, 128, 256, None, (128, 128),
+                              "checkpoint"),
+    # LFM2: 32 / 8 heads of 64
+    "lfm2_causal": (4, 1, 64, 64, 384, None, None, None),
+    "lfm2_one_kernel_block": (4, 1, 64, 64, 512, None, None, None),
+    "lfm2_window_one_key": (4, 1, 64, 64, 256, 1, (128, 128), None),
+    # Laguna: 48 (full layers) and 64 (window layers) over 8 of 128
+    "laguna_causal": (6, 1, 128, 128, 256, None, (128, 128), None),
+    "laguna_window_one_block": (8, 1, 128, 128, 384, 128, None, None),
+    "laguna_window_undivided": (8, 1, 128, 128, 384, 200, None, None),
+    "laguna_window_wide_q": (8, 2, 128, 128, 512, 130, (256, 128), None),
+    "laguna_window_vmap": (8, 2, 128, 128, 256, 72, (128, 128), "vmap"),
+}
+
+
+def _kernel_qkv(case, lead=(1,), seed=0):
+    h, kv, d, dv, t = KERNEL_CASES[case][:5]
+    kq, kk, kp, kv_ = jax.random.split(jax.random.PRNGKey(seed), 4)
+    k = jax.random.normal(kk, lead + (t, kv, d), jnp.float32)
+    if case.startswith("mla"):
+        # k = [k_nope | k_pe], the last 64 widths one vector for all heads
+        k_pe = jax.random.normal(kp, lead + (t, 1, 64), jnp.float32)
+        k = jnp.concatenate(
+            [k[..., :d - 64], jnp.broadcast_to(k_pe, lead + (t, kv, 64))],
+            axis=-1)
+    return (jax.random.normal(kq, lead + (t, h, d), jnp.float32), k,
+            jax.random.normal(kv_, lead + (t, kv, dv), jnp.float32))
+
+
+def _kernel_fns(case):
+    """(the kernel in interpret mode, the plain path, the masked softmax)
+    for a case, wrapped as the case says."""
+    _h, _kv, _d, _dv, t, window, blocks, wrapper = KERNEL_CASES[case]
+    took = attention_kernel.plan(t, window)
+    if blocks:
+        took = took._replace(q=blocks[0], k=blocks[1], compute=blocks[1])
+    assert t % took.q == 0 and t % took.k == 0
+    fns = [functools.partial(attention_kernel.attention, window=window,
+                             took=took, interpret=True),
+           functools.partial(plain_causal_attention, q_block=128,
+                             window=window),
+           full_matrix_attention if window is None else
+           functools.partial(banded_matrix_attention, window=window)]
+    if wrapper == "vmap":      # clients, each a batch of sequences
+        fns = [jax.vmap(fn) for fn in fns]
+    elif wrapper == "checkpoint":
+        fns[0] = jax.checkpoint(fns[0])
+    return fns
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_CASES))
+def test_the_kernel_matches_the_plain_path_and_a_masked_softmax(case):
+    """Outputs and q / k / v gradients, in float32, to the tolerance of a
+    reordered sum (an online softmax reorders; it leaves nothing out)."""
+    h, _kv, _d, dv, t, window, _blocks, wrapper = KERNEL_CASES[case]
+    lead = (2, 1) if wrapper == "vmap" else (1,)
+    q, k, v = _kernel_qkv(case, lead)
+    w = jax.random.normal(jax.random.PRNGKey(7), lead + (t, h * dv))
+
+    def scalar(fn):
+        def with_output(q, k, v):
+            o = fn(q, k, v)
+            return jnp.sum(o * w), o
+        return jax.value_and_grad(with_output, argnums=(0, 1, 2),
+                                  has_aux=True)
+
+    kernel, plain, matrix = _kernel_fns(case)
+    (_, got), grads = scalar(kernel)(q, k, v)
+    for other in (plain, matrix):
+        (_, want), wants = scalar(other)(q, k, v)
+        np.testing.assert_allclose(got, want, rtol=2e-5, atol=5e-6)
+        for g, wg in zip(grads, wants):
+            np.testing.assert_allclose(g, wg, rtol=2e-4, atol=5e-5)
+    if window is not None:
+        # the window is felt: the causal result is another
+        causal = jax.vmap(full_matrix_attention) if wrapper == "vmap" \
+            else full_matrix_attention
+        assert float(jnp.abs(want - causal(q, k, v)).max()) > 1e-3
+
+
+@pytest.mark.parametrize("case", ["lfm2_causal", "laguna_window_undivided"])
+def test_in_the_kernel_a_key_outside_a_rows_band_leaves_the_row_bit_equal(
+        case):
+    """A key and value moved at one position: the rows before it and, under
+    a window, the rows whose band has passed it, come out bit for bit."""
+    t, window = KERNEL_CASES[case][4:6]
+    at = 150
+    kernel = _kernel_fns(case)[0]
+    q, k, v = _kernel_qkv(case, seed=1)
+    base = kernel(q, k, v)
+    moved = kernel(q, k.at[:, at].add(3.0), v.at[:, at].add(-2.0))
+    reads = np.r_[at:t if window is None else at + window]
+    outside = np.setdiff1d(np.arange(t), reads)
+    np.testing.assert_array_equal(np.asarray(base[:, outside]),
+                                  np.asarray(moved[:, outside]))
+    assert (np.asarray(base[:, reads]) != np.asarray(moved[:, reads])) \
+        .any(axis=-1).all()
+
+
+@pytest.mark.parametrize("t,window,want", [
+    (2048, None, (1024, 1024, 512, "fused")),
+    (4096, None, (1024, 1024, 512, "fused")),
+    (256, None, (256, 256, 256, "fused")),
+    (384, None, (128, 128, 128, "fused")),
+    (1280, None, (256, 256, 256, "fused")),
+    # under a window: two backward kernels, a key block no wider than
+    # the band
+    (4096, 512, (512, 512, 512, "apart")),
+    (4096, 256, (512, 256, 256, "apart")),
+    (2048, 100, (512, 128, 128, "apart")),
+    (4096, 2048, (512, 512, 512, "apart")),
+    (2048, 300, (512, 256, 256, "apart")),
+    (256, 512, (256, 256, 256, "apart")),
+    (384, 200, (128, 128, 128, "apart")),
+    (16, None, None), (32, 8, None), (2000, None, None), (200, 64, None)])
+def test_the_kernels_plan_follows_the_sequence_and_the_mask(t, window, want):
+    assert attention_kernel.plan(t, window) == want
+
+
+def _traced(t, window, q_block=token_ops.ATTN_QUERY_BLOCK, fn=None):
+    q, k, v = (jax.ShapeDtypeStruct((1, t, n, 64), jnp.bfloat16)
+               for n in (4, 2, 2))
+    fn = fn or causal_attention
+
+    def scalar(q, k, v):
+        return jnp.sum(fn(q, k, v, q_block, window).astype(jnp.float32))
+
+    return str(jax.make_jaxpr(jax.grad(scalar, argnums=(0, 1, 2)))(q, k, v))
+
+
+@pytest.mark.parametrize("t,window", [
+    (2048, None), (4096, 512), (384, None), (384, 200)])
+def test_the_kernel_is_taken_only_where_a_program_is_traced_for_the_tpu(
+        t, window, monkeypatch):
+    """Here `causal_attention` traces the plain path and nothing of the
+    kernel; traced as on the TPU, the kernel's forward and backward calls
+    and no score tensor."""
+    plain = _traced(t, window, fn=plain_causal_attention)
+    assert token_ops.kernel_plan(t, window=window) is None
+    assert _traced(t, window) == plain and "pallas_call" not in plain
+    monkeypatch.setattr(attention_kernel, "on_tpu", lambda: True)
+    took = token_ops.kernel_plan(t, window=window)
+    assert took == attention_kernel.plan(t, window)
+    kernel = _traced(t, window)
+    # the forward kernel, and dq with dkv in one kernel or in two
+    assert kernel.count("pallas_call") == {"fused": 2, "apart": 3}[
+        took.backward]
+    # float32 scores [B, KV, g, query rows, keys]
+    scores = re.compile(r"f32\[1,2,2,\d+,\d+\]")
+    assert scores.search(plain) and not scores.search(kernel)
+
+
+@pytest.mark.parametrize("t,window,q_block", [
+    (200, None, 256), (2000, None, 256), (16, None, 256), (48, 8, 16),
+    (600, 64, 200), (256, None, 256), (128, 64, 256)])
+def test_what_the_kernel_does_not_serve_stays_plain_on_the_tpu_too(
+        t, window, q_block, as_on_the_tpu):
+    """A length the 128-lane blocks do not divide, and a sequence the plain
+    path runs as one block: the function is the plain path's to the letter
+    on every platform."""
+    assert token_ops.kernel_plan(t, q_block, window) is None
+    assert _traced(t, window, q_block) == \
+        _traced(t, window, q_block, fn=plain_causal_attention)

@@ -315,7 +315,8 @@ def test_published_file_is_the_catalog_row_and_the_cut_counts_491_7m():
     assert model.build_counters(8192, 2048) == {
         "experts_held": 8, "vocab_held": 16160, "moe_rows": 16384,
         "moe_rows_worst": 65536, "attn_squares_computed": 36,
-        "attn_squares": 64, "mtp_depth": 1, "shared_experts": 1}
+        "attn_squares": 64, "attn_path": {"plain": 6}, "mtp_depth": 1,
+        "shared_experts": 1}
     whole = mm.spec_from("joyai-llm-flash", "", 0, 0, 0)
     full = param_count(abstract_params(
         mm.MlaMoE(spec=whole, dtype=jnp.bfloat16), (2048,)))

@@ -404,7 +404,8 @@ def test_published_file_is_the_catalog_row_and_the_cut_counts_490_3m():
         "experts_held": 16, "vocab_held": 12544, "moe_rows": 16384,
         "moe_rows_worst": 65536, "attn_squares_computed": 136,
         "attn_squares": 256, "attn_window": 512,
-        "attn_window_squares_computed": 45, "attn_window_layers": 3,
+        "attn_window_squares_computed": 45, "attn_window_squares": 256,
+        "attn_path": {"plain": 5}, "attn_window_layers": 3,
         "attn_full_layers": 2, "shared_experts": 1}
     whole = sm.spec_from("laguna-xs.2", "", 0, 0, 0)
     full = param_count(abstract_params(

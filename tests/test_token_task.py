@@ -319,9 +319,13 @@ def test_engine_counts_the_buffers_rows_and_its_overflow(
     assert counted["moe_overflow_steps"] == (4 if overflow else 0)
     # sequences of 16: one block, its one square
     assert counted["attn_squares_computed"] == counted["attn_squares"] == 1
+    # layer 2 of (1, 2) is the attention layer, and here its core is plain
+    assert [(n, labels) for name, n, labels in eng.tracer.counted()
+            if name == "attn_path"] == [(1, {"path": "plain"})]
     out = capsys.readouterr().out
     assert f"[model] moe rows {rows} of {pairs}" in out
     assert "[model] attention squares 1 of 1" in out
+    assert "; path plain in 1 layer(s)" in out
     run_dirs = [d for d in tmp_path.iterdir() if d.is_dir()]
     with open(run_dirs[0] / "metrics.jsonl") as fh:
         written = [json.loads(line) for line in fh]

@@ -5,16 +5,24 @@ product, and the step's temporaries fit). Nothing runs, so nothing here is a
 time or a result. All of it, for both token models, lives in this one file:
 only the worker that is given the file loads the TPU's library, inside the
 fixture (a second such file could go to another worker, whose fixture would
-then skip every test in silence)."""
+then skip every test in silence).
+
+Traced for the TPU (the `one_chip` fixture says so to
+`attention_kernel.on_tpu`, as the chip's own process would),
+`token_ops.causal_attention` is the fused kernel of
+`models/attention_kernel.py` (PR 35): every case that runs it holds the
+kernel's custom calls and no float32 score tensor."""
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    lfm2_moe as lm, mla_moe as mm, swa_moe as sm)
+    attention_kernel, lfm2_moe as lm, mla_moe as mm, swa_moe as sm,
+    token_ops)
 
 TOKENS = 8192          # a client's step: 4 sequences of 2048
 # temporaries (bytes) of the cases that run `token_ops.causal_attention`,
@@ -40,7 +48,11 @@ def one_chip():
                                             topology_name="v5e:2x2")
     except Exception as e:    # noqa: BLE001 - any failure to describe skips
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    # what is traced here is built for the described chip
+    was = attention_kernel.on_tpu
+    attention_kernel.on_tpu = lambda: True
+    yield SingleDeviceSharding(topo.devices[0])
+    attention_kernel.on_tpu = was
 
 
 @pytest.fixture(scope="module")
@@ -58,6 +70,36 @@ def quiet_cache():
 
 def _aval(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_the_core_is_the_kernel(text, seq_len, causal=0, window=0):
+    """The compiled program runs the attention core of `causal` + `window`
+    layers as the splash kernel: the custom calls of forward and backward
+    are in the text (a causal layer's backward is one kernel that makes dq
+    with dk and dv, a window layer's two: `attention_kernel.plan`), and
+    ENTRY holds no float32 tensor of the plain path's scores, [B, KV, g,
+    query block, a whole number of query blocks] in whatever order the
+    compiler keeps the axes (the plain path compiled the same way holds
+    eight to sixteen of them a layer)."""
+    assert attention_kernel.plan(seq_len).backward == attention_kernel.FUSED
+    assert attention_kernel.plan(seq_len, 512).backward == \
+        attention_kernel.APART
+    for call, layers in (("splash_mha_fwd", causal + window),
+                         ("splash_mha_dkv", causal + window),
+                         ("splash_mha_dq", window)):
+        calls = re.findall(rf'op_name="[^"]*/{call}[^"]*/pallas_call', text)
+        assert len(calls) >= layers and bool(calls) == bool(layers), \
+            (call, len(calls))
+    qb = token_ops.ATTN_QUERY_BLOCK
+    entry = text[text.index("\nENTRY "):]
+    scores = []
+    for dims in re.findall(r"f32\[([\d,]+)\]", entry):
+        dims = [int(d) for d in dims.split(",")]
+        if len(dims) == 5 and qb in dims:
+            dims.remove(qb)
+            if any(d % qb == 0 and d <= seq_len for d in dims):
+                scores.append(dims)
+    assert not scores, scores[:4]
 
 
 @pytest.mark.parametrize("what", ["sparse_ffn", "short_conv", "attention"])
@@ -110,6 +152,7 @@ def test_layer_compiles_for_the_v5e_at_published_widths(one_chip,
     assert 0 < temp < 3 * 2 ** 30, temp
     if what in ATTN_TEMP_AT_PARENT:
         assert temp <= ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT[what], temp
+        _assert_the_core_is_the_kernel(text, 2048, causal=1)
     if what == "sparse_ffn":
         # 8 of 32 experts held: the first pass sorts into 16384 rows of the
         # 32768 pairs, and the rest hangs on a conditional that carries no
@@ -166,6 +209,7 @@ def test_mla_layer_compiles_for_the_v5e_at_published_widths(one_chip,
     assert 0 < temp < 3 * 2 ** 30, temp
     if what in ATTN_TEMP_AT_PARENT:
         assert temp <= ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT[what], temp
+        _assert_the_core_is_the_kernel(text, 2048, causal=1)
     if what == "sparse_ffn":
         # 8 of 256 experts held: 16384 of the step's 65536 sorted rows (two
         # rows a token), the rest on a conditional that carries no buffer of its
@@ -213,6 +257,8 @@ def test_mla_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
     assert ma.temp_size_in_bytes <= \
         ATTN_TEMP_ROOM * ATTN_TEMP_AT_PARENT["mla_step"], ma.temp_size_in_bytes
+    # five blocks and the MTP module's
+    _assert_the_core_is_the_kernel(text, 2048, causal=6)
 
 
 def swa_spec():
@@ -281,9 +327,11 @@ def test_swa_layer_compiles_for_the_v5e_at_published_widths(one_chip,
         entry = text[text.index("\nENTRY "):]
         assert f"[{TOKENS * spec.top_k},{f}]" not in entry
     else:
-        # no product of the core spans the sequence: a window block reads
-        # 768 keys, and no score tensor has 4096 of them
+        # no product of the core spans the sequence, and no score tensor
+        # is left at all
         assert "4096,4096]" not in text
+        _assert_the_core_is_the_kernel(
+            text, 4096, **{"window" if kind == sm.WINDOW else "causal": 1})
 
 
 def test_swa_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
@@ -319,3 +367,4 @@ def test_swa_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     assert 0 < ma.temp_size_in_bytes < 4 * 2 ** 30, ma.temp_size_in_bytes
     assert ma.temp_size_in_bytes <= \
         ATTN_TEMP_ROOM * SWA_TEMP_AT_PR33["swa_step"], ma.temp_size_in_bytes
+    _assert_the_core_is_the_kernel(text, 4096, causal=2, window=3)
