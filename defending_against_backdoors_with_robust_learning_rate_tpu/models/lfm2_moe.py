@@ -25,7 +25,8 @@ vocabulary. Equations as the source's `lfm2_moe` implementation has them:
 Precision (`--dtype bf16`): parameters stay float32; matrix products take
 bfloat16 operands; router logits, sigmoid, softmax, norms and the logits
 are float32. Named scopes tell the parts apart in a device trace:
-`short_conv`, `attention`, `moe_router`, `moe_experts`, `dense_ffn`,
+`short_conv`, `attention` (inside it `attn_rope` around the rotary step and
+the shared core's `attn_core`), `moe_router`, `moe_experts`, `dense_ffn`,
 `lm_head`."""
 
 from __future__ import annotations
@@ -44,7 +45,7 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, attention_plan, causal_attention, dense_ffn,
+    _mm, _rms, attention_plan, causal_attention, core_flops, dense_ffn,
     dispatch_rows, rope_half)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -143,10 +144,12 @@ def sparse_ffn(p, x, sp: LMSpec, src_layer: int, dtype):
 
 
 def _rope(x, theta):
-    """Rotate-half rotary embedding over the whole head; x [B, T, n, d]."""
-    d = x.shape[-1]
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    return rope_half(x, inv)
+    """Rotate-half rotary embedding over the whole head, under the scope
+    `attn_rope`; x [B, T, n, d]."""
+    with jax.named_scope("attn_rope"):
+        d = x.shape[-1]
+        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        return rope_half(x, inv)
 
 
 def short_conv(p, x, sp: LMSpec, dtype):
@@ -248,14 +251,18 @@ class LFM2MoE(nn.Module):
     def build_counters(self, n_tokens: int, seq_len: int):
         """Counted once when an engine is built (obs/spans.py), for a step
         of `n_tokens` tokens in sequences of `seq_len`."""
+        sp = self.spec
         path, computed, square = attention_plan(seq_len)
+        layers = sum(kind != "conv" for _i, kind, _s in sp.layers)
         return {"experts_held": self.spec.experts_held,
                 "vocab_held": self.spec.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
                 "moe_rows_worst": n_tokens * self.spec.top_k,
                 "attn_squares_computed": computed, "attn_squares": square,
-                "attn_path": {path: sum(kind != "conv" for _i, kind, _s
-                                        in self.spec.layers)}}
+                "attn_path": {path: layers},
+                "attn_core_flops": {"full": layers * core_flops(
+                    n_tokens, seq_len, sp.heads, sp.head_dim,
+                    sp.head_dim)}}
 
     @nn.compact
     def __call__(self, tokens, *, train: bool = False):

@@ -35,8 +35,10 @@ family's public implementation give them (what the file lacks is under its
 
 Precision (`--dtype bf16`): parameters stay float32; matrix products take
 bfloat16 operands; router, softmax, norms, rotary and logits are float32.
-Scopes: `mla_attention`, `shared_expert`, `mtp` (around a whole module),
-and the shared code's `moe_router`, `moe_experts`, `dense_ffn`; `lm_head`."""
+Scopes: `mla_attention` (inside it `attn_rope` around the rotary pairs and
+the assembly of q and k, and the shared core's `attn_core`),
+`shared_expert`, `mtp` (around a whole module), and the shared code's
+`moe_router`, `moe_experts`, `dense_ffn`; `lm_head`."""
 
 from __future__ import annotations
 
@@ -54,7 +56,7 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, attention_plan, causal_attention, dense_ffn,
+    _mm, _rms, attention_plan, causal_attention, core_flops, dense_ffn,
     dispatch_rows, shared_expert)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -197,12 +199,14 @@ def mla_attention(p, x, sp: MlaSpec, dtype):
         down = _mm(x, p["kv_a_proj"], dtype)
         ckv = _rms(down[..., :sp.kv_rank], p["kv_a_norm"], sp.norm_eps)
         kv = _mm(ckv, p["kv_b_proj"], dtype).reshape(b, t, h, dn + dv)
-        q_pe = _rope_pairs(q[..., dn:], sp.rope_theta).astype(dtype)
-        k_pe = _rope_pairs(down[:, :, None, sp.kv_rank:],
-                           sp.rope_theta).astype(dtype)
-        q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
-        k = jnp.concatenate(
-            [kv[..., :dn], jnp.broadcast_to(k_pe, (b, t, h, dr))], axis=-1)
+        with jax.named_scope("attn_rope"):
+            q_pe = _rope_pairs(q[..., dn:], sp.rope_theta).astype(dtype)
+            k_pe = _rope_pairs(down[:, :, None, sp.kv_rank:],
+                               sp.rope_theta).astype(dtype)
+            q = jnp.concatenate([q[..., :dn], q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_pe, (b, t, h, dr))],
+                axis=-1)
         o = causal_attention(q, k, kv[..., dn:])
         return _mm(o, p["o_proj"], dtype)
 
@@ -322,7 +326,11 @@ class MlaMoE(nn.Module):
     def build_counters(self, n_tokens: int, seq_len: int):
         """Counted once when an engine is built (obs/spans.py), for a step
         of `n_tokens` tokens in sequences of `seq_len`."""
+        sp = self.spec
         path, computed, square = attention_plan(seq_len)
+        # every block's core, the MTP modules' over all T positions too
+        flops = (len(sp.layers) + sp.mtp_depth) * core_flops(
+            n_tokens, seq_len, sp.heads, sp.nope_dim + sp.rope_dim, sp.v_dim)
         return {"experts_held": self.spec.experts_held,
                 "vocab_held": self.spec.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
@@ -330,6 +338,7 @@ class MlaMoE(nn.Module):
                 "attn_squares_computed": computed, "attn_squares": square,
                 "attn_path": {path: len(self.spec.layers)
                               + self.spec.mtp_depth},
+                "attn_core_flops": {"full": flops},
                 "mtp_depth": self.spec.mtp_depth,
                 "shared_experts": self.spec.shared_ffn // self.spec.moe_ffn}
 

@@ -33,7 +33,8 @@ Precision (`--dtype bf16`): parameters stay float32; matrix products take
 bfloat16 operands; router, softmax, norms, rotary, the gate's sigmoid and
 logits are float32. Scopes: `window_attention` and `global_attention` (the
 two layer kinds, each around projections, rotary, core, gate and output
-product), and the shared code's `moe_router`, `moe_experts`,
+product; inside them `attn_rope` around the rotary step and the shared
+core's `attn_core`), and the shared code's `moe_router`, `moe_experts`,
 `shared_expert`, `dense_ffn`; `lm_head`."""
 
 from __future__ import annotations
@@ -53,7 +54,7 @@ import numpy as np
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
     token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
-    _mm, _rms, attention_plan, causal_attention, dense_ffn,
+    _mm, _rms, attention_plan, causal_attention, core_flops, dense_ffn,
     dispatch_rows, rope_half, shared_expert)
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -244,8 +245,10 @@ def attention(p, x, sp: SwaSpec, kind: str, dtype):
         q = _mm(x, p["q_proj"], dtype).reshape(b, t, h, hd)
         k = _mm(x, p["k_proj"], dtype).reshape(b, t, kv, hd)
         v = _mm(x, p["v_proj"], dtype).reshape(b, t, kv, hd)
-        o = causal_attention(rope(q, r).astype(dtype),
-                             rope(k, r).astype(dtype), v,
+        with jax.named_scope("attn_rope"):
+            q = rope(q, r).astype(dtype)
+            k = rope(k, r).astype(dtype)
+        o = causal_attention(q, k, v,
                              window=sp.window if kind == WINDOW else None)
         gate = jax.nn.sigmoid(
             _mm(x, p["g_proj"], dtype).astype(jnp.float32))     # [B, T, H]
@@ -333,6 +336,12 @@ class SwaMoE(nn.Module):
         paths = {}
         for took, kind in ((path, FULL), (w_path, WINDOW)):
             paths[took] = paths.get(took, 0) + kinds.count(kind)
+        flops = {}
+        for _i, kind, heads, _s in sp.layers:
+            label = "window" if kind == WINDOW else "full"
+            flops[label] = flops.get(label, 0.0) + core_flops(
+                n_tokens, seq_len, heads, sp.head_dim, sp.head_dim,
+                sp.window if kind == WINDOW else None)
         return {"experts_held": sp.experts_held,
                 "vocab_held": sp.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
@@ -341,6 +350,7 @@ class SwaMoE(nn.Module):
                 "attn_window": sp.window,
                 "attn_window_squares_computed": in_window,
                 "attn_window_squares": w_square, "attn_path": paths,
+                "attn_core_flops": flops,
                 "attn_window_layers": kinds.count(WINDOW),
                 "attn_full_layers": kinds.count(FULL),
                 "shared_experts": 1}

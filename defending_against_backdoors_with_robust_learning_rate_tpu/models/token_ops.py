@@ -31,7 +31,9 @@ for the CPU runs, what the TPU runs for a sequence of one block or of a
 length that 128 lanes do not divide, and what the kernel is tested against.
 A program built for the TPU runs every other sequence through the fused
 kernel of `attention_kernel.py`, forward and backward, where the float32
-scores stay in VMEM."""
+scores stay in VMEM. Both run under the scope `attn_core`; each model opens
+`attn_rope` around its rotary step, and `core_flops` counts what the core
+multiplies."""
 
 from __future__ import annotations
 
@@ -227,11 +229,14 @@ def causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK, window=None):
     and backward (`attention_kernel.attention`: the float32 scores never
     leave VMEM); everywhere else, and for every other sequence on the TPU
     too, the plain path over query blocks of `q_block`, which is also the
-    reference the kernel is tested against."""
+    reference the kernel is tested against. Either lowering, the kernel's
+    scaling of q and its head-major transposes included, runs under the
+    scope `attn_core` (forward, recompute and backward)."""
     took = kernel_plan(q.shape[1], q_block, window)
-    if took is None:
-        return plain_causal_attention(q, k, v, q_block, window)
-    return attention_kernel.attention(q, k, v, window, took)
+    with jax.named_scope("attn_core"):
+        if took is None:
+            return plain_causal_attention(q, k, v, q_block, window)
+        return attention_kernel.attention(q, k, v, window, took)
 
 
 def attention_plan(seq_len: int, window=None):
@@ -242,6 +247,17 @@ def attention_plan(seq_len: int, window=None):
     if took is None:
         return ("plain",) + attention_squares(seq_len, window=window)
     return ("kernel",) + attention_squares(seq_len, took.q, window, took.k)
+
+
+def core_flops(n_tokens: int, seq_len: int, heads: int, qk_dim: int,
+               v_dim: int, window=None) -> float:
+    """Forward operations (2 a multiply-add) of one layer's `q k^T` and `p v`
+    over the keys the mask keeps, for `n_tokens` tokens in sequences of
+    `seq_len`: query r reads `min(r + 1, window)` keys (`r + 1` without a
+    window, the causal half), whatever squares either lowering forms."""
+    w = min(window or seq_len, seq_len)
+    keys = w * (w + 1) // 2 + (seq_len - w) * w      # over a sequence
+    return 2.0 * heads * (qk_dim + v_dim) * keys * n_tokens / seq_len
 
 
 def dispatch_rows(sp, n_tokens: int) -> int:

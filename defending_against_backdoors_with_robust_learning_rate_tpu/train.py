@@ -324,16 +324,21 @@ class RoundEngine:
             # experts and vocabulary rows it holds, the rows a sparse
             # layer's first pass takes of a step's sorted pairs, and the
             # squares of a sequence's scores its attention forms, by layer
-            # kind where some layers have a window, and the attention
-            # layers by the path their core takes: attn_path{path=})
+            # kind where some layers have a window, the attention layers
+            # by the path their core takes: attn_path{path=}, and the
+            # forward operations of a step's cores by layer kind:
+            # attn_core_flops{kind=})
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
             attn_paths = built.pop("attn_path", {})
+            attn_flops = built.pop("attn_core_flops", {})
             for name, value in built.items():
                 tracer.count(name, value)
             for path, layers in attn_paths.items():
                 tracer.count("attn_path", layers, path=path)
+            for kind, flops in attn_flops.items():
+                tracer.count("attn_core_flops", flops, kind=kind)
             # stack or fold, settled here from the stack's bytes and what
             # the device has free (compile_cache.resolved_agg), before a
             # parameter exists; from here on cfg carries the resolved

@@ -406,7 +406,12 @@ def test_published_file_is_the_catalog_row_and_the_cut_counts_490_3m():
         "attn_squares": 256, "attn_window": 512,
         "attn_window_squares_computed": 45, "attn_window_squares": 256,
         "attn_path": {"plain": 5}, "attn_window_layers": 3,
-        "attn_full_layers": 2, "shared_experts": 1}
+        "attn_full_layers": 2, "shared_experts": 1,
+        # q k^T and p v of a step's two sequences: two full layers of 48
+        # heads on the causal half, three of 64 on the band of 512
+        "attn_core_flops": {
+            "full": 2 * 2 * 48 * 256 * (4096 * 4097 // 2) * 2,
+            "window": 3 * 2 * 64 * 256 * (512 * 513 // 2 + 3584 * 512) * 2}}
     whole = sm.spec_from("laguna-xs.2", "", 0, 0, 0)
     full = param_count(abstract_params(
         sm.SwaMoE(spec=whole, dtype=jnp.bfloat16), (4096,)))

@@ -322,6 +322,11 @@ def test_engine_counts_the_buffers_rows_and_its_overflow(
     # layer 2 of (1, 2) is the attention layer, and here its core is plain
     assert [(n, labels) for name, n, labels in eng.tracer.counted()
             if name == "attn_path"] == [(1, {"path": "plain"})]
+    # its core: q k^T and p v of 4 heads of 8 over the causal half of two
+    # sequences of 16
+    assert [(n, labels) for name, n, labels in eng.tracer.counted()
+            if name == "attn_core_flops"] == [
+        (2 * 4 * (8 + 8) * (16 * 17 // 2) * 2, {"kind": "full"})]
     out = capsys.readouterr().out
     assert f"[model] moe rows {rows} of {pairs}" in out
     assert "[model] attention squares 1 of 1" in out

@@ -11,7 +11,8 @@ Traced for the TPU (the `one_chip` fixture says so to
 `attention_kernel.on_tpu`, as the chip's own process would),
 `token_ops.causal_attention` is the fused kernel of
 `models/attention_kernel.py` (PR 35): every case that runs it holds the
-kernel's custom calls and no float32 score tensor."""
+kernel's custom calls, each under the scope `attn_core` (PR 36), and no
+float32 score tensor."""
 
 import os
 import re
@@ -87,9 +88,13 @@ def _assert_the_core_is_the_kernel(text, seq_len, causal=0, window=0):
     for call, layers in (("splash_mha_fwd", causal + window),
                          ("splash_mha_dkv", causal + window),
                          ("splash_mha_dq", window)):
-        calls = re.findall(rf'op_name="[^"]*/{call}[^"]*/pallas_call', text)
+        calls = re.findall(rf'op_name="([^"]*)/{call}[^"]*/pallas_call',
+                           text)
         assert len(calls) >= layers and bool(calls) == bool(layers), \
             (call, len(calls))
+        # forward, recompute and backward under the core's scope (PR 36)
+        assert all(re.search(r"(^|/)(\w+\()*attn_core\)*/", c)
+                   for c in calls), calls[:2]
     qb = token_ops.ATTN_QUERY_BLOCK
     entry = text[text.index("\nENTRY "):]
     scores = []
