@@ -148,7 +148,9 @@ def _rope(x, theta):
     `attn_rope`; x [B, T, n, d]."""
     with jax.named_scope("attn_rope"):
         d = x.shape[-1]
-        inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+        with jax.ensure_compile_time_eval():     # known when traced
+            inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32)
+                                   / d))
         return rope_half(x, inv)
 
 
@@ -259,7 +261,7 @@ class LFM2MoE(nn.Module):
                 "moe_rows": self.dispatch_rows(n_tokens),
                 "moe_rows_worst": n_tokens * self.spec.top_k,
                 "attn_squares_computed": computed, "attn_squares": square,
-                "attn_path": {path: layers},
+                "attn_path": {path: layers}, "attn_rope_product": layers,
                 "attn_core_flops": {"full": layers * core_flops(
                     n_tokens, seq_len, sp.heads, sp.head_dim,
                     sp.head_dim)}}

@@ -339,6 +339,8 @@ class MlaMoE(nn.Module):
                 "attn_path": {path: len(self.spec.layers)
                               + self.spec.mtp_depth},
                 "attn_core_flops": {"full": flops},
+                # its rotary pairs are its own (`_rope_pairs`), no product
+                "attn_rope_product": 0,
                 "mtp_depth": self.spec.mtp_depth,
                 "shared_experts": self.spec.shared_ffn // self.spec.moe_ffn}
 

@@ -230,8 +230,7 @@ def from_cfg(cfg, dtype=jnp.float32, remat: bool = False) -> "SwaMoE":
 
 def rope(x, r: Rope):
     """This layer kind's rotary embedding of x [B, T, n, d], in float32."""
-    return rope_half(x.astype(jnp.float32), jnp.asarray(rope_inv_freq(r)),
-                     r.scale)
+    return rope_half(x.astype(jnp.float32), rope_inv_freq(r), r.scale)
 
 
 def attention(p, x, sp: SwaSpec, kind: str, dtype):
@@ -351,6 +350,7 @@ class SwaMoE(nn.Module):
                 "attn_window_squares_computed": in_window,
                 "attn_window_squares": w_square, "attn_path": paths,
                 "attn_core_flops": flops,
+                "attn_rope_product": len(kinds),
                 "attn_window_layers": kinds.count(WINDOW),
                 "attn_full_layers": kinds.count(FULL),
                 "shared_experts": 1}

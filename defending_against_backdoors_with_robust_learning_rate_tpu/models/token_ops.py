@@ -139,23 +139,90 @@ def attention_squares(seq_len: int, q_block: int = ATTN_QUERY_BLOCK,
             nq * nk)
 
 
+@functools.lru_cache(maxsize=32)
+def _rope_tables(seq_len: int, d: int, inv_freq: tuple, scale: float):
+    """(c, s) [T, d] float32 and the rotation R [d, d] of `rope_half`:
+    cos and sin of `position * inv_freq` times `scale` on the rotated
+    widths, 1 and 0 on the passed ones; `R[i + h, i] = -1` and `R[i, i + h]
+    = 1` for i < h, half the rotated widths, zero elsewhere, so that `x @
+    R` is `[-x2, x1]` beside zeros. Computed once, by the same `jax.numpy`
+    expressions the program would run, and kept: constants of every
+    program that reads them."""
+    r = 2 * len(inv_freq)
+    h = r // 2
+    with jax.ensure_compile_time_eval():
+        inv = jnp.asarray(inv_freq, jnp.float32)
+        ang = jnp.arange(seq_len, dtype=jnp.float32)[:, None] * inv[None, :]
+        ang = jnp.concatenate([ang, ang], axis=-1)
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        if scale != 1.0:
+            cos, sin = cos * scale, sin * scale
+        c = np.ones((seq_len, d), np.float32)
+        s = np.zeros((seq_len, d), np.float32)
+        c[:, :r], s[:, :r] = np.asarray(cos), np.asarray(sin)
+    rot = np.zeros((d, d), np.float32)
+    rot[np.arange(h) + h, np.arange(h)] = -1.0
+    rot[np.arange(h), np.arange(h) + h] = 1.0
+    for table in (c, s, rot):       # shared by every caller of the cache
+        table.setflags(write=False)
+    return c, s, rot
+
+
+def _turn(x, c, s, rot, r: int):
+    """`x c + (x @ rot) s` in float32 over [B, T, n, d], the widths from `r`
+    on passed through as they are. Every column of `rot` holds one ±1 or
+    none, so the product is exact: one pass for bfloat16 x, float32 x at
+    `highest` (its three bfloat16 parts each exact)."""
+    precision = (jax.lax.Precision.DEFAULT if x.dtype == jnp.bfloat16
+                 else jax.lax.Precision.HIGHEST)
+    turned = jnp.einsum("btnd,de->btne", x, rot.astype(x.dtype),
+                        precision=precision,
+                        preferred_element_type=jnp.float32)
+    xf = x.astype(jnp.float32)
+    c, s = jnp.asarray(c)[None, :, None, :], jnp.asarray(s)[None, :, None, :]
+    out = xf * c + turned * s
+    if r == x.shape[-1]:
+        return out
+    return jnp.where(jnp.arange(x.shape[-1]) < r, out, xf)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _rope(x, key):
+    c, s, rot = _rope_tables(*key)
+    return _turn(x, c, s, rot, 2 * len(key[2]))
+
+
+def _rope_fwd(x, key):
+    # nothing to save: the empty array carries x's dtype to the backward
+    return _rope(x, key), jnp.zeros((0,), x.dtype)
+
+
+def _rope_bwd(key, like, g):
+    # the transpose of the forward: both halves of s hold the same sines,
+    # so the sine that meets a width on the way back is its own
+    c, s, rot = _rope_tables(*key)
+    return (_turn(g, c, s, rot.T, 2 * len(key[2])).astype(like.dtype),)
+
+
+_rope.defvjp(_rope_fwd, _rope_bwd)
+
+
 def rope_half(x, inv_freq, scale: float = 1.0):
     """Rotate-half rotary embedding, `x cos + rot(x) sin` with `rot(x) =
     [-x2, x1]`, over the first `2 * len(inv_freq)` widths of a head, the
-    rest passed through; x [B, T, n, d], positions from 0. `scale`
-    multiplies cos and sin (YaRN's attention factor)."""
-    d, t, r = x.shape[-1], x.shape[1], 2 * inv_freq.shape[0]
-    ang = jnp.arange(t, dtype=jnp.float32)[:, None] * inv_freq[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)[None, :, None, :]
-    xr = x if r == d else x[..., :r]
-    x1, x2 = xr[..., :r // 2], xr[..., r // 2:]
-    rot = jnp.concatenate([-x2, x1], axis=-1)
+    rest passed through; x [B, T, n, d], positions from 0, the result in
+    float32. `scale` multiplies cos and sin (YaRN's attention factor);
+    `inv_freq` has to be known when the program is traced.
 
-    def scaled(fn):
-        return fn(ang) if scale == 1.0 else fn(ang) * scale
-
-    out = xr * scaled(jnp.cos) + rot * scaled(jnp.sin)
-    return out if r == d else jnp.concatenate([out, x[..., r:]], axis=-1)
+    One pass a direction: `rot(x)` is a product with a constant signed
+    permutation, and cos and sin are constant tables (`_rope_tables`), so
+    the forward and the backward (`g c + (g @ R^T) s`, cast to x's dtype)
+    are each a product with the elementwise work on its output, and no
+    float32 copy of x is written on the way. Nothing is saved for the
+    backward."""
+    key = (x.shape[1], x.shape[-1],
+           tuple(np.asarray(inv_freq, np.float32).tolist()), float(scale))
+    return _rope(x, key)
 
 
 def plain_causal_attention(q, k, v, q_block: int = ATTN_QUERY_BLOCK,

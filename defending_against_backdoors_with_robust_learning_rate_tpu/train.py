@@ -325,9 +325,10 @@ class RoundEngine:
             # layer's first pass takes of a step's sorted pairs, and the
             # squares of a sequence's scores its attention forms, by layer
             # kind where some layers have a window, the attention layers
-            # by the path their core takes: attn_path{path=}, and the
-            # forward operations of a step's cores by layer kind:
-            # attn_core_flops{kind=})
+            # by the path their core takes: attn_path{path=}, the forward
+            # operations of a step's cores by layer kind:
+            # attn_core_flops{kind=}, and the layers whose rotary step is
+            # token_ops.rope_half's product: attn_rope_product)
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
@@ -373,7 +374,9 @@ class RoundEngine:
                          if "attn_window" in built else "")
                       + "; path " + ", ".join(
                           f"{path} in {layers} layer(s)"
-                          for path, layers in sorted(attn_paths.items())))
+                          for path, layers in sorted(attn_paths.items()))
+                      + f"; rotary by product in "
+                        f"{built['attn_rope_product']} layer(s)")
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

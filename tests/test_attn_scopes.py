@@ -37,12 +37,14 @@ TINY = {
         ("window_attention", "global_attention")),
 }
 OP = re.compile(r" = .*? ([a-z][a-z0-9-]*)\(.*op_name=\"([^\"]*)\"")
+# name, dims, opcode and operands of an instruction
+DEF = re.compile(r"^\s*(?:ROOT )?(\S+) = \w+\[([\d,]*)\]\S* "
+                 r"([a-z][a-z0-9-]*)\(([^)]*)\)")
 
 
-def _ops(model):
-    """(opcode, op_name) of every operation of the lowered training
-    gradient, forward, recompute and backward, in HLO before XLA's
-    passes."""
+def _lowered(model):
+    """The lowered training gradient, forward, recompute and backward, in
+    HLO before XLA's passes."""
     tokens = jnp.zeros((2, T), jnp.int32)
     params = jax.eval_shape(lambda: model.init(jax.random.PRNGKey(0), tokens,
                                                train=True))
@@ -52,9 +54,29 @@ def _ops(model):
         return sum(jnp.sum(x.astype(jnp.float32))
                    for x in (out[0],) + tuple(out[2] if len(out) > 2 else ()))
 
-    text = jax.jit(jax.grad(loss)).lower(params, tokens).as_text(
+    return jax.jit(jax.grad(loss)).lower(params, tokens).as_text(
         dialect="hlo", debug_info=True)
+
+
+def _ops(text):
+    """(opcode, op_name) of every operation."""
     return [m.groups() for m in map(OP.search, text.splitlines()) if m]
+
+
+def _rope_products(text):
+    """(opcode of the right operand, its dims, the product's width) of
+    every `dot` under `attn_rope`."""
+    lines = [(DEF.match(line), line) for line in text.splitlines()]
+    defs = {m.group(1): m for m, _line in lines if m}
+    out = []
+    for m, line in lines:
+        on = OP.search(line)
+        if m and m.group(3) == "dot" and on and _under(on.group(2),
+                                                      "attn_rope"):
+            rhs = defs[m.group(4).split(", ")[1]]
+            out.append((rhs.group(3), rhs.group(2),
+                        m.group(2).split(",")[-1]))
+    return out
 
 
 def _under(op_name, scope):
@@ -65,18 +87,30 @@ def _under(op_name, scope):
 @pytest.fixture(scope="module", params=sorted(TINY))
 def lowered(request):
     make, layer_scopes = TINY[request.param]
-    return request.param, _ops(make()), layer_scopes
+    text = _lowered(make())
+    return request.param, _ops(text), layer_scopes, _rope_products(text)
 
 
 def test_the_core_and_the_rotary_step_lie_under_their_scopes(lowered):
-    name, ops, layer_scopes = lowered
+    name, ops, layer_scopes, products = lowered
     core = Counter(op for op, n in ops if _under(n, "attn_core"))
     rope = Counter(op for op, n in ops if _under(n, "attn_rope"))
-    # the core's two products and its softmax, the rotary's angles
+    # the core's two products and its softmax, the rotary's tables
     assert core["dot"] >= 2 and core["exponential"] >= 1, core
     assert rope["multiply"] >= 2, rope
     trig = [n for op, n in ops if op in ("cosine", "sine")]
-    assert trig and all(_under(n, "attn_rope") for n in trig)
+    if name == "mla_moe":
+        # its rotary pairs take their angles in the program
+        assert trig and all(_under(n, "attn_rope") for n in trig)
+        assert not products, products
+    else:
+        # token_ops.rope_half: cos and sin are constant tables, and the
+        # rotation is a product with a constant [d, d] (forward; inside
+        # the recomputed block and backward it arrives as an operand)
+        assert not trig, trig[:2]
+        assert products and all(dims == f"{d},{d}"
+                                for _op, dims, d in products), products
+        assert any(op == "constant" for op, _dims, _d in products), products
     for op, n in ops:
         if _under(n, "attn_core") or _under(n, "attn_rope"):
             # inside the layer's own scope, and never one inside the other

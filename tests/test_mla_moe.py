@@ -316,7 +316,7 @@ def test_published_file_is_the_catalog_row_and_the_cut_counts_491_7m():
         "experts_held": 8, "vocab_held": 16160, "moe_rows": 16384,
         "moe_rows_worst": 65536, "attn_squares_computed": 36,
         "attn_squares": 64, "attn_path": {"plain": 6}, "mtp_depth": 1,
-        "shared_experts": 1,
+        "shared_experts": 1, "attn_rope_product": 0,
         # q k^T and p v on the causal half of four sequences, in the five
         # blocks and the MTP module's: 32 heads of 192 + 128
         "attn_core_flops": {"full": 6 * 2 * 32 * 320 * (2048 * 2049 // 2)

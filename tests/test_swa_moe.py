@@ -406,7 +406,7 @@ def test_published_file_is_the_catalog_row_and_the_cut_counts_490_3m():
         "attn_squares": 256, "attn_window": 512,
         "attn_window_squares_computed": 45, "attn_window_squares": 256,
         "attn_path": {"plain": 5}, "attn_window_layers": 3,
-        "attn_full_layers": 2, "shared_experts": 1,
+        "attn_full_layers": 2, "shared_experts": 1, "attn_rope_product": 5,
         # q k^T and p v of a step's two sequences: two full layers of 48
         # heads on the causal half, three of 64 on the band of 512
         "attn_core_flops": {

@@ -331,6 +331,9 @@ def test_engine_counts_the_buffers_rows_and_its_overflow(
     assert f"[model] moe rows {rows} of {pairs}" in out
     assert "[model] attention squares 1 of 1" in out
     assert "; path plain in 1 layer(s)" in out
+    # and its rotary step is rope_half's product
+    assert counted["attn_rope_product"] == 1
+    assert "; rotary by product in 1 layer(s)" in out
     run_dirs = [d for d in tmp_path.iterdir() if d.is_dir()]
     with open(run_dirs[0] / "metrics.jsonl") as fh:
         written = [json.loads(line) for line in fh]
