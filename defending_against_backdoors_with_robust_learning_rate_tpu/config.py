@@ -63,7 +63,8 @@ class Config:
     process_id: int = -1            # this process's id; -1 = auto
     arch: str = "auto"              # auto | cnn | resnet9, or a token model
                                     # of models/registry.TOKEN_ARCHS:
-                                    # lfm2_moe | mla_moe | swa_moe
+                                    # lfm2_moe | mla_moe | swa_moe |
+                                    # gdn_moe
     dtype: str = "f32"              # f32 | bf16 (compute dtype on the MXU)
     rng_impl: str = "auto"          # auto: hardware RNG (rbg) on TPU,
                                     # threefry elsewhere; threefry | rbg
@@ -451,14 +452,16 @@ class Config:
     # synthetic-data knobs (used when `data` is missing on disk or 'synthetic')
     synth_train_size: int = 2048
     synth_val_size: int = 512
-    # --- the token task (--data=tokens --arch=lfm2_moe|mla_moe|swa_moe;
+    # --- the token task (--data=tokens
+    # --arch=lfm2_moe|mla_moe|swa_moe|gdn_moe;
     # fl/task.py) ---
     seq_len: int = 2048             # tokens a packed sequence feeds the model
     lm_config: str = "lfm2-8b-a1b"  # the published widths: a name in the
                                     # arch's PUBLISHED (lfm2_moe:
                                     # lfm2-8b-a1b; mla_moe:
                                     # joyai-llm-flash; swa_moe:
-                                    # laguna-xs.2), or a file
+                                    # laguna-xs.2; gdn_moe:
+                                    # qwen3-next-80b-a3b), or a file
     lm_layers: str = ""             # the cut in depth: source layer indices
                                     # held, comma-separated ("" = all)
     lm_experts_held: int = 0        # experts of a sparse layer that live on
@@ -799,7 +802,8 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
                         "with --data=tokens a token model: lfm2_moe (short "
                         "convolutions + GQA) | mla_moe (latent attention + "
                         "MTP) | swa_moe (sliding-window and full attention "
-                        "mixed by layer)")
+                        "mixed by layer) | gdn_moe (Gated DeltaNet linear "
+                        "attention beside gated full attention)")
     p.add_argument("--dtype", type=str, default=d.dtype, help="f32|bf16")
     p.add_argument("--rng_impl", choices=("auto", "threefry", "rbg"),
                    default=d.rng_impl,
@@ -1191,7 +1195,8 @@ def _add_tpu_flags(p: argparse.ArgumentParser) -> None:
     p.add_argument("--lm_config", type=str, default=d.lm_config,
                    help="a token model's published widths, a name "
                         "(--arch=lfm2_moe: lfm2-8b-a1b; --arch=mla_moe: "
-                        "joyai-llm-flash; --arch=swa_moe: laguna-xs.2) or a "
+                        "joyai-llm-flash; --arch=swa_moe: laguna-xs.2; "
+                        "--arch=gdn_moe: qwen3-next-80b-a3b) or a "
                         "JSON file")
     p.add_argument("--lm_layers", type=str, default=d.lm_layers,
                    help="the cut in depth: source layer indices held, "

@@ -34,7 +34,9 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
     latent attention, a 256-expert mixture with a shared expert, a
     multi-token-prediction module; `swa_moe`: sliding-window and full
     attention mixed by layer with query heads and rotary embedding by layer
-    kind, a 256-expert mixture with a shared expert): it reads its widths and its cut from
+    kind, a 256-expert mixture with a shared expert; `gdn_moe`: Gated
+    DeltaNet linear attention beside gated full attention, a 512-expert
+    softmax-routed mixture with a gated shared expert): it reads its widths and its cut from
     `cfg`, which it needs, and under `remat` recomputes block by block. This module is the one place that knows a model by its
     name: the engine, the planner and the data layer ask the model (or
     `arch_takes_tokens`, `token_vocab`) what it is."""
@@ -67,7 +69,7 @@ def get_model(data: str, arch: str = "cnn", dtype: str = "f32",
 # third value, the logits per token ahead, and carries `ahead_weight`
 # (fl/task.make_batch_loss)
 TOKEN_ARCHS = {"lfm2_moe": "lfm2_moe", "mla_moe": "mla_moe",
-               "swa_moe": "swa_moe"}
+               "swa_moe": "swa_moe", "gdn_moe": "gdn_moe"}
 
 
 def _token_module(arch: str):

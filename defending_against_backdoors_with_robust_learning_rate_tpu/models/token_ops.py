@@ -1,19 +1,23 @@
-"""What the token models share (`lfm2_moe.py`, `mla_moe.py`, `swa_moe.py`):
-RMSNorm, the matrix product in the products' dtype, rotate-half rotary
-embedding, causal attention (with a sliding window where a layer has one),
-the gated feed-forward, the shared expert, and ONE implementation of the
-sparse feed-forward: sigmoid routing over every
-published expert, the sort of the (token, expert) pairs by held expert,
-dispatch, the held experts' grouped products and combine.
+"""What the token models share (`lfm2_moe.py`, `mla_moe.py`, `swa_moe.py`,
+`gdn_moe.py`): RMSNorm, the matrix product in the products' dtype,
+rotate-half rotary embedding, causal attention (with a sliding window where a
+layer has one), the gated feed-forward, the shared expert (under a sigmoid
+gate where a model has one), and ONE implementation of the sparse
+feed-forward: sigmoid or softmax routing over every published expert, the
+sort of the (token, expert) pairs by held expert, dispatch, the held
+experts' grouped products and combine.
 
 A sparse layer reads its shape off the model's spec, whatever its class:
 `n_experts` (the router's width), `top_k`, `norm_topk`, `topk_eps` (what the
 source adds to the selected scores' sum before dividing), `routed_scale`,
-`experts_held` from `expert_offset`. The routing bias is the model's own
-buffer and comes as an argument (None: the scores alone select).
+`experts_held` from `expert_offset`, and `scoring` where the spec has it
+("softmax"; a spec without the field scores by "sigmoid"). The routing bias
+is the model's own buffer and comes as an argument (None: the scores alone
+select).
 
-`s = sigmoid(x W_g)` in float32 over ALL published experts, `sel =
-top_k(s + b)`, `w = s[sel] / (sum s[sel] + topk_eps) * routed_scale`, and of
+`s = sigmoid(x W_g)` (or `softmax(x W_g)`) in float32 over ALL published
+experts, `sel = top_k(s + b)`, `w = s[sel] / (sum s[sel] + topk_eps) *
+routed_scale`, and of
 `y = sum_e w_e expert_e(x)` the terms whose expert is held here. That
 partial sum goes on to the next layer; nothing stands in for the experts
 of other chips. No (token, expert) pair is dropped: pairs are sorted by
@@ -419,12 +423,20 @@ def dense_ffn(p, x, dtype):
                    * _mm(x, p["w3"], dtype), p["w2"], dtype)
 
 
-def shared_expert(p, x, dtype):
+def shared_expert(p, x, dtype, gated: bool = False):
     """The gated feed-forward every token passes beside its routed experts
-    (`shared_w1`, `shared_w3`, `shared_w2`), added ungated."""
+    (`shared_w1`, `shared_w3`, `shared_w2`), added ungated, or `gated`
+    times `sigmoid(x W_sg)`, one float32 scalar a token (`shared_gate`
+    [D, 1])."""
     with jax.named_scope("shared_expert"):
-        return _mm(jax.nn.silu(_mm(x, p["shared_w1"], dtype))
-                   * _mm(x, p["shared_w3"], dtype), p["shared_w2"], dtype)
+        y = _mm(jax.nn.silu(_mm(x, p["shared_w1"], dtype))
+                * _mm(x, p["shared_w3"], dtype), p["shared_w2"], dtype)
+        if not gated:
+            return y
+        gate = jax.nn.sigmoid(jnp.dot(x.astype(dtype),
+                                      p["shared_gate"].astype(dtype),
+                                      preferred_element_type=jnp.float32))
+        return (y.astype(jnp.float32) * gate).astype(y.dtype)
 
 
 def _expert_rows(lo, hi, trained, sort):
@@ -538,7 +550,9 @@ def sparse_ffn(p, x, sp, bias, dtype):
     with jax.named_scope("moe_router"):
         logits = jnp.dot(x.astype(jnp.float32), p["gate"],
                          precision=jax.lax.Precision.HIGHEST)
-        s = jax.nn.sigmoid(logits)
+        s = (jax.nn.softmax(logits, axis=-1)
+             if getattr(sp, "scoring", "sigmoid") == "softmax"
+             else jax.nn.sigmoid(logits))
         pick = s if bias is None else s + bias
         _, sel = jax.lax.top_k(pick, top_k)              # [n, k]
         w = jnp.take_along_axis(s, sel, axis=-1)

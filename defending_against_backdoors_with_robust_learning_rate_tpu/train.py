@@ -327,8 +327,12 @@ class RoundEngine:
             # kind where some layers have a window, the attention layers
             # by the path their core takes: attn_path{path=}, the forward
             # operations of a step's cores by layer kind:
-            # attn_core_flops{kind=}, and the layers whose rotary step is
-            # token_ops.rope_half's product: attn_rope_product)
+            # attn_core_flops{kind=}, the layers whose rotary step is
+            # token_ops.rope_half's product: attn_rope_product, and of a
+            # linear-attention model its layers, chunk, chunks a sequence,
+            # the core's forward operations a step and its state's bytes:
+            # gdn_layers, gdn_chunk, gdn_chunks, gdn_core_flops,
+            # gdn_state_bytes)
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
@@ -377,6 +381,14 @@ class RoundEngine:
                           for path, layers in sorted(attn_paths.items()))
                       + f"; rotary by product in "
                         f"{built['attn_rope_product']} layer(s)")
+            if "gdn_layers" in built:
+                print(f"[model] gdn {built['gdn_layers']} linear-attention "
+                      f"layer(s): the delta rule in {built['gdn_chunks']} "
+                      f"chunk(s) of {built['gdn_chunk']} tokens a sequence, "
+                      f"a float32 state of {built['gdn_state_bytes']:,} "
+                      f"bytes a layer and step, "
+                      f"{built['gdn_core_flops']:.4g} core operations a "
+                      f"step")
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

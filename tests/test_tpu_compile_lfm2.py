@@ -406,3 +406,64 @@ def test_swa_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     assert ma.temp_size_in_bytes <= \
         ATTN_TEMP_ROOM * SWA_TEMP_AT_PR33["swa_step"], ma.temp_size_in_bytes
     _assert_the_core_is_the_kernel(text, 4096, causal=2, window=3)
+
+
+def test_gdn_round_program_compiles_for_the_v5e_and_fits(one_chip,
+                                                          quiet_cache,
+                                                          monkeypatch,
+                                                          tmp_path):
+    """The linear/full-attention model's whole `round` program as the
+    planner builds it for the benchmark cell's flags
+    (`benchmark/configs/qwen3-next-80b-a3b-ep32.json`: 424.3M parameters,
+    ten clients folded one at a time, two steps of 2 x 4096 tokens each),
+    traced as the chip's process would trace it (no CPU loops, the client
+    loop flat). The chunked rule's triangular solve is in it, the one full
+    layer's core is the kernel, and its arguments, outputs and temporaries
+    fit a 16 GB chip with room for the eval programs."""
+    import json
+
+    from defending_against_backdoors_with_robust_learning_rate_tpu.config import (
+        args_parser)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.data.registry import (
+        get_federated_data)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.fl.common import (
+        make_normalizer)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.models.registry import (
+        get_model)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.ops import (
+        loops)
+    from defending_against_backdoors_with_robust_learning_rate_tpu.utils import (
+        compile_cache as cc)
+    with open(os.path.join(os.path.dirname(__file__), os.pardir, "benchmark",
+                           "configs", "qwen3-next-80b-a3b-ep32.json")) as f:
+        flags = json.load(f)["flags"]
+    monkeypatch.setattr(loops, "cpu_backend", lambda: False)
+    monkeypatch.setattr(loops, "carry_is_large", lambda params, steps:
+                        steps <= loops.LARGE_CARRY_MAX_STEPS)
+    cfg = args_parser(flags + [
+        "--platform=cpu", "--no_tensorboard", "--no_compile_cache",
+        "--data_dir=/nonexistent", f"--log_dir={tmp_path}"]).replace(
+            remat_policy="block", agg_path="fold")
+    resolved = cc.resolved_agg
+    monkeypatch.setattr(cc, "resolved_agg", lambda c, n: resolved(
+        c.replace(agg_path="fold"), n))
+    fed = get_federated_data(cfg)
+    model = get_model(cfg.data, cfg.model_arch, cfg.dtype, remat=cfg.remat,
+                      remat_policy=cfg.remat_policy, cfg=cfg)
+    spec, = [s for s in cc.plan_programs(
+        cfg, model, make_normalizer(fed.mean, fed.std, fed.raw_is_normalized),
+        fed) if s.family == "round"]
+    args = jax.tree_util.tree_map(
+        lambda a: _aval(a.shape, a.dtype, one_chip),
+        cc.abstractify(spec.example_args))
+    compiled = spec.jit_obj.lower(*args).compile()
+    text = compiled.as_text()
+    # the solve of each linear layer's chunks, forward and recomputed
+    assert text.count("InvertDiagBlocksLowerTriangular") >= 3
+    assert text.count("ragged-dot") >= 4 * 3 * 3
+    _assert_the_core_is_the_kernel(text, 4096, causal=1)
+    ma = compiled.memory_analysis()
+    assert ma.argument_size_in_bytes >= 4 * 424_340_544
+    held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
+    assert held < 14 * 2 ** 30, held
