@@ -18,7 +18,9 @@ give them (what the file lacks is under its `assumed`):
   per value head, from `S = 0` at a sequence's start: `S ← exp(g_t) S`, `u_t
   = beta_t (v_t - S^T k_t)`, `S ← S + k_t u_t^T`, `o_t = S^T q_t`; `y =
   rmsnorm(o) w_n silu(z)`; `W_out`. The recurrence runs in chunks
-  (`chunked_delta_rule`), never a token at a time.
+  (`chunked_delta_rule`), never a token at a time; in a program built for
+  the TPU, where the heads are whole 128-lane tiles, as the fused kernels
+  of `models/gdn_kernel.py` (`delta_rule` picks when a program is traced).
 - `full_attention` (`gated_attention`): `[q | gate] = x W_q` a head at a
   time, zero-centred norms on q and k a head, rotate-half rotary on the
   first `partial_rotary_factor x head_dim` widths (`token_ops.rope_half`),
@@ -49,7 +51,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from defending_against_backdoors_with_robust_learning_rate_tpu.models import (
-    attention_kernel, token_ops)
+    attention_kernel, gdn_kernel, token_ops)
 from defending_against_backdoors_with_robust_learning_rate_tpu.models.token_ops import (
     _mm, _rms, attention_plan, causal_attention, core_flops, dispatch_rows,
     rope_half, shared_expert)
@@ -236,12 +238,13 @@ def inverse_by_halves(a):
 
 def unit_lower_inverse(a):
     """`(I + a)^-1` of strictly lower triangular a [..., C, C] in float32:
-    in a program built for the TPU, XLA's triangular solve against the
-    identity (the TPU's tiles pad the small blocks `inverse_by_halves`
-    multiplies to 128 lanes: 1.7 GB more of a layer's temporaries at the
-    cell's shape, XLA's analysis for a described v5e); elsewhere by
-    halves, since the CPU's solve is a LAPACK call that an executable
-    loaded from the compile cache by another process cannot reach."""
+    in a program built for the TPU (heads the kernel of `gdn_kernel.py`
+    does not take), XLA's triangular solve against the identity (the TPU's
+    tiles pad the small blocks `inverse_by_halves` multiplies to 128 lanes:
+    1.7 GB more of a layer's temporaries at the cell's shape, XLA's
+    analysis for a described v5e); elsewhere by halves, since the CPU's
+    solve is a LAPACK call that an executable loaded from the compile cache
+    by another process cannot reach."""
     if not attention_kernel.on_tpu():
         return inverse_by_halves(a)
     eye = jnp.eye(a.shape[-1], dtype=a.dtype)
@@ -322,6 +325,19 @@ def chunked_delta_rule(q, k, v, g, beta, dtype, chunk: int = GDN_CHUNK):
     return o[:, :t]
 
 
+def delta_rule(q, k, v, g, beta, dtype):
+    """`chunked_delta_rule`'s contract by the lowering chosen when the
+    program is traced (`gdn_kernel.plan`: platform and widths): the fused
+    kernels of `models/gdn_kernel.py` on the TPU where the heads are whole
+    128-lane tiles, `chunked_delta_rule` itself everywhere else. Under
+    `jax.checkpoint(policy=gdn_kernel.SAVED)` the kernels' residuals (the
+    states and inverses) are kept and the rest formed again."""
+    took = gdn_kernel.plan(q.shape[1], q.shape[-1], v.shape[-1], GDN_CHUNK)
+    if took is None:
+        return chunked_delta_rule(q, k, v, g, beta, dtype)
+    return gdn_kernel.chunked_delta_rule(q, k, v, g, beta, dtype, took)
+
+
 def gdn(p, x, sp: GdnSpec, dtype):
     """The Gated DeltaNet mixer on normed x [B, T, D]."""
     hk, hv, dk, dv = sp.k_heads, sp.v_heads, sp.k_dim, sp.v_dim
@@ -340,8 +356,8 @@ def gdn(p, x, sp: GdnSpec, dtype):
                     sp.l2_eps) * dk ** -0.5
         k = _l2norm(k.reshape(b, t, hk, dk).astype(jnp.float32), sp.l2_eps)
         with jax.named_scope("gdn_core"):
-            o = jax.checkpoint(functools.partial(
-                chunked_delta_rule, dtype=dtype))(
+            o = jax.checkpoint(functools.partial(delta_rule, dtype=dtype),
+                               policy=gdn_kernel.SAVED)(
                 q.astype(dtype), k.astype(dtype),
                 v.reshape(b, t, hv, dv).astype(dtype), g, beta)
         y = _rms(o, p["gdn_norm"], sp.norm_eps) * jax.nn.silu(
@@ -470,6 +486,8 @@ class GdnMoE(nn.Module):
         path, computed, square = attention_plan(seq_len)
         kinds = [kind for _i, kind in sp.layers]
         linear, full = kinds.count(LINEAR), kinds.count(FULL)
+        core = ("xla" if gdn_kernel.plan(seq_len, sp.k_dim, sp.v_dim,
+                                         GDN_CHUNK) is None else "kernel")
         return {"experts_held": sp.experts_held,
                 "vocab_held": sp.vocab_held,
                 "moe_rows": self.dispatch_rows(n_tokens),
@@ -482,6 +500,7 @@ class GdnMoE(nn.Module):
                 "attn_rope_product": full, "attn_full_layers": full,
                 "shared_experts": 1,
                 "gdn_layers": linear, "gdn_chunk": GDN_CHUNK,
+                "gdn_path": {core: linear} if linear else {},
                 "gdn_chunks": -(-seq_len // GDN_CHUNK),
                 "gdn_core_flops": gdn_core_flops(n_tokens, sp),
                 "gdn_state_bytes": (n_tokens // seq_len * sp.v_heads

@@ -332,18 +332,22 @@ class RoundEngine:
             # linear-attention model its layers, chunk, chunks a sequence,
             # the core's forward operations a step and its state's bytes:
             # gdn_layers, gdn_chunk, gdn_chunks, gdn_core_flops,
-            # gdn_state_bytes)
+            # gdn_state_bytes, and its layers by the path their core
+            # takes: gdn_path{path=})
             built = (model.build_counters(cfg.bs * example_shape[0],
                                           example_shape[0])
                      if task_mod.is_tokens(cfg) else {})
             attn_paths = built.pop("attn_path", {})
             attn_flops = built.pop("attn_core_flops", {})
+            gdn_paths = built.pop("gdn_path", {})
             for name, value in built.items():
                 tracer.count(name, value)
             for path, layers in attn_paths.items():
                 tracer.count("attn_path", layers, path=path)
             for kind, flops in attn_flops.items():
                 tracer.count("attn_core_flops", flops, kind=kind)
+            for path, layers in gdn_paths.items():
+                tracer.count("gdn_path", layers, path=path)
             # stack or fold, settled here from the stack's bytes and what
             # the device has free (compile_cache.resolved_agg), before a
             # parameter exists; from here on cfg carries the resolved
@@ -388,7 +392,8 @@ class RoundEngine:
                       f"a float32 state of {built['gdn_state_bytes']:,} "
                       f"bytes a layer and step, "
                       f"{built['gdn_core_flops']:.4g} core operations a "
-                      f"step")
+                      f"step; core by kernel in "
+                      f"{gdn_paths.get('kernel', 0)} layer(s)")
             if cfg.remat:
                 tracer.count("remat", policy=remat.policy)
                 tracer.count("remat_saved_bytes", remat.saved_bytes)

@@ -362,6 +362,41 @@ def test_counters_count_what_the_reference_counts(built):
     assert got["moe_rows_worst"] == 2 * T * 4
 
 
+def test_the_core_path_is_counted_at_build_and_named(built, monkeypatch,
+                                                     tmp_path, capsys):
+    """`gdn_path`: the linear layers by the lowering their core takes,
+    `gdn_kernel.plan`'s answer. On the CPU the XLA rule; traced
+    for the TPU, the kernel at the cell's heads of 128 and still the XLA
+    rule at the toy's heads of 8. The `[model] gdn` line ends with the
+    layers the kernel takes."""
+    cfg, _spec, model, _p, _d, _c, _r = built
+    cell = gm.GdnMoE(spec=gm.spec_from("qwen3-next-80b-a3b", "0,1,2,3", 16,
+                                       0, 18992), dtype=jnp.bfloat16)
+    assert model.build_counters(2 * T, T)["gdn_path"] == {"xla": 3}
+    assert cell.build_counters(8192, 4096)["gdn_path"] == {"xla": 3}
+    monkeypatch.setattr(attention_kernel, "on_tpu", lambda: True)
+    assert cell.build_counters(8192, 4096)["gdn_path"] == {"kernel": 3}
+    assert model.build_counters(2 * T, T)["gdn_path"] == {"xla": 3}
+    monkeypatch.undo()
+
+    from defending_against_backdoors_with_robust_learning_rate_tpu import (
+        train)
+
+    class Built(Exception):
+        """Raised where the engine has printed its model lines."""
+
+    def stop(*_a, **_k):
+        raise Built
+
+    monkeypatch.setattr(train, "make_normalizer", stop)
+    with pytest.raises(Built):
+        train.RoundEngine(cfg.replace(log_dir=str(tmp_path)))
+    line, = [ln for ln in capsys.readouterr().out.splitlines()
+             if ln.startswith("[model] gdn ")]
+    assert line.startswith("[model] gdn 3 linear-attention layer(s)")
+    assert line.endswith("; core by kernel in 0 layer(s)")
+
+
 def test_the_cut_of_the_published_widths_counts_424_3m():
     """The cut's arithmetic at the published widths, from shapes alone."""
     with open(gm.PUBLISHED["qwen3-next-80b-a3b"]) as f:

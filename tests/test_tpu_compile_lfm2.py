@@ -408,6 +408,13 @@ def test_swa_training_step_compiles_for_the_v5e_and_its_temporaries_fit(
     _assert_the_core_is_the_kernel(text, 4096, causal=2, window=3)
 
 
+# temporaries (bytes) of the Qwen3-Next round program, read from the test
+# below at commit b165a46 (the chunked rule as XLA's solve and a scan over
+# chunks), here, under the suite's settings; the kernels' program may hold
+# no more
+GDN_TEMP_AT_PARENT = 9_232_519_168
+
+
 def test_gdn_round_program_compiles_for_the_v5e_and_fits(one_chip,
                                                           quiet_cache,
                                                           monkeypatch,
@@ -417,9 +424,14 @@ def test_gdn_round_program_compiles_for_the_v5e_and_fits(one_chip,
     (`benchmark/configs/qwen3-next-80b-a3b-ep32.json`: 424.3M parameters,
     ten clients folded one at a time, two steps of 2 x 4096 tokens each),
     traced as the chip's process would trace it (no CPU loops, the client
-    loop flat). The chunked rule's triangular solve is in it, the one full
-    layer's core is the kernel, and its arguments, outputs and temporaries
-    fit a 16 GB chip with room for the eval programs."""
+    loop flat). The linear layers' core is the fused kernel of
+    `models/gdn_kernel.py`: the forward that keeps its residuals
+    (the pass and the block's recompute) and the backward, each under the
+    scope `gdn_core` that `gdn_core_ms` reads, with no triangular solve and
+    no loop over chunks left under it. The one full layer's core is the
+    attention kernel, and the arguments, outputs and temporaries fit a
+    16 GB chip with room for the eval programs, the temporaries no more
+    than the XLA lowering's."""
     import json
 
     from defending_against_backdoors_with_robust_learning_rate_tpu.config import (
@@ -458,8 +470,19 @@ def test_gdn_round_program_compiles_for_the_v5e_and_fits(one_chip,
         cc.abstractify(spec.example_args))
     compiled = spec.jit_obj.lower(*args).compile()
     text = compiled.as_text()
-    # the solve of each linear layer's chunks, forward and recomputed
-    assert text.count("InvertDiagBlocksLowerTriangular") >= 3
+    # the kernels of each linear layer's core, under its scope;
+    # the XLA lowering's solve (18 of them) and its 24 scans over chunks
+    # are gone
+    for call, layers in (("gdn_forward_res", 3), ("gdn_backward", 3)):
+        calls = re.findall(rf'op_name="([^"]*)/{call}/pallas_call', text)
+        held = {re.search(r"layer_\d+", c).group() for c in calls}
+        assert len(held) == layers, (call, held)
+        assert all(re.search(r"(^|/)gdn/gdn_core(/|$)", c) for c in calls), \
+            calls[:2]
+    assert "gdn_forward/pallas_call" not in text
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    whiles = re.findall(r"^\s*\S+ = [^\n]* while\([^\n]*$", text, re.M)
+    assert whiles and not [w for w in whiles if "gdn_core" in w], whiles
     assert text.count("ragged-dot") >= 4 * 3 * 3
     _assert_the_core_is_the_kernel(text, 4096, causal=1)
     ma = compiled.memory_analysis()
@@ -467,3 +490,4 @@ def test_gdn_round_program_compiles_for_the_v5e_and_fits(one_chip,
     held = (ma.argument_size_in_bytes + ma.output_size_in_bytes
             - ma.alias_size_in_bytes + ma.temp_size_in_bytes)
     assert held < 14 * 2 ** 30, held
+    assert ma.temp_size_in_bytes <= GDN_TEMP_AT_PARENT, ma.temp_size_in_bytes
